@@ -85,6 +85,7 @@ if importlib.util.find_spec("repro") is None:       # script run w/o PYTHONPATH
 
 from repro.configs import get_arch, reduced            # noqa: E402
 from repro.data import DataConfig, make_prompts        # noqa: E402
+from repro.launch.jax_cache import enable_compile_cache  # noqa: E402
 from repro.models import transformer                   # noqa: E402
 from repro.serving import ContinuousBatchingEngine     # noqa: E402
 from repro.serving import kv_pool                      # noqa: E402
@@ -110,7 +111,7 @@ SPEC_WARM_PROMPT = [7] * 8 + list(range(16))
 
 
 def make_engine(params, cfg, *, kv_bits, max_batch, max_seq_len,
-                paged_impl="xla", prefill_mode="chunked",
+                paged_impl="auto", prefill_mode="chunked",
                 prefix_cache=False):
     # full token budget: every slot advances a chunk per mixed step — the
     # batched-prefill configuration the >= 1.5x gate measures
@@ -257,6 +258,7 @@ def main(argv=None):
                     "parity, chunked-vs-legacy prefill, prefix caching); "
                     "the bytes and int4 gates always run")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     prompt_len = args.prompt_len or (48 if args.smoke else 16)
     max_new = args.max_new or (8 if args.smoke else 32)
     batches = args.batches or ([] if args.smoke else [1, 2, 4, 8, 16, 32])
@@ -291,7 +293,8 @@ def main(argv=None):
     # -- pallas kernels (interpret) vs XLA gather: same tokens --------------
     few = prompts[:2]
     r_xla = make_engine(params, cfg, kv_bits=args.kv_bits, max_batch=2,
-                        max_seq_len=max_seq_len).run(few, max_new=8)
+                        max_seq_len=max_seq_len,
+                        paged_impl="xla").run(few, max_new=8)
     r_pal = make_engine(params, cfg, kv_bits=args.kv_bits, max_batch=2,
                         max_seq_len=max_seq_len,
                         paged_impl="pallas_interpret").run(few, max_new=8)

@@ -38,7 +38,10 @@ def run_variant(arch, shape, tag, *, quant="int8", strategy="fsdp_tp",
                "--kv-bits", str(kv_bits), "--n-micro", str(n_micro)]
         if tag:
             cmd += ["--tag", tag]
-        e = {**os.environ, "PYTHONPATH": os.path.join(ROOT, "src")}
+        # placeholder-device dry-run: the child stays on the CPU, so on a
+        # TPU host it never takes the chip
+        e = {**os.environ, "PYTHONPATH": os.path.join(ROOT, "src"),
+             "JAX_PLATFORMS": "cpu"}
         e.update(env or {})
         r = subprocess.run(cmd, capture_output=True, text=True, env=e)
         if r.returncode != 0:

@@ -37,21 +37,22 @@ def _analytic_fused(cfg, b, quant):
     server): weights streamed once per prefill at their storage width,
     activations touched ~3x per layer at bf16, attention bf16."""
     from repro.roofline import analysis, hw
+    pk = hw.peaks(hw.V5E)
     n = cfg.param_count()
     tokens = b * SEQ
     mf = analysis.model_flops(cfg, "prefill", SEQ, b)
     lin = mf["linear_fwd_flops"]
     attn = mf["attn_flops"]
     if quant == "fp16":
-        compute = (lin + attn) / hw.PEAK_BF16
+        compute = (lin + attn) / pk.bf16_flops
         w_bytes = 2 * n
     else:
-        compute = lin / hw.PEAK_INT8 + attn / hw.PEAK_BF16
+        compute = lin / pk.int8_ops + attn / pk.bf16_flops
         w_bytes = n if quant == "int8" else n // 2
     act_bytes = tokens * cfg.d_model * cfg.n_layers * 3 * 2
     kv_bytes = tokens * cfg.n_kv_heads * cfg.hd * 2 * cfg.n_layers * 2
     attn_bytes = attn // (2 * cfg.hd) * 2          # K/V streamed per q-block
-    mem = (w_bytes + act_bytes + kv_bytes + attn_bytes) / hw.HBM_BW
+    mem = (w_bytes + act_bytes + kv_bytes + attn_bytes) / pk.hbm_bw
     return {"compute_s": compute / N_CHIPS, "memory_s": mem / N_CHIPS,
             "latency_ms": max(compute, mem) / N_CHIPS * 1e3,
             "weight_gb": w_bytes / 2**30}
@@ -120,10 +121,13 @@ def _subprocess_main():
 
 def main(print_rows=True):
     if not os.path.exists(RESULT):
+        # a CPU placeholder-device compile: JAX_PLATFORMS=cpu keeps the
+        # child off the TPU on a chip host
         r = subprocess.run([sys.executable, __file__, "--subprocess"],
                            env={**os.environ,
                                 "PYTHONPATH": os.environ.get("PYTHONPATH",
-                                                             "src")},
+                                                             "src"),
+                                "JAX_PLATFORMS": "cpu"},
                            capture_output=True, text=True)
         if r.returncode != 0:
             print(r.stderr[-2000:], file=sys.stderr)

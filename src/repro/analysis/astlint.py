@@ -10,9 +10,9 @@ line to silence a rule for the whole file.
 
 Enforced invariants (see README "Static analysis"):
   * pallas-compiler-params — every `pl.pallas_call` passes `compiler_params=`
-    built via the `repro.kernels.tpu_compiler_params` version shim.
+    built via `repro.kernels.tpu_compiler_params`.
   * raw-compiler-params   — no direct `pltpu.TPUCompilerParams(...)` /
-    `pltpu.CompilerParams(...)` construction outside the shim module.
+    `pltpu.CompilerParams(...)` construction outside `repro.kernels`.
   * magic-quant-literal   — no bare -128 / -127 / 127 / 15 quant-range
     literals outside `core/quant/qtypes.py`; use `qmin(bits)` / `qmax(bits)`.
   * no-float64            — no float64 dtypes (TPU pipeline is f32/bf16/int).
@@ -152,8 +152,8 @@ def _check_pallas_compiler_params(ctx: FileCtx) -> Iterator[Finding]:
                   and _callee_name(kw.value) == "tpu_compiler_params"):
             yield from ctx.finding(
                 "pallas-compiler-params", kw.value,
-                "compiler_params not built via the tpu_compiler_params shim "
-                "(raw construction breaks across JAX pallas renames)")
+                "compiler_params not built via repro.kernels."
+                "tpu_compiler_params (the one construction point)")
 
 
 @rule("raw-compiler-params",

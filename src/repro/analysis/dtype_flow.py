@@ -25,8 +25,9 @@ Checked invariants:
     dequantization; any data tensor reaching d > 0 was double-scaled.
   * scale-mismatch    — add-like ops never combine tensors at different
     scale states (e.g. an int32 accumulator with a dequantized float).
-  * packed-int4-upcast — packed int4 storage is never converted to a wider
-    dtype or fed to a matmul before the shift-based unpack.
+  * packed-int4-upcast — packed int4 storage is never converted to a float
+    dtype or fed to a matmul before the shift-based unpack (an integer
+    widening ahead of the shifts is the unpack itself, and legal).
   * nonlinear-on-unscaled — transcendental ops never consume a tensor that
     still owes a dequant scale.
 
@@ -46,10 +47,7 @@ import jax.numpy as jnp
 
 from repro.analysis.findings import Finding
 
-try:  # jax.core is the public home through 0.4.x
-    from jax import core as _core
-except ImportError:  # pragma: no cover - newer jax
-    from jax._src import core as _core
+from jax.extend import core as _core
 
 try:
     from jax._src import source_info_util as _siu
@@ -313,7 +311,10 @@ class _FlowChecker:
         if p == "convert_element_type":
             f = ins[0]
             new = eqn.outvars[0].aval.dtype
-            if f.packed and new != jnp.int8:
+            # an integer widening keeps the nibbles intact (the shifts that
+            # follow still unpack them); any other conversion reads the
+            # packed byte as one number
+            if f.packed and not jnp.issubdtype(new, jnp.integer):
                 self._emit(
                     "packed-int4-upcast", eqn,
                     f"packed int4 storage converted to {new} before "

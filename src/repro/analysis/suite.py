@@ -126,8 +126,8 @@ def spec_paged_attn_dequant() -> TraceSpec:
     from repro.kernels import paged_attn
     b, nq, nkv, hd, page, n_pages, w = 2, 4, 2, 32, 8, 5, 2
     args = (_sds((b, nq, hd), jnp.float32),
-            _sds((n_pages, page, nkv, hd), jnp.int8),
-            _sds((n_pages, page, nkv, hd), jnp.int8),
+            _sds((n_pages, nkv, page, hd), jnp.int8),
+            _sds((n_pages, nkv, page, hd), jnp.int8),
             _sds((n_pages, nkv), jnp.float32),
             _sds((n_pages, nkv), jnp.float32),
             _sds((b, w), jnp.int32), _sds((b,), jnp.int32))
@@ -142,8 +142,8 @@ def spec_paged_prefill_dequant() -> TraceSpec:
     from repro.kernels import paged_prefill
     b, c, nq, nkv, hd, page, n_pages, w = 2, 16, 4, 2, 32, 8, 7, 3
     args = (_sds((b, c, nq, hd), jnp.float32),
-            _sds((n_pages, page, nkv, hd), jnp.int8),
-            _sds((n_pages, page, nkv, hd), jnp.int8),
+            _sds((n_pages, nkv, page, hd), jnp.int8),
+            _sds((n_pages, nkv, page, hd), jnp.int8),
             _sds((n_pages, nkv), jnp.float32),
             _sds((n_pages, nkv), jnp.float32),
             _sds((b, w), jnp.int32), _sds((b,), jnp.int32),
@@ -311,8 +311,7 @@ def spec_serving_verify_step() -> TraceSpec:
     def step(params, pools, page_table, window_rows, tokens, q_start,
              n_new, n_keep):
         logits, kv_win = transformer.verify_step_paged(
-            params, pools, page_table, tokens, q_start, n_new,
-            cfg, paged_impl="xla")
+            params, pools, page_table, tokens, q_start, n_new, cfg)
         out = {}
         for i in pools:
             kw, vw = kv_win[i]

@@ -238,12 +238,23 @@ def pack_int4(x: jax.Array, axis: int = 0) -> jax.Array:
     return jnp.moveaxis(packed, 0, axis)
 
 
+def int4_nibbles(packed: jax.Array):
+    """Sign-extended (low, high) nibbles of packed int4 bytes, as int32.
+
+    The bytes are widened to int32 *before* the shifts: Mosaic (the Pallas
+    TPU compiler) has no 8-bit shifts, and a 32-bit lane gives the same
+    arithmetic-shift sign extension for int8 and uint8 storage alike —
+    `(b << 28) >> 28` keeps the low nibble, `(b << 24) >> 28` the high one.
+    Every unpack in the repo (XLA and kernel bodies) goes through here."""
+    b = packed.astype(jnp.int32)
+    return (b << 28) >> 28, (b << 24) >> 28
+
+
 def unpack_int4(packed: jax.Array, axis: int = 0, orig_dim: int = 0) -> jax.Array:
     """Inverse of pack_int4 — arithmetic shifts sign-extend the nibbles."""
     assert packed.dtype == jnp.int8
     p = jnp.moveaxis(packed, axis, 0)
-    lo = jnp.right_shift(jnp.left_shift(p, 4), 4)   # sign-extended low nibble
-    hi = jnp.right_shift(p, 4)                      # arithmetic shift
+    lo, hi = int4_nibbles(p)
     out = jnp.stack([lo, hi], axis=1).reshape((-1,) + p.shape[1:])
     if orig_dim:
         out = out[:orig_dim]
@@ -275,8 +286,7 @@ def unpack_int4_halves(packed: jax.Array, group: int) -> jax.Array:
     k2, n = packed.shape
     g2 = group // 2
     pg = packed.reshape(k2 // g2, g2, n)
-    lo = jnp.right_shift(jnp.left_shift(pg, 4), 4)
-    hi = jnp.right_shift(pg, 4)
+    lo, hi = int4_nibbles(pg)
     out = jnp.concatenate([lo, hi], axis=1)  # (K//g, g, N)
     return out.reshape(2 * k2, n).astype(jnp.int8)
 
@@ -299,15 +309,11 @@ def pack_int4_halves_lastdim(x: jax.Array) -> jax.Array:
 
 def unpack_int4_halves_lastdim(packed: jax.Array) -> jax.Array:
     """Inverse of pack_int4_halves_lastdim: (..., D//2) uint8 -> (..., D)
-    int8. The uint8 -> int8 astype is a same-width reinterpret (XLA integer
-    conversions wrap), so the shift-based sign extension sees the stored
-    bit pattern unchanged — works identically inside Pallas kernel bodies.
-    """
+    int8, through the int32-widened shifts of `int4_nibbles`, so the same
+    code runs inside Pallas kernel bodies."""
     assert packed.dtype == jnp.uint8
-    b = packed.astype(jnp.int8)
-    lo = jnp.right_shift(jnp.left_shift(b, 4), 4)
-    hi = jnp.right_shift(b, 4)
-    return jnp.concatenate([lo, hi], axis=-1)
+    lo, hi = int4_nibbles(packed)
+    return jnp.concatenate([lo, hi], axis=-1).astype(jnp.int8)
 
 
 # ---------------------------------------------------------------------------

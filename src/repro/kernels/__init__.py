@@ -1,19 +1,15 @@
 """Pallas TPU kernels for the low-bit inference framework.
 
-Modules: int8_gemm, w4a8_gemm, quantize_act, hadamard, paged_attn (kernels);
-ops (jit'd wrappers + dispatch); ref (pure-jnp oracles).
+Modules: int8_gemm, w4a8_gemm, quantize_act, hadamard, paged_attn,
+paged_prefill, flash_attn (kernels); ops (jit'd wrappers + dispatch); ref
+(pure-jnp oracles).
 
-Version-compat shim: the TPU compiler-params dataclass was renamed across
-JAX releases (`TPUCompilerParams` in 0.4.x, `CompilerParams` in newer
-pallas). Kernels build their params through `tpu_compiler_params` so both
-spellings work against whichever JAX is installed.
+Every kernel builds its compiler params through `tpu_compiler_params`, the
+one construction point quantlint's `pallas-compiler-params` rule checks.
 """
 from jax.experimental.pallas import tpu as _pltpu
 
-_COMPILER_PARAMS_CLS = getattr(_pltpu, "CompilerParams", None) \
-    or getattr(_pltpu, "TPUCompilerParams")
-
 
 def tpu_compiler_params(**kwargs):
-    """Construct pltpu compiler params under either JAX spelling."""
-    return _COMPILER_PARAMS_CLS(**kwargs)
+    """Construct the Pallas TPU compiler params."""
+    return _pltpu.CompilerParams(**kwargs)

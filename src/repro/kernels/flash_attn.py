@@ -118,6 +118,7 @@ def flash_attention_rows(q, k, v, *, hper: int = 1, causal: bool = True,
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
 
 

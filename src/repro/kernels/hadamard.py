@@ -40,4 +40,5 @@ def block_hadamard(x: jax.Array, *, block: int = 128,
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="block_hadamard",
     )(x)

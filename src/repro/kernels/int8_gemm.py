@@ -78,4 +78,5 @@ def int8_matmul(x_q: jax.Array, w_q: jax.Array,
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="int8_matmul",
     )(x_q, w_q, x_scale, w_scale)

@@ -1,6 +1,6 @@
 """Public jit'd wrappers over the Pallas kernels with XLA fallbacks.
 
-Implementation dispatch:
+Implementation dispatch (`impl`, resolved by `resolve_impl`):
   * "pallas"           — compiled TPU kernels (the deployment path)
   * "pallas_interpret" — same kernel bodies executed in interpret mode
                          (CPU correctness validation; used by tests)
@@ -8,16 +8,18 @@ Implementation dispatch:
                          contract (see ref.py); used on CPU and for the
                          multi-pod dry-run, where XLA's int8 dot carries the
                          cost_analysis FLOPs/bytes for the roofline.
-  * "auto"             — "pallas" on TPU backends, else "xla".
+  * "auto" / None      — "pallas" on TPU backends, else "xla".
 
-Wrappers flatten leading batch dims to M, pad M to tile multiples, and fall
-back to "xla" whenever a dim is not kernel-aligned (K, N multiples of 128),
-so callers never have to think about tiling.
+Wrappers flatten leading batch dims to M and pad M to tile multiples. A
+kernel takes K, N in multiples of 128 (W4A8: of the group size): under
+"auto" a shape the kernel cannot take runs the XLA path, while an explicit
+"pallas" / "pallas_interpret" on such a shape raises instead of quietly
+running something other than what was asked for.
 """
 from __future__ import annotations
 
-import contextlib
-from typing import Optional
+import re
+from typing import Optional, Set
 
 import jax
 import jax.numpy as jnp
@@ -25,30 +27,41 @@ from jax.experimental import pallas as pl
 
 from repro.kernels import int8_gemm, w4a8_gemm, quantize_act, hadamard, ref
 
-_DEFAULT_IMPL = "auto"
-
-
-def set_default_impl(impl: str) -> None:
-    global _DEFAULT_IMPL
-    assert impl in ("auto", "pallas", "pallas_interpret", "xla")
-    _DEFAULT_IMPL = impl
-
-
-@contextlib.contextmanager
-def default_impl(impl: str):
-    prev = _DEFAULT_IMPL
-    set_default_impl(impl)
-    try:
-        yield
-    finally:
-        set_default_impl(prev)
+IMPLS = ("auto", "pallas", "pallas_interpret", "xla")
 
 
 def resolve_impl(impl: Optional[str]) -> str:
-    impl = impl or _DEFAULT_IMPL
+    """"auto" (or None) -> "pallas" on a TPU backend, "xla" elsewhere; any
+    other impl is returned as given."""
+    impl = impl or "auto"
+    if impl not in IMPLS:
+        raise ValueError(f"unknown impl {impl!r}; expected one of {IMPLS}")
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     return impl
+
+
+def _dispatch(impl: Optional[str], fits: bool, what: str) -> str:
+    """Resolve impl for one call whose shape the kernel can (fits) or
+    cannot take: "auto" falls back to "xla" on an unfit shape, an explicit
+    kernel impl raises."""
+    resolved = resolve_impl(impl)
+    if resolved == "xla" or fits:
+        return resolved
+    if (impl or "auto") == "auto":
+        return "xla"
+    raise ValueError(f"{what}: impl={resolved!r} requested, but the kernel "
+                     "cannot take this shape (see kernels/ops.py)")
+
+
+_TPU_CUSTOM_CALL = re.compile(
+    r'%([\w.-]+) = [^\n]*custom_call_target="tpu_custom_call"')
+
+
+def compiled_kernels(hlo_text: str) -> Set[str]:
+    """Names of the Pallas TPU kernels (their pallas_call `name=`) that a
+    compiled program's HLO text (`compiled.as_text()`) calls."""
+    return {m.split(".")[0] for m in _TPU_CUSTOM_CALL.findall(hlo_text)}
 
 
 def _aligned(*dims_mults) -> bool:
@@ -79,33 +92,35 @@ def _pad_m(x: jax.Array, mult: int):
 
 def paged_pool_scales(k_pages, k_scale, v_scale):
     """Normalize per-(page, head) scale inputs for the paged kernels:
-    quantized pools pass their real scales through; float pools get dummy
-    all-ones scales so one kernel signature serves all dtypes. `packed`
-    flags uint8 nibble pages (kv_bits=4) whose last dim is head_dim // 2 —
-    kernel bodies must shift-unpack before dequantizing. Returns
+    quantized pools pass their real (P, nkv) scales through; float pools
+    get dummy all-ones scales so one kernel signature serves all dtypes.
+    Scales come back as a (P, nkv, 1, 1) view, so that each grid step can
+    block one scale as (1, 1, 1, 1) — the last two dims equal the array's,
+    as the TPU (8, 128) block rule requires. `packed` flags uint8 nibble
+    pages (kv_bits=4) whose last dim is head_dim // 2 — kernel bodies must
+    shift-unpack before dequantizing. Returns
     (k_scale, v_scale, quantized, packed)."""
     quantized = k_pages.dtype in (jnp.int8, jnp.uint8)
     packed = k_pages.dtype == jnp.uint8
+    n_pages, nkv = k_pages.shape[:2]
     if not quantized:
-        n_pages, _, nkv, _ = k_pages.shape
         ones = jnp.ones((n_pages, nkv), jnp.float32)
         k_scale, v_scale = ones, ones
-    return k_scale, v_scale, quantized, packed
+    return (k_scale.reshape(n_pages, nkv, 1, 1),
+            v_scale.reshape(n_pages, nkv, 1, 1), quantized, packed)
 
 
 def paged_block_specs(w: int, page: int, hd: int):
     """(page-data, scale) BlockSpecs shared by the paged kernels on the
-    (B, n_kv_heads, W) grid: index_maps dereference the scalar-prefetched
+    (B, n_kv_heads, W) grid over head-major (P, nkv, page, hd) pages and
+    (P, nkv, 1, 1) scales: index_maps dereference the scalar-prefetched
     flat page table `pt`; `*_` absorbs the kernel-specific trailing
     prefetch refs (lengths, q_start, ...)."""
     def page_map(bi, h, j, pt, *_):
-        return (pt[bi * w + j], 0, h, 0)
+        return (pt[bi * w + j], h, 0, 0)
 
-    def scale_map(bi, h, j, pt, *_):
-        return (pt[bi * w + j], h)
-
-    return (pl.BlockSpec((1, page, 1, hd), page_map),
-            pl.BlockSpec((1, 1), scale_map))
+    return (pl.BlockSpec((1, 1, page, hd), page_map),
+            pl.BlockSpec((1, 1, 1, 1), page_map))
 
 
 # ---------------------------------------------------------------------------
@@ -115,12 +130,13 @@ def paged_block_specs(w: int, page: int, hd: int):
 def int8_matmul(x_q, w_q, x_scale, w_scale, *, out_dtype=jnp.float32,
                 impl: Optional[str] = None):
     """x_q (..., K) int8 @ w_q (K, N) int8 with fused dequant -> (..., N)."""
-    impl = resolve_impl(impl)
     x2, lead = _flatten_m(x_q)
     s2 = x_scale.reshape(x2.shape[0], 1)
     k, n = w_q.shape
     ws = w_scale.reshape(1, n)
-    if impl == "xla" or not _aligned((k, 128), (n, 128)):
+    impl = _dispatch(impl, _aligned((k, 128), (n, 128)),
+                     f"int8_matmul K={k} N={n}")
+    if impl == "xla":
         out = ref.int8_matmul_ref(x2, w_q, s2, ws, out_dtype)
     else:
         interp = impl == "pallas_interpret"
@@ -141,13 +157,14 @@ def int8_matmul(x_q, w_q, x_scale, w_scale, *, out_dtype=jnp.float32,
 
 def w4a8_matmul(x_q, w_packed, x_scale, w_group_scale, *, group_size: int,
                 out_dtype=jnp.float32, impl: Optional[str] = None):
-    impl = resolve_impl(impl)
     x2, lead = _flatten_m(x_q)
     s2 = x_scale.reshape(x2.shape[0], 1)
     kp, n = w_packed.shape
     k = kp * 2
-    if impl == "xla" or not _aligned((k, group_size), (n, 128)) \
-            or group_size % 2:
+    impl = _dispatch(impl, _aligned((k, group_size), (n, 128))
+                     and group_size % 2 == 0,
+                     f"w4a8_matmul K={k} N={n} group={group_size}")
+    if impl == "xla":
         out = ref.w4a8_matmul_ref(x2, w_packed, s2, w_group_scale,
                                   group_size, out_dtype)
     else:
@@ -172,12 +189,12 @@ def quantize_act_dynamic(x, smooth=None, gamma=None, *,
                          hadamard_block: int = 0, rmsnorm_eps: float = 0.0,
                          impl: Optional[str] = None):
     """x (..., K) -> (q int8 (..., K), scale f32 (..., 1))."""
-    impl = resolve_impl(impl)
     x2, lead = _flatten_m(x)
     k = x2.shape[1]
-    pallas_ok = _aligned((k, 128)) and (hadamard_block == 0
-                                        or k % hadamard_block == 0)
-    if impl == "xla" or not pallas_ok:
+    impl = _dispatch(impl, _aligned((k, 128)) and (
+        hadamard_block == 0 or k % hadamard_block == 0),
+        f"quantize_act_dynamic K={k} hadamard_block={hadamard_block}")
+    if impl == "xla":
         if rmsnorm_eps > 0.0 and gamma is not None:
             q, s = ref.fused_rmsnorm_quant_ref(x2, gamma, rmsnorm_eps, smooth)
             if hadamard_block:
@@ -199,10 +216,10 @@ def quantize_act_dynamic(x, smooth=None, gamma=None, *,
 # ---------------------------------------------------------------------------
 
 def block_hadamard(x, *, block: int = 128, impl: Optional[str] = None):
-    impl = resolve_impl(impl)
     x2, lead = _flatten_m(x)
     k = x2.shape[1]
-    if impl == "xla" or k % block != 0:
+    impl = _dispatch(impl, k % block == 0, f"block_hadamard K={k}")
+    if impl == "xla":
         out = ref.hadamard_ref(x2, block)
     else:
         interp = impl == "pallas_interpret"

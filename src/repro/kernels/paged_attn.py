@@ -10,10 +10,12 @@ scale. Online-softmax state (m, l, acc) lives in VMEM scratch across the
 page axis, exactly like flash_attn.py's KV-block loop.
 
 Grid: (B, n_kv_heads, n_pages) with pages innermost ("arbitrary" — the
-accumulators carry across it). GQA query heads of one KV head are processed
-together as a (hper, hd) block. Sequences shorter than the page-table width
-mask dead slots by position; fully-dead pages are skipped via pl.when (the
-DMA of the scratch page they point at is wasted bandwidth, not wrong).
+accumulators carry across it). Each step DMAs one (page, hd) tile of the
+head-major pool (kv_pool.py) and its (1, 1) scale. GQA query heads of one
+KV head are processed together as a (hper, hd) block. Sequences shorter
+than the page-table width mask dead slots by position; fully-dead pages are
+skipped via pl.when (the DMA of the scratch page they point at is wasted
+bandwidth, not wrong).
 """
 from __future__ import annotations
 
@@ -25,7 +27,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quant.qtypes import unpack_int4_halves_lastdim
-from repro.kernels import ops, tpu_compiler_params
+from repro.kernels import ops, ref, tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -47,15 +49,15 @@ def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
     @pl.when(j * page < klen)
     def _block():
         q = q_ref[0, 0].astype(jnp.float32) * scale          # (hper, hd)
-        k = k_ref[0, :, 0, :]                                # (page, hd[/2])
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0, 0]                                      # (page, hd[/2])
+        v = v_ref[0, 0]
         if packed:                 # in-register nibble unpack: (page, hd)
             k = unpack_int4_halves_lastdim(k)
             v = unpack_int4_halves_lastdim(v)
         k = k.astype(jnp.float32)
         v = v.astype(jnp.float32)
         if quantized:
-            k = k * ks_ref[0, 0]
+            k = k * ks_ref[0, 0]                             # (1, 1) scale
             v = v * vs_ref[0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -82,12 +84,12 @@ def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
 def paged_decode_attention(q, k_pages, v_pages, k_scale, v_scale,
                            page_table, kv_lengths, *,
                            interpret: bool = False):
-    """q: (B, nq, hd); k_pages/v_pages: (P, page, nkv, hd) int8/float or
-    (P, page, nkv, hd//2) uint8 packed int4; k_scale/v_scale: (P, nkv) f32
+    """q: (B, nq, hd); k_pages/v_pages: (P, nkv, page, hd) int8/float or
+    (P, nkv, page, hd//2) uint8 packed int4; k_scale/v_scale: (P, nkv) f32
     (quantized pools) or None; page_table: (B, W) physical page ids;
     kv_lengths: (B,) valid keys (>= 1). Returns (B, nq, hd) in q.dtype."""
     b, nq, hd = q.shape
-    n_pages, page, nkv, hd_kv = k_pages.shape      # hd_kv = hd//2 if packed
+    n_pages, nkv, page, hd_kv = k_pages.shape      # hd_kv = hd//2 if packed
     w = page_table.shape[1]
     hper = nq // nkv
     assert nq == nkv * hper, (nq, nkv)
@@ -126,6 +128,7 @@ def paged_decode_attention(q, k_pages, v_pages, k_scale, v_scale,
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode_attention",
     )(pt_flat, kv_lengths.astype(jnp.int32), qg, k_pages, v_pages,
       k_scale, v_scale)
     return out.reshape(b, nq, hd)
@@ -136,27 +139,17 @@ def paged_decode_attention_ref(q, k_pages, v_pages, k_scale, v_scale,
     """Pure-jnp oracle (and the XLA serving path on CPU): gather pages,
     dequantize, masked softmax. Same contract as the kernel."""
     b, nq, hd = q.shape
-    _, page, nkv, _ = k_pages.shape
-    w = page_table.shape[1]
+    nkv = k_pages.shape[1]
     hper = nq // nkv
-
-    def read(pages, scales):
-        g = pages[page_table]                          # (B, W, page, nkv, hd)
-        if g.dtype == jnp.uint8:                       # packed int4 pages
-            g = unpack_int4_halves_lastdim(g)
-        g = g.astype(jnp.float32)
-        if pages.dtype in (jnp.int8, jnp.uint8):
-            g = g * scales[page_table][:, :, None, :, None]
-        return g.reshape(b, w * page, nkv, hd)
-
-    k = read(k_pages, k_scale)
-    v = read(v_pages, v_scale)
+    k = ref._read_pages(k_pages, k_scale, page_table)   # (B, W*page, nkv, hd)
+    v = ref._read_pages(v_pages, v_scale, page_table)
+    t = k.shape[1]
     # GQA via an explicit group axis: materializing jnp.repeat'ed K/V
     # costs ~2x the attention itself on the XLA CPU path; the grouped
     # contraction is bitwise identical (same per-(query, key) dot)
     qg = (q.reshape(b, nkv, hper, hd).astype(jnp.float32) / (hd ** 0.5))
     scores = jnp.einsum("bgph,btgh->bgpt", qg, k)
-    mask = (jnp.arange(w * page)[None, None, None, :]
+    mask = (jnp.arange(t)[None, None, None, :]
             < kv_lengths[:, None, None, None])
     scores = jnp.where(mask, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)

@@ -37,8 +37,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quant.qtypes import unpack_int4_halves_lastdim
 from repro.kernels import ops, tpu_compiler_params
-from repro.kernels.ref import (  # noqa: F401  (oracles)
-    paged_prefill_attention_ref, paged_verify_attention_ref)
+from repro.kernels.ref import paged_prefill_attention_ref  # noqa: F401 oracle
 
 NEG_INF = -1e30
 
@@ -61,15 +60,15 @@ def _kernel(pt_ref, qstart_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
     @pl.when(j * page < klen)
     def _block():
         q = q_ref[0, 0].astype(jnp.float32) * scale      # (C*hper, hd)
-        k = k_ref[0, :, 0, :]                            # (page, hd[/2])
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0, 0]                                  # (page, hd[/2])
+        v = v_ref[0, 0]
         if packed:                 # in-register nibble unpack: (page, hd)
             k = unpack_int4_halves_lastdim(k)
             v = unpack_int4_halves_lastdim(v)
         k = k.astype(jnp.float32)
         v = v.astype(jnp.float32)
         if quantized:
-            k = k * ks_ref[0, 0]
+            k = k * ks_ref[0, 0]                         # (1, 1) scale
             v = v * vs_ref[0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -97,13 +96,13 @@ def paged_prefill_attention(q, k_pages, v_pages, k_scale, v_scale,
                             page_table, q_start, kv_lengths, *,
                             interpret: bool = False):
     """q: (B, C, nq, hd) chunk queries at positions q_start[b] + i;
-    k_pages/v_pages: (P, page, nkv, hd) int8/float or (P, page, nkv, hd//2)
+    k_pages/v_pages: (P, nkv, page, hd) int8/float or (P, nkv, page, hd//2)
     uint8 packed int4; k_scale/v_scale: (P, nkv) f32 (quantized pools) or
     None; page_table: (B, W) physical ids; q_start: (B,); kv_lengths: (B,)
     valid keys (>= 1). Returns (B, C, nq, hd) in q.dtype. Same contract as
     `ref.paged_prefill_attention_ref`."""
     b, c, nq, hd = q.shape
-    n_pages, page, nkv, hd_kv = k_pages.shape      # hd_kv = hd//2 if packed
+    n_pages, nkv, page, hd_kv = k_pages.shape      # hd_kv = hd//2 if packed
     w = page_table.shape[1]
     hper = nq // nkv
     assert nq == nkv * hper, (nq, nkv)
@@ -145,29 +144,9 @@ def paged_prefill_attention(q, k_pages, v_pages, k_scale, v_scale,
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_prefill_attention",
     )(pt_flat, q_start.astype(jnp.int32), kv_lengths.astype(jnp.int32),
       qg, k_pages, v_pages, k_scale, v_scale)
     return (out.reshape(b, nkv, c, hper, hd).transpose(0, 2, 1, 3, 4)
             .reshape(b, c, nq, hd))
 
-
-def paged_verify_attention(q, k_pages, v_pages, k_scale, v_scale,
-                           page_table, q_start, n_new, k_win, v_win, *,
-                           interpret: bool = False):
-    """Multi-query-per-sequence decode variant for speculative verify:
-    causal-masked chunk attention with the valid-key horizon pinned to the
-    draft window's end (kv_lengths = q_start + n_new) and the window's raw
-    K/V (k_win/v_win) spliced over the gathered keys, so the pool is never
-    written for a draft that may be rejected. C = k+1 need not be
-    page-aligned (window-sizing via kv_pool.verify_window_pages, not
-    chunk_window_pages).
-
-    The streaming Pallas chunk kernel reads pages only; feeding it the
-    in-flight window would need an extra VMEM operand (ROADMAP), so the
-    verify step currently runs the XLA gather path on every backend —
-    identical math, and the per-step cost is one page-table gather, same
-    as the kernel's contract. Contract: `ref.paged_verify_attention_ref`."""
-    del interpret  # no Pallas variant yet; XLA gather path on all backends
-    return paged_verify_attention_ref(q, k_pages, v_pages, k_scale, v_scale,
-                                      page_table, q_start, n_new,
-                                      k_win, v_win)

@@ -118,5 +118,6 @@ def quantize_act_dynamic(x: jax.Array, smooth=None, gamma=None, *,
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="quantize_act_dynamic",
     )(*args)
     return q, scale

@@ -118,7 +118,8 @@ def paged_prefill_attention_ref(q, k_pages, v_pages, k_scale, v_scale,
     `paged_prefill.py` is pinned to.
 
     q: (B, C, nq, hd) chunk queries, query i at absolute position
-    q_start[b] + i; k_pages/v_pages: (P, page, nkv, hd) int8 or float;
+    q_start[b] + i; k_pages/v_pages: (P, nkv, page, hd) int8 or float, or
+    (P, nkv, page, hd // 2) uint8 packed int4;
     k_scale/v_scale: (P, nkv) f32 per-(page, head) scales (int8 pools) or
     None; page_table: (B, W) physical page ids; q_start: (B,);
     kv_lengths: (B,) valid keys including the in-flight chunk (>= 1).
@@ -132,18 +133,13 @@ def paged_prefill_attention_ref(q, k_pages, v_pages, k_scale, v_scale,
 
 def _read_pages(pages, scales, page_table):
     """Gather + dequantize a page table's worth of KV: (B, W*page, nkv, hd)
-    f32. uint8 pages are packed int4 (two nibbles per byte along head_dim,
-    grouped halves) — shift-unpacked before the scale is applied, so hd
-    here is twice the stored last dim."""
-    b, w = page_table.shape
-    g = pages[page_table]                              # (B, W, page, nkv, .)
-    if g.dtype == jnp.uint8:
-        g = qtypes.unpack_int4_halves_lastdim(g)
-    g = g.astype(jnp.float32)
-    if pages.dtype in (jnp.int8, jnp.uint8):
-        g = g * scales[page_table][:, :, None, :, None]
-    _, _, page, nkv, hd = g.shape
-    return g.reshape(b, w * page, nkv, hd)
+    f32 (hd is twice the stored last dim for packed int4 pages), through
+    the pool's own `kv_pool.dequant_pages`."""
+    # Lazy import: repro.serving's package init reaches this module.
+    from repro.serving.kv_pool import dequant_pages
+    quantized = pages.dtype in (jnp.int8, jnp.uint8)
+    return dequant_pages(pages[page_table],
+                         scales[page_table] if quantized else None)
 
 
 def _chunk_attend(q, k, v, q_start, kv_lengths):
@@ -187,7 +183,7 @@ def paged_verify_attention_ref(q, k_pages, v_pages, k_scale, v_scale,
     one quantize-dequantize round trip, so verify logits can differ from
     the written-then-read chain within quantization noise."""
     c = q.shape[1]
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     kv_lengths = jnp.maximum(q_start + n_new, 1)
     # extend the *table* (not the gathered data) by enough pages that the
     # per-batch splice never clamps near the end of a full sequence

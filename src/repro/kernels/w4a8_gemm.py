@@ -10,7 +10,9 @@ Per-group dequantization: the K grid dimension steps one quantization group
 (bk == group_size) at a time; each group's int32 partial product is scaled
 by its (1, bn) float32 group scale and accumulated into a float32 VMEM
 accumulator, so cross-group accumulation is exact in fp32 (the contract
-`ref.w4a8_matmul_ref` checks).
+`ref.w4a8_matmul_ref` checks). The group-scale block is the whole
+(K // group_size, bn) column strip — a (1, bn) block would break the TPU
+(8, 128) rule on the second-minor dim — and each K step reads its own row.
 """
 from __future__ import annotations
 
@@ -21,6 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.quant.qtypes import int4_nibbles
 from repro.kernels import tpu_compiler_params
 
 
@@ -32,13 +35,11 @@ def _kernel(x_ref, wp_ref, xs_ref, gs_ref, o_ref, acc_ref):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # Unpack the (g/2, bn) packed tile -> (g, bn) int8 (values in [-8, 7]).
-    packed = wp_ref[...]
-    lo = jnp.right_shift(jnp.left_shift(packed, 4), 4)
-    hi = jnp.right_shift(packed, 4)
-    w_tile = jnp.concatenate([lo, hi], axis=0)          # 'halves' layout
+    lo, hi = int4_nibbles(wp_ref[...])
+    w_tile = jnp.concatenate([lo, hi], axis=0).astype(jnp.int8)  # 'halves'
 
     part = jnp.dot(x_ref[...], w_tile, preferred_element_type=jnp.int32)
-    acc_ref[...] += part.astype(jnp.float32) * gs_ref[...]
+    acc_ref[...] += part.astype(jnp.float32) * gs_ref[pl.ds(k, 1), :]
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _epilogue():
@@ -69,7 +70,7 @@ def w4a8_matmul(x_q: jax.Array, w_packed: jax.Array,
             pl.BlockSpec((bm, g), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((g // 2, bn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((bm, 1), lambda i, j, kk: (i, 0)),
-            pl.BlockSpec((1, bn), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((k // g, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
@@ -77,4 +78,5 @@ def w4a8_matmul(x_q: jax.Array, w_packed: jax.Array,
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="w4a8_matmul",
     )(x_q, w_packed, x_scale, w_group_scale)

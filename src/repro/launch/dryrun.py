@@ -331,8 +331,11 @@ def run_all(args) -> int:
         while idx < len(jobs) and len(running) < args.jobs:
             arch, shape, mp, cmd, ok = jobs[idx]
             idx += 1
+            # placeholder-device analysis: children stay on the CPU, so on
+            # a TPU host none of them takes the chip
             p = subprocess.Popen(cmd, stdout=subprocess.PIPE,
-                                 stderr=subprocess.STDOUT, text=True)
+                                 stderr=subprocess.STDOUT, text=True,
+                                 env={**os.environ, "JAX_PLATFORMS": "cpu"})
             running.append((p, arch, shape, mp))
         done = [r for r in running if r[0].poll() is not None]
         for r in done:

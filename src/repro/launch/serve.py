@@ -14,14 +14,16 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+from typing import Optional
 
 import jax
-import numpy as np
 
 from repro.checkpoint import Checkpointer
 from repro.configs import get_arch, reduced
 from repro.core.quant import calibrate, preset, ptq
 from repro.data import DataConfig, SyntheticLM, make_prompts
+from repro.kernels import ops
+from repro.launch.jax_cache import enable_compile_cache
 from repro.models import transformer
 from repro.serving import ContinuousBatchingEngine, ServingEngine
 
@@ -62,6 +64,38 @@ def _validate(ap: argparse.ArgumentParser, args) -> None:
             ap.error("--top-p must be in (0, 1]")
 
 
+def build_engine(cfg, *, quant: str = "fp16", seed: int = 0,
+                 ckpt_dir: Optional[str] = None, calib_batches: int = 4,
+                 calib_len: int = 32, engine: str = "continuous",
+                 **engine_kw):
+    """Init (or restore) weights, post-training-quantize them under the
+    `quant` preset (calibrated on `calib_batches` synthetic batches of
+    `calib_len` tokens), and build the serving engine — the one path from
+    weights to a serving engine, shared by `main` and chip_smoke.py.
+
+    `engine` picks ContinuousBatchingEngine ("continuous", which also takes
+    `seed` for its sampler key) or the legacy ServingEngine ("batch");
+    engine_kw go to its constructor. GEMM and paged-attention kernels
+    resolve "auto": Pallas on TPU, XLA elsewhere."""
+    params = transformer.init_params(jax.random.PRNGKey(seed), cfg)
+    if ckpt_dir:
+        params = Checkpointer(ckpt_dir).restore(params)
+        print(f"[serve] restored params from {ckpt_dir}")
+    qcfg = preset(quant)
+    if qcfg is not None:
+        dcfg = DataConfig(vocab=cfg.vocab, seq_len=calib_len, seed=seed + 1)
+        t0 = time.time()
+        stats = calibrate.collect_stats(
+            params, SyntheticLM(dcfg).batches(0, calib_batches, 4), cfg)
+        params = ptq.quantize_model(params, cfg, qcfg, stats)
+        print(f"[serve] PTQ ({quant}) done in {time.time() - t0:.1f}s; "
+              f"calibrated on {calib_batches} batches")
+    if engine == "continuous":
+        return ContinuousBatchingEngine(params, cfg, qcfg=qcfg, seed=seed,
+                                        **engine_kw)
+    return ServingEngine(params, cfg, qcfg=qcfg, **engine_kw)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -75,8 +109,9 @@ def main(argv=None):
     ap.add_argument("--page-size", type=int, default=16)
     ap.add_argument("--max-batch", type=int, default=8)
     ap.add_argument("--max-seq-len", type=int, default=256)
-    ap.add_argument("--paged-impl", default="xla",
-                    choices=["xla", "pallas", "pallas_interpret"])
+    ap.add_argument("--paged-impl", default="auto", choices=list(ops.IMPLS),
+                    help="paged-attention read path (auto: the Pallas "
+                    "kernel on TPU, the jnp oracle elsewhere)")
     ap.add_argument("--prefill-mode", default="chunked",
                     choices=["chunked", "legacy"],
                     help="chunked: batched mixed prefill/decode steps; "
@@ -116,27 +151,13 @@ def main(argv=None):
     args = ap.parse_args(argv)
     _validate(ap, args)
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
-    params = transformer.init_params(jax.random.PRNGKey(args.seed), cfg)
-    if args.ckpt_dir:
-        params = Checkpointer(args.ckpt_dir).restore(params)
-        print(f"[serve] restored params from {args.ckpt_dir}")
-
-    qcfg = preset(args.quant)
-    impl = None
-    if qcfg is not None:
-        dcfg = DataConfig(vocab=cfg.vocab, seq_len=max(32, args.prompt_len),
-                          seed=args.seed + 1)
-        data = SyntheticLM(dcfg)
-        t0 = time.time()
-        stats = calibrate.collect_stats(
-            params, data.batches(0, args.calib_batches, 4), cfg)
-        params = ptq.quantize_model(params, cfg, qcfg, stats)
-        impl = "xla"
-        print(f"[serve] PTQ ({args.quant}) done in {time.time() - t0:.1f}s; "
-              f"calibrated on {args.calib_batches} batches")
+    common = dict(quant=args.quant, seed=args.seed, ckpt_dir=args.ckpt_dir,
+                  calib_batches=args.calib_batches,
+                  calib_len=max(32, args.prompt_len), kv_bits=args.kv_bits)
 
     prompts = make_prompts(DataConfig(vocab=cfg.vocab, seq_len=64),
                            args.requests, args.prompt_len)
@@ -144,14 +165,14 @@ def main(argv=None):
         use_cache = (args.prefill_mode == "chunked"
                      if args.prefix_cache is None
                      else args.prefix_cache == "on")
-        eng = ContinuousBatchingEngine(
-            params, cfg, qcfg=qcfg, impl=impl, kv_bits=args.kv_bits,
+        eng = build_engine(
+            cfg, **common,
             page_size=args.page_size, max_batch=args.max_batch,
             max_seq_len=args.max_seq_len, paged_impl=args.paged_impl,
             prefill_mode=args.prefill_mode, chunk_pages=args.chunk_pages,
             token_budget=args.token_budget, prefix_cache=use_cache,
             spec_decode=args.spec_decode, sampler=args.sampler,
-            temperature=args.temperature, top_p=args.top_p, seed=args.seed)
+            temperature=args.temperature, top_p=args.top_p)
         mode = "slow_think" if args.mode == "all" else args.mode
         t0 = time.time()
         res = eng.run(prompts, mode=mode, max_new=args.max_new)
@@ -178,8 +199,7 @@ def main(argv=None):
             print(f"[serve] req {i}: {len(toks)} tokens: {toks[:16]}")
         return 0
 
-    eng = ServingEngine(params, cfg, qcfg=qcfg, impl=impl,
-                        kv_bits=args.kv_bits)
+    eng = build_engine(cfg, **common, engine="batch")
     t0 = time.time()
     if args.mode == "all":
         study = eng.cot_study(prompts, max_new=args.max_new,

@@ -157,11 +157,13 @@ def sdpa_causal(q, k, v, cfg, *, window: int = 0, lengths=None,
     if _USE_FLASH and lengths is None and t_offset == 0 \
             and q.shape[-1] % 8 == 0:
         # Deployment path: the Pallas flash kernel (scores never touch
-        # HBM). REPRO_FLASH=1 on TPU; interpret-mode execution elsewhere.
+        # HBM), resolved like every kernel: on TPU under "auto", the XLA
+        # path below elsewhere.
+        from repro.kernels import ops
         from repro.kernels.flash_attn import flash_attention
-        return flash_attention(
-            q, k, v, causal=True, window=window,
-            interpret=jax.default_backend() != "tpu").reshape(b, s, -1)
+        if ops.resolve_impl(None) == "pallas":
+            return flash_attention(q, k, v, causal=True,
+                                   window=window).reshape(b, s, -1)
     if b * h * s * t <= _SCORE_BUDGET:
         mask = causal_mask(s, t_offset=t_offset, window=window,
                            lengths=lengths, t=t)
@@ -353,22 +355,24 @@ def attn_decode(p, x, cfg, cache: dict, pos: jax.Array, *,
 
 def attn_decode_paged(p, x, cfg, pool: dict, page_table: jax.Array,
                       pos: jax.Array, *, qcfg: Optional[QuantConfig] = None,
-                      impl=None, paged_impl: str = "xla"):
+                      impl=None, paged_impl: Optional[str] = None):
     """Decode step against the paged (optionally int8) KV pool.
 
     x: (B, 1, d); pos: (B,) absolute write position (== tokens already in
     cache); pool: one block's page pool (serving/kv_pool.py layout);
-    page_table: (B, W) physical page ids. paged_impl selects the gather
-    path: "xla" (jnp gather oracle) or "pallas"/"pallas_interpret" (the
-    scalar-prefetch streaming kernel). Returns (out (B,1,d), pool)."""
+    page_table: (B, W) physical page ids. paged_impl selects the read path
+    as `ops.resolve_impl` resolves it: "xla" (jnp gather oracle) or
+    "pallas"/"pallas_interpret" (the scalar-prefetch streaming kernel);
+    "auto"/None takes the kernel on TPU. Returns (out (B,1,d), pool)."""
     # Lazy imports: repro.serving imports this module at package init.
-    from repro.kernels import paged_attn
+    from repro.kernels import ops, paged_attn
     from repro.serving import kv_pool
+    paged_impl = ops.resolve_impl(paged_impl)
     q, k, v = _qkv(p, x, cfg, pos[:, None], qcfg, impl, None, "")
     pool = kv_pool.write_token(pool, page_table, pos, k[:, 0], v[:, 0])
     kv_len = jnp.maximum(pos + 1, 1)      # dead slots attend scratch page 0
     ks, vs = pool.get("k_s"), pool.get("v_s")
-    if paged_impl in ("pallas", "pallas_interpret"):
+    if paged_impl != "xla":
         out = paged_attn.paged_decode_attention(
             q[:, 0], pool["k"], pool["v"], ks, vs, page_table, kv_len,
             interpret=paged_impl == "pallas_interpret")
@@ -384,7 +388,7 @@ def attn_prefill_chunk_paged(p, x, cfg, pool: dict, page_table: jax.Array,
                              window_rows: jax.Array, q_start: jax.Array,
                              n_new: jax.Array, *,
                              qcfg: Optional[QuantConfig] = None,
-                             impl=None, paged_impl: str = "xla"):
+                             impl=None, paged_impl: Optional[str] = None):
     """Mixed chunked-prefill/decode attention step against the paged pool.
 
     x: (B, C, d) chunk hidden states at absolute positions q_start[b] + i;
@@ -396,16 +400,17 @@ def attn_prefill_chunk_paged(p, x, cfg, pool: dict, page_table: jax.Array,
     (fused quantize-on-write — no dense cache), then the chunk queries
     attend causally over everything written so far, so intra-chunk
     attention sees the same (re-rounded) pages decode will. Returns
-    (out (B, C, d), pool)."""
-    from repro.kernels import paged_prefill
+    (out (B, C, d), pool). paged_impl resolves as in `attn_decode_paged`."""
+    from repro.kernels import ops, paged_prefill
     from repro.serving import kv_pool
+    paged_impl = ops.resolve_impl(paged_impl)
     b, c = x.shape[0], x.shape[1]
     positions = q_start[:, None] + jnp.arange(c)[None, :]
     q, k, v = _qkv(p, x, cfg, positions, qcfg, impl, None, "")
     pool = kv_pool.write_chunk(pool, k, v, window_rows, q_start, n_new)
     kv_len = jnp.maximum(q_start + n_new, 1)  # idle slots attend scratch
     ks, vs = pool.get("k_s"), pool.get("v_s")
-    if paged_impl in ("pallas", "pallas_interpret"):
+    if paged_impl != "xla":
         out = paged_prefill.paged_prefill_attention(
             q, pool["k"], pool["v"], ks, vs, page_table, q_start, kv_len,
             interpret=paged_impl == "pallas_interpret")
@@ -419,28 +424,30 @@ def attn_prefill_chunk_paged(p, x, cfg, pool: dict, page_table: jax.Array,
 
 def attn_verify_paged(p, x, cfg, pool: dict, page_table: jax.Array,
                       q_start: jax.Array, n_new: jax.Array, *,
-                      qcfg: Optional[QuantConfig] = None,
-                      impl=None, paged_impl: str = "xla"):
+                      qcfg: Optional[QuantConfig] = None, impl=None):
     """Speculative-verify attention step: score a k+1-token draft window
     in one pass against the paged pool, *without writing it*.
 
     Unlike `attn_prefill_chunk_paged` the window K/V never goes through
     the quantize-on-write path here — the raw projections are spliced
     over the gathered past keys inside the read
-    (`paged_verify_attention`), so a fully rejected draft leaves the pool
-    untouched and the engine commits only the accepted prefix afterwards
-    (`kv_pool.write_chunk`, or `kv_pool.truncate` when a window was
-    optimistically written). C = k+1 is not page-aligned. Returns
-    (out (B, C, d), (k, v)) — the raw window projections the commit
-    needs."""
-    from repro.kernels import paged_prefill
+    (`ref.paged_verify_attention_ref`), so a fully rejected draft leaves
+    the pool untouched and the engine commits only the accepted prefix
+    afterwards (`kv_pool.write_chunk`, or `kv_pool.truncate` when a window
+    was optimistically written). C = k+1 is not page-aligned.
+
+    There is no Pallas verify kernel: the streaming chunk kernel reads
+    pages only, and feeding it the in-flight window needs an extra VMEM
+    operand (ROADMAP). So verify runs this jnp gather path on every
+    backend. Returns (out (B, C, d), (k, v)) — the raw window projections
+    the commit needs."""
+    from repro.kernels import ref
     b, c = x.shape[0], x.shape[1]
     positions = q_start[:, None] + jnp.arange(c)[None, :]
     q, k, v = _qkv(p, x, cfg, positions, qcfg, impl, None, "")
     ks, vs = pool.get("k_s"), pool.get("v_s")
-    out = paged_prefill.paged_verify_attention(
-        q, pool["k"], pool["v"], ks, vs, page_table, q_start, n_new, k, v,
-        interpret=paged_impl == "pallas_interpret")
+    out = ref.paged_verify_attention_ref(
+        q, pool["k"], pool["v"], ks, vs, page_table, q_start, n_new, k, v)
     out = out.reshape(b, c, -1).astype(x.dtype)
     out = qlinear.apply(p["wo"], out, qcfg, impl)
     return out, (k, v)

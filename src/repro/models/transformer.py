@@ -493,7 +493,7 @@ def init_paged_pools(cfg, n_pages: int, page_size: int, kv_bits: int = 16,
 
 
 def decode_step_paged(params, pools, page_table, tokens, pos, cfg, *,
-                      qcfg=None, impl=None, paged_impl: str = "xla",
+                      qcfg=None, impl=None, paged_impl=None,
                       dtype=jnp.bfloat16):
     """One decode step against paged KV pools. tokens: (B,) int32; pos: (B,)
     absolute write positions (inactive slots: 0 with a scratch page-table
@@ -527,7 +527,7 @@ def decode_step_paged(params, pools, page_table, tokens, pos, cfg, *,
 
 def prefill_chunk_paged(params, pools, page_table, window_rows, tokens,
                         q_start, n_new, cfg, *, qcfg=None, impl=None,
-                        paged_impl: str = "xla", dtype=jnp.bfloat16):
+                        paged_impl=None, dtype=jnp.bfloat16):
     """One mixed chunked-prefill/decode step against paged KV pools — the
     single steady-state "mixed" compilation of the continuous-batching
     engine (C = chunk width is static; every step has the same shape, so
@@ -575,8 +575,7 @@ def prefill_chunk_paged(params, pools, page_table, window_rows, tokens,
 
 
 def verify_step_paged(params, pools, page_table, tokens, q_start, n_new,
-                      cfg, *, qcfg=None, impl=None, paged_impl: str = "xla",
-                      dtype=jnp.bfloat16):
+                      cfg, *, qcfg=None, impl=None, dtype=jnp.bfloat16):
     """One speculative-verify step: score all C = k+1 positions of each
     sequence's draft window in a single forward (multi-query decode with
     causal masking over the window), *read-only* on the pools.
@@ -604,7 +603,7 @@ def verify_step_paged(params, pools, page_table, tokens, q_start, n_new,
             h = rms_norm(x, p["ln1"]["g"], cfg.norm_eps)
             a, kv = attn.attn_verify_paged(
                 p["attn"], h, cfg, gpool[str(i)], page_table,
-                q_start, n_new, qcfg=qcfg, impl=impl, paged_impl=paged_impl)
+                q_start, n_new, qcfg=qcfg, impl=impl)
             x = x + a
             h = rms_norm(x, p["ln2"]["g"], cfg.norm_eps)
             if btype == "moe":

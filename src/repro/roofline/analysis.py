@@ -1,7 +1,7 @@
 """Roofline analysis from compiled dry-run artifacts.
 
-Three terms per (arch x shape x mesh), all in seconds-per-step on the v5e
-model in hw.py:
+Three terms per (arch x shape x mesh), all in seconds-per-step on the
+per-chip peaks of hw.py (v5e for the dry-run meshes):
 
   compute    = HLO FLOPs / peak            (int8 cells: linear-GEMM FLOPs at
                                             the int8 peak, rest at bf16)
@@ -162,12 +162,13 @@ def roofline_terms(*, hlo_flops_per_dev: float, hlo_bytes_per_dev: float,
                    link_bytes_per_dev: float, n_chips: int,
                    int8_linear_flops_global: float = 0.0) -> Dict:
     """All inputs per-device except int8_linear_flops_global (analytic,
-    divided by chips here)."""
+    divided by chips here). Peaks are v5e's: the dry-run's meshes are v5e."""
+    pk = hw.peaks(hw.V5E)
     int8_per_dev = min(int8_linear_flops_global / n_chips, hlo_flops_per_dev)
     bf16_per_dev = hlo_flops_per_dev - int8_per_dev
-    compute = bf16_per_dev / hw.PEAK_BF16 + int8_per_dev / hw.PEAK_INT8
-    memory = hlo_bytes_per_dev / hw.HBM_BW
-    collective = link_bytes_per_dev / hw.ICI_BW
+    compute = bf16_per_dev / pk.bf16_flops + int8_per_dev / pk.int8_ops
+    memory = hlo_bytes_per_dev / pk.hbm_bw
+    collective = link_bytes_per_dev / pk.ici_bw
     terms = {"compute_s": compute, "memory_s": memory,
              "collective_s": collective}
     dom = max(terms, key=terms.get)

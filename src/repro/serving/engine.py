@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import ops
 from repro.models import transformer
 from repro.serving import cot, sampling
 from repro.serving.draft import NgramDrafter
@@ -209,7 +210,7 @@ class ContinuousBatchingEngine:
                  page_size: int = 16, max_batch: int = 8,
                  max_seq_len: int = 256, n_pages: Optional[int] = None,
                  eos_id: Optional[int] = None, dtype=jnp.bfloat16,
-                 paged_impl: str = "xla", prefill_mode: str = "chunked",
+                 paged_impl: str = "auto", prefill_mode: str = "chunked",
                  chunk_pages: int = 2, token_budget: Optional[int] = None,
                  prefix_cache: bool = False, spec_decode: int = 0,
                  sampler: str = "greedy", temperature: float = 0.8,
@@ -227,6 +228,8 @@ class ContinuousBatchingEngine:
         self.cfg = cfg
         self.page_size = page_size
         self.eos_id = eos_id
+        # "auto": the paged Pallas kernels on TPU, the jnp oracle elsewhere
+        self.paged_impl = paged_impl = ops.resolve_impl(paged_impl)
         self.max_pages_per_seq = -(-max_seq_len // page_size)
         if n_pages is None:
             n_pages = 1 + max_batch * self.max_pages_per_seq
@@ -315,7 +318,7 @@ class ContinuousBatchingEngine:
             from repro.serving import kv_pool
             logits, kv_win = transformer.verify_step_paged(
                 params, pools, page_table, tokens, q_start, n_new, cfg,
-                qcfg=qcfg, impl=impl, paged_impl=paged_impl, dtype=dtype)
+                qcfg=qcfg, impl=impl, dtype=dtype)
             emit, acc = sampling.speculative_accept(
                 logits.astype(jnp.float32), tokens, n_new, key,
                 mode=self.sampler, temp=temperature, top_p=top_p)
@@ -363,6 +366,17 @@ class ContinuousBatchingEngine:
                 "mixed": self._mixed._cache_size(),
                 "decode": self._decode._cache_size(),
                 "verify": self._verify._cache_size()}
+
+    def lower_steps(self) -> Dict[str, "jax.stages.Lowered"]:
+        """The steady-state mixed and decode programs lowered at this
+        engine's shapes, from the arguments `step` builds (here with no
+        lane advancing), so that compiling them yields the programs serving
+        runs (which kernels they call, their memory)."""
+        mixed = self._mixed_args([], [])
+        return {"mixed": self._mixed.lower(
+                    self.params, self.pools, *map(jnp.asarray, mixed)),
+                "decode": self._decode.lower(
+                    self.params, self.pools, *self._decode_args())}
 
     def spec_stats(self) -> Dict[str, float]:
         """Cumulative speculative-decoding counters and acceptance rate
@@ -469,8 +483,7 @@ class ContinuousBatchingEngine:
         if not sched.active:
             return progressed
         logits, self.pools = self._decode(
-            self.params, self.pools, jnp.asarray(sched.page_table),
-            jnp.asarray(self._last_tok), jnp.asarray(sched.lengths))
+            self.params, self.pools, *self._decode_args())
         self.steps_run += 1
         nxt = self._sample_tokens(logits)
         for slot in list(sched.active):
@@ -518,14 +531,54 @@ class ContinuousBatchingEngine:
                 decoding = [s for s in decoding if s in sched.active]
                 return advancing, decoding
 
+    def _decode_args(self):
+        """The decode step's (page_table, tokens, pos) for every lane."""
+        sched = self.sched
+        return (jnp.asarray(sched.page_table), jnp.asarray(self._last_tok),
+                jnp.asarray(sched.lengths))
+
+    def _mixed_args(self, advancing, decoding):
+        """The mixed step's host arrays (page_table, windows, tokens,
+        q_start, n_new): the next prompt chunk of each advancing slot, one
+        token for each decoding slot, idle lanes n_new = 0."""
+        sched = self.sched
+        page = self.page_size
+        c, wc = self.chunk_tokens, self.window_pages
+        b = sched.n_slots
+        toks = np.zeros((b, c), np.int32)
+        q_start = np.zeros(b, np.int32)
+        n_new = np.zeros(b, np.int32)
+        windows = np.full((b, wc), SCRATCH_PAGE, np.int32)
+
+        def fill_window(slot, start):
+            pidx0 = start // page
+            row = sched.page_table[slot]
+            take = min(wc, row.shape[0] - pidx0)
+            windows[slot, :take] = row[pidx0:pidx0 + take]
+
+        for slot in advancing:
+            req = sched.active[slot]
+            prog = int(sched.prefill_progress[slot])
+            n = min(c, len(req.prompt) - prog)
+            toks[slot, :n] = req.prompt[prog:prog + n]
+            q_start[slot] = prog
+            n_new[slot] = n
+            fill_window(slot, prog)
+        for slot in decoding:
+            start = int(sched.lengths[slot])
+            toks[slot, 0] = self._last_tok[slot]
+            q_start[slot] = start
+            n_new[slot] = 1
+            fill_window(slot, start)
+        return sched.page_table, windows, toks, q_start, n_new
+
     def _step_chunked(self) -> bool:
         """Admit lazily (first chunk's pages only), then run one fixed-shape
         mixed step: prompt chunks for advancing prefill slots, one token for
         each decode slot, idle lanes masked out with n_new = 0."""
         sched = self.sched
-        page = self.page_size
-        c, wc = self.chunk_tokens, self.window_pages
-        progressed = bool(sched.admit(max_prefill_pages=c // page))
+        progressed = bool(sched.admit(
+            max_prefill_pages=self.chunk_tokens // self.page_size))
         if not sched.active:
             return progressed
         advancing, decoding = self._plan_chunked()
@@ -539,41 +592,13 @@ class ContinuousBatchingEngine:
                     return True
             # steady-state decode: same compiled program as legacy decode
             logits, self.pools = self._decode(
-                self.params, self.pools, jnp.asarray(sched.page_table),
-                jnp.asarray(self._last_tok), jnp.asarray(sched.lengths))
+                self.params, self.pools, *self._decode_args())
             self.steps_run += 1
         else:
-            b = sched.n_slots
-            toks = np.zeros((b, c), np.int32)
-            q_start = np.zeros(b, np.int32)
-            n_new = np.zeros(b, np.int32)
-            windows = np.full((b, wc), SCRATCH_PAGE, np.int32)
-
-            def fill_window(slot, start):
-                pidx0 = start // page
-                row = sched.page_table[slot]
-                take = min(wc, row.shape[0] - pidx0)
-                windows[slot, :take] = row[pidx0:pidx0 + take]
-
-            for slot in advancing:
-                req = sched.active[slot]
-                prog = int(sched.prefill_progress[slot])
-                n = min(c, len(req.prompt) - prog)
-                toks[slot, :n] = req.prompt[prog:prog + n]
-                q_start[slot] = prog
-                n_new[slot] = n
-                fill_window(slot, prog)
-            for slot in decoding:
-                start = int(sched.lengths[slot])
-                toks[slot, 0] = self._last_tok[slot]
-                q_start[slot] = start
-                n_new[slot] = 1
-                fill_window(slot, start)
-
+            args = self._mixed_args(advancing, decoding)
+            n_new = args[-1]
             logits, self.pools = self._mixed(
-                self.params, self.pools, jnp.asarray(sched.page_table),
-                jnp.asarray(windows), jnp.asarray(toks),
-                jnp.asarray(q_start), jnp.asarray(n_new))
+                self.params, self.pools, *map(jnp.asarray, args))
             self.mixed_steps += 1
 
         nxt = np.asarray(self._sample(logits))

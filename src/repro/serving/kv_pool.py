@@ -4,11 +4,19 @@ packed-int4 quantization (per-head scales) and free-list reuse.
 Layout per transformer block (leading scan-group axis G added by
 `transformer.init_paged_pools`):
 
-    k, v   : (P, page_size, n_kv_heads, head_dim)      int8 | cache dtype
-             (P, page_size, n_kv_heads, head_dim // 2) uint8, kv_bits=4:
+    k, v   : (P, n_kv_heads, page_size, head_dim)      int8 | cache dtype
+             (P, n_kv_heads, page_size, head_dim // 2) uint8, kv_bits=4:
              two nibbles per byte along head_dim in the grouped-halves
              layout (`qtypes.pack_int4_halves_lastdim`)
     k_s,v_s: (P, n_kv_heads) float32                   (quantized pools)
+
+Pages are head-major: one (page_size, head_dim) tile per (page, kv-head),
+so the paged Pallas kernels block a page as (1, 1, page_size, head_dim) —
+the last two block dims equal the array's, which the TPU (8, 128) block
+rule accepts for any page size. Token-major pages would put a 1 on the
+n_kv_heads axis, second-to-last in the block, which Mosaic rejects.
+Writes and oracle reads do their token-wise math on (page, nkv, hd) views
+and swap the two axes at the storage boundary.
 
 The leaf dtype is the discriminator — uint8 means packed int4, int8 means
 int8, floats mean an unquantized pool — so kernels and oracles that only
@@ -142,7 +150,7 @@ def init_pool(cfg, n_pages: int, page_size: int, kv_bits: int = 16,
               dtype=jnp.bfloat16) -> dict:
     assert kv_bits in (16, 8, 4), f"unsupported kv_bits {kv_bits}"
     nkv, hd = cfg.n_kv_heads, cfg.hd
-    shape = (n_pages, page_size, nkv, hd)
+    shape = (n_pages, nkv, page_size, hd)
     scales = {"k_s": jnp.zeros((n_pages, nkv), jnp.float32),
               "v_s": jnp.zeros((n_pages, nkv), jnp.float32)}
     if kv_bits == 8:
@@ -150,7 +158,7 @@ def init_pool(cfg, n_pages: int, page_size: int, kv_bits: int = 16,
                 "v": jnp.zeros(shape, jnp.int8), **scales}
     if kv_bits == 4:
         assert hd % 2 == 0, f"head_dim {hd} must be even for packed int4"
-        pshape = (n_pages, page_size, nkv, hd // 2)
+        pshape = (n_pages, nkv, page_size, hd // 2)
         return {"k": jnp.zeros(pshape, jnp.uint8),
                 "v": jnp.zeros(pshape, jnp.uint8), **scales}
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -158,6 +166,10 @@ def init_pool(cfg, n_pages: int, page_size: int, kv_bits: int = 16,
 
 def pool_is_quantized(pool: dict) -> bool:
     return pool["k"].dtype in (jnp.int8, jnp.uint8)
+
+
+def page_size_of(pool: dict) -> int:
+    return pool["k"].shape[-2]
 
 
 def pool_kv_bits(pool: dict) -> int:
@@ -175,32 +187,59 @@ def pool_bytes(pool: dict) -> int:
 
 def bytes_per_token(pool: dict) -> float:
     """Pool bytes per token *slot* (both K and V, incl. scale overhead)."""
-    n_pages, page = pool["k"].shape[0], pool["k"].shape[1]
-    return pool_bytes(pool) / (n_pages * page)
+    return pool_bytes(pool) / (pool["k"].shape[0] * page_size_of(pool))
 
 
 def _quantize_pages(x: jax.Array, bits: int = 8):
-    """x: (..., page, nkv, hd) -> (quantized pages, per (page, head) scale).
+    """x: (..., nkv, page, hd) head-major pages -> (quantized pages, per
+    (page, head) scale (..., nkv)).
 
     bits=8 yields int8 codes; bits=4 narrow-clips to [-7, 7] and packs two
     nibbles per byte along head_dim (uint8, grouped halves) so no dense
     intermediate wider than the packed page ever lands in the pool.
     """
-    am = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=(-3, -1))   # (..., nkv)
+    am = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=(-2, -1))   # (..., nkv)
     s = paper_scale(am, bits)
-    q = jnp.clip(jnp.round(x.astype(jnp.float32) / s[..., None, :, None]),
+    q = jnp.clip(jnp.round(x.astype(jnp.float32) / s[..., None, None]),
                  qmin(bits), qmax(bits)).astype(jnp.int8)
     if bits == 4:
         return pack_int4_halves_lastdim(q), s
     return q, s
 
 
-def _unpack_gathered(pages: jax.Array) -> jax.Array:
-    """Undo nibble packing on gathered pages (uint8 leaves only); int8 and
-    float pages pass through untouched."""
+def _to_pages(x: jax.Array, page: int) -> jax.Array:
+    """Token-major (B, W*page, nkv, hd) -> head-major pages
+    (B*W, nkv, page, hd), ready to scatter at flat page ids."""
+    b, t, nkv, hd = x.shape
+    return (x.reshape(b, t // page, page, nkv, hd).swapaxes(2, 3)
+            .reshape(-1, nkv, page, hd))
+
+
+def dequant_pages(pages, scales):
+    """Gathered head-major pages (B, W, nkv, page, hd[/2]) -> token-major
+    f32 (B, W*page, nkv, hd): nibble-unpack uint8 (packed int4) pages, apply
+    the per-(page, head) scales (B, W, nkv) when given (quantized pools;
+    None for float pools), and swap to token order. The one read path of
+    the pool's merge-on-write, `gather_kv` and the kernels' oracles."""
     if pages.dtype == jnp.uint8:
-        return unpack_int4_halves_lastdim(pages)
-    return pages
+        pages = unpack_int4_halves_lastdim(pages)
+    f = pages.astype(jnp.float32)
+    if scales is not None:
+        f = f * scales[..., None, None]
+    b, w, nkv, page, hd = f.shape
+    return f.swapaxes(2, 3).reshape(b, w * page, nkv, hd)
+
+
+def _store_pages(pool: dict, name: str, s_name: str, ids: jax.Array,
+                 pages: jax.Array, bits: int) -> None:
+    """Quantize (quantized pools) and scatter head-major f32 pages
+    (N, nkv, page, hd) into pool[name] at physical ids (N,)."""
+    if pool_is_quantized(pool):
+        q, sc = _quantize_pages(pages, bits)
+        pool[name] = pool[name].at[ids].set(q)
+        pool[s_name] = pool[s_name].at[ids].set(sc)
+    else:
+        pool[name] = pool[name].at[ids].set(pages.astype(pool[name].dtype))
 
 
 # -- prefill: bulk page fill -------------------------------------------------
@@ -214,29 +253,16 @@ def write_prefill(pool: dict, k: jax.Array, v: jax.Array,
     lengths: (B,) valid prompt tokens — positions beyond are zeroed so they
     can't inflate the page scale.
     """
-    page = pool["k"].shape[1]
-    b, s, nkv, hd = k.shape
+    page = page_size_of(pool)
+    s = k.shape[1]
     assert s % page == 0, (s, page)
     valid = (jnp.arange(s)[None, :] < lengths[:, None])[..., None, None]
-    kz = jnp.where(valid, k, 0).reshape(b, s // page, page, nkv, hd)
-    vz = jnp.where(valid, v, 0).reshape(b, s // page, page, nkv, hd)
     ids = page_rows.reshape(-1)
+    bits = pool_kv_bits(pool)
     pool = dict(pool)
-    if pool_is_quantized(pool):
-        bits = pool_kv_bits(pool)
-        kq, ks = _quantize_pages(kz, bits)
-        vq, vs = _quantize_pages(vz, bits)
-        # kq/vq last dim is hd//2 for packed int4 — reshape shape-generically
-        pool["k"] = pool["k"].at[ids].set(kq.reshape(-1, *kq.shape[2:]))
-        pool["v"] = pool["v"].at[ids].set(vq.reshape(-1, *vq.shape[2:]))
-        pool["k_s"] = pool["k_s"].at[ids].set(ks.reshape(-1, nkv))
-        pool["v_s"] = pool["v_s"].at[ids].set(vs.reshape(-1, nkv))
-    else:
-        dt = pool["k"].dtype
-        pool["k"] = pool["k"].at[ids].set(
-            kz.reshape(-1, page, nkv, hd).astype(dt))
-        pool["v"] = pool["v"].at[ids].set(
-            vz.reshape(-1, page, nkv, hd).astype(dt))
+    for name, s_name, x in (("k", "k_s", k), ("v", "v_s", v)):
+        pages = _to_pages(jnp.where(valid, x, 0).astype(jnp.float32), page)
+        _store_pages(pool, name, s_name, ids, pages, bits)
     return pool
 
 
@@ -275,8 +301,8 @@ def write_chunk(pool: dict, k: jax.Array, v: jax.Array,
     this to rebuild the window from its pre-speculation snapshot without a
     restore scatter + re-gather round trip.
     """
-    page = pool["k"].shape[1]
-    b, c, nkv, hd = k.shape
+    page = page_size_of(pool)
+    b, c = k.shape[:2]
     wc = window_rows.shape[1]
     assert wc * page >= c + page, (wc, page, c)
     wpos = jnp.arange(wc * page)[None, :]                     # window-local
@@ -292,27 +318,15 @@ def write_chunk(pool: dict, k: jax.Array, v: jax.Array,
     bits = pool_kv_bits(pool)
     pool = dict(pool)
     for name, s_name, tok in (("k", "k_s", k), ("v", "v_s", v)):
-        gathered = (src[name] if src is not None
-                    else pool[name][window_rows])
-        pages = _unpack_gathered(gathered).astype(jnp.float32)  # (B,Wc,p,..)
-        if quantized:
-            sc = (src[s_name] if src is not None
-                  else pool[s_name][window_rows])             # (B, Wc, nkv)
-            pages = pages * sc[:, :, None, :, None]
-        f = pages.reshape(b, wc * page, nkv, hd)
-        f = jnp.where(keep_old, f, 0.0)
+        leaves = src if src is not None else {
+            n: pool[n][window_rows] for n in (name, s_name) if n in pool}
+        f = dequant_pages(leaves[name],
+                          leaves[s_name] if quantized else None)
+        f = jnp.where(keep_old, f, 0.0)               # (B, Wc*page, nkv, hd)
         newv = jnp.take_along_axis(tok.astype(jnp.float32),
                                    jc[:, :, None, None], axis=1)
         f = jnp.where(use_new, newv, f)
-        f = f.reshape(b, wc, page, nkv, hd)
-        if quantized:
-            q, s = _quantize_pages(f, bits)
-            pool[name] = pool[name].at[ids].set(
-                q.reshape(-1, *q.shape[2:]))
-            pool[s_name] = pool[s_name].at[ids].set(s.reshape(-1, nkv))
-        else:
-            pool[name] = pool[name].at[ids].set(
-                f.reshape(-1, page, nkv, hd).astype(pool[name].dtype))
+        _store_pages(pool, name, s_name, ids, _to_pages(f, page), bits)
     return pool
 
 
@@ -348,10 +362,10 @@ def truncate(pool: dict, window_rows: jax.Array, snap: dict, k: jax.Array,
 # -- decode: one token per sequence ------------------------------------------
 
 def _requant_page(pages_f, new_tok, slot, bits=8):
-    """pages_f: (B, page, nkv, hd) f32 (already dequantized + masked);
+    """pages_f: (B, nkv, page, hd) f32 (already dequantized + masked);
     new_tok: (B, nkv, hd); slot: (B,) write slot. Returns (q, scale)."""
     b = pages_f.shape[0]
-    pages_f = pages_f.at[jnp.arange(b), slot].set(
+    pages_f = pages_f.at[jnp.arange(b), :, slot].set(
         new_tok.astype(jnp.float32))
     return _quantize_pages(pages_f, bits)
 
@@ -363,7 +377,7 @@ def write_token(pool: dict, page_table: jax.Array, pos: jax.Array,
     page_table: (B, W) physical ids; k, v: (B, nkv, hd). Inactive slots
     should carry pos=0 with a scratch-zeroed page-table row.
     """
-    page = pool["k"].shape[1]
+    page = page_size_of(pool)
     b = k.shape[0]
     pidx = pos // page
     slot = pos % page
@@ -373,21 +387,22 @@ def write_token(pool: dict, page_table: jax.Array, pos: jax.Array,
         # Gather page, dequantize, zero not-yet-written slots (pages are
         # reused without zeroing), extend, requantize per (page, head).
         bits = pool_kv_bits(pool)
-        live = jnp.arange(page)[None, :, None, None] <= slot[:, None, None,
+        live = jnp.arange(page)[None, None, :, None] <= slot[:, None, None,
                                                             None]
         for name, s_name, tok in (("k", "k_s", k), ("v", "v_s", v)):
-            pg = _unpack_gathered(pool[name][phys]).astype(
-                jnp.float32)                                    # (B,page,..)
+            pg = pool[name][phys]                               # (B,nkv,page,.)
+            if bits == 4:
+                pg = unpack_int4_halves_lastdim(pg)
+            pg = pg.astype(jnp.float32)
             sc = pool[s_name][phys]                             # (B,nkv)
-            pg = jnp.where(live, pg * sc[:, None, :, None], 0.0)
+            pg = jnp.where(live, pg * sc[:, :, None, None], 0.0)
             q, s_new = _requant_page(pg, tok, slot, bits)
             pool[name] = pool[name].at[phys].set(q)
             pool[s_name] = pool[s_name].at[phys].set(s_new)
     else:
         dt = pool["k"].dtype
-        idx = (phys, slot)
-        pool["k"] = pool["k"].at[idx].set(k.astype(dt))
-        pool["v"] = pool["v"].at[idx].set(v.astype(dt))
+        pool["k"] = pool["k"].at[phys, :, slot].set(k.astype(dt))
+        pool["v"] = pool["v"].at[phys, :, slot].set(v.astype(dt))
     return pool
 
 
@@ -396,14 +411,9 @@ def write_token(pool: dict, page_table: jax.Array, pos: jax.Array,
 def gather_kv(pool: dict, page_table: jax.Array):
     """Dequantized gather: (B, W*page, nkv, hd) bf16 pair — the XLA
     reference read path (the Pallas kernel streams pages instead)."""
-    page = pool["k"].shape[1]
-    b, w = page_table.shape
     out = []
     for name, s_name in (("k", "k_s"), ("v", "v_s")):
-        pages = _unpack_gathered(pool[name][page_table])  # (B,W,page,nkv,hd)
-        if pool_is_quantized(pool):
-            sc = pool[s_name][page_table]               # (B, W, nkv)
-            pages = pages.astype(jnp.float32) * sc[:, :, None, :, None]
-        out.append(pages.reshape(b, w * page, *pages.shape[3:])
+        sc = pool[s_name][page_table] if pool_is_quantized(pool) else None
+        out.append(dequant_pages(pool[name][page_table], sc)
                    .astype(jnp.bfloat16))
     return out[0], out[1]

@@ -22,10 +22,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-try:                                    # JAX >= 0.5 re-exports at top level
-    from jax import shard_map           # type: ignore[attr-defined]
-except ImportError:                     # JAX 0.4.x experimental spelling
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.quant.qtypes import qmax, qmin

@@ -15,6 +15,7 @@ import pytest
 from repro.analysis import (FLOW_RULES, RULES, TraceSpec, check_suite,
                             check_trace, lint_file, lint_paths)
 from repro.analysis.suite import default_specs
+from repro.core.quant import qtypes
 
 REPO = Path(__file__).resolve().parents[1]
 FIXTURES = REPO / "tests" / "fixtures" / "quantlint"
@@ -186,6 +187,18 @@ def test_flow_packed_int4_upcast():
     args = (_sds((8, 8), jnp.int8), _sds((8, 1), jnp.float32))
     spec = TraceSpec("fix_packed_ok", good, args, {0: "packed", 1: "scale"})
     assert check_trace(spec) == []
+
+    # widening to int32 ahead of the shifts (the kernel-safe unpack) is
+    # legal for int8 and uint8 storage alike
+    def widened(p, s):
+        lo, hi = qtypes.int4_nibbles(p)
+        return jnp.concatenate([lo, hi], -1).astype(jnp.float32) * s
+
+    for dt in (jnp.int8, jnp.uint8):
+        args = (_sds((8, 8), dt), _sds((8, 1), jnp.float32))
+        spec = TraceSpec("fix_packed_widened_ok", widened, args,
+                         {0: "packed", 1: "scale"})
+        assert check_trace(spec) == []
 
 
 def test_flow_nonlinear_on_unscaled():

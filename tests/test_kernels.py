@@ -87,15 +87,21 @@ def test_w4a8_pack_unpack_roundtrip_halves():
     np.testing.assert_array_equal(np.asarray(back), np.asarray(w4))
 
 
-def test_w4a8_wrapper_fallback_unaligned():
+def test_w4a8_wrapper_fallback_unaligned(monkeypatch):
     r = rng(5)
     x = r.integers(-127, 128, (10, 256)).astype(np.int8)   # M=10 unaligned
     w4 = r.integers(-8, 8, (256, 96)).astype(np.int8)      # N=96 unaligned
     wp = qtypes.pack_int4_halves(jnp.asarray(w4), 128)
     xs = r.uniform(0.001, 0.1, (10, 1)).astype(np.float32)
     gs = r.uniform(0.001, 0.1, (2, 96)).astype(np.float32)
-    got = ops.w4a8_matmul(x, wp, xs, gs, group_size=128, impl="pallas_interpret")
     want = ref.w4a8_matmul_ref(x, wp, xs, gs, 128)
+    # an explicit kernel request on a shape the kernel cannot take raises
+    with pytest.raises(ValueError, match="cannot take this shape"):
+        ops.w4a8_matmul(x, wp, xs, gs, group_size=128,
+                        impl="pallas_interpret")
+    # "auto" on a TPU backend falls back to the XLA path for that shape
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    got = ops.w4a8_matmul(x, wp, xs, gs, group_size=128, impl="auto")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5)
 
 

@@ -72,7 +72,7 @@ def test_roofline_terms_dominance():
                                 hlo_bytes_per_dev=1e9,
                                 link_bytes_per_dev=1e6, n_chips=256)
     assert t["dominant"] == "compute_s"
-    assert abs(t["compute_s"] - 1e12 / hw.PEAK_BF16) < 1e-9
+    assert abs(t["compute_s"] - 1e12 / hw.peaks(hw.V5E).bf16_flops) < 1e-9
     t2 = analysis.roofline_terms(hlo_flops_per_dev=1e9,
                                  hlo_bytes_per_dev=1e12,
                                  link_bytes_per_dev=1e6, n_chips=256)
@@ -85,7 +85,17 @@ def test_int8_split_peak():
                                 link_bytes_per_dev=0.0, n_chips=1,
                                 int8_linear_flops_global=2e12)
     # all flops at int8 peak
-    assert abs(t["compute_s"] - 2e12 / hw.PEAK_INT8) < 1e-9
+    assert abs(t["compute_s"] - 2e12 / hw.peaks(hw.V5E).int8_ops) < 1e-9
+
+
+def test_peaks_keyed_by_device_kind():
+    pk = hw.peaks("TPU v5 lite")
+    assert (pk.bf16_flops, pk.int8_ops, pk.hbm_bw, pk.ici_bw) == (
+        197e12, 393e12, 819e9, 50e9)
+    # a kind with no recorded peaks raises; it never borrows another's
+    for kind in ("cpu", "TPU v4"):
+        with pytest.raises(KeyError, match="no peaks recorded"):
+            hw.peaks(kind)
 
 
 def test_collective_ring_adjustments():

