@@ -184,6 +184,13 @@ def main(argv=None):
               f"{res.prefill_tokens} prompt tokens chunked, "
               f"{res.evictions} evictions, "
               f"KV {eng.kv_bytes_per_token():.0f} B/token")
+        tel = eng.telemetry.summary()
+        if tel["ttft_p50_ms"] is not None:
+            print(f"[serve] TTFT p50 {tel['ttft_p50_ms']:.1f} ms "
+                  f"(admission wait p50 {tel['admit_wait_p50_ms']:.1f} ms, "
+                  f"prefill p50 {tel['prefill_p50_ms']:.1f} ms); host "
+                  f"{tel['host_ms_per_step']:.2f} ms per step outside the "
+                  f"device wait, over {tel['steps']} steps")
         if args.spec_decode:
             st = eng.spec_stats()
             print(f"[serve] speculative: {res.spec_steps} verify steps, "

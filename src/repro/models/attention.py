@@ -369,16 +369,18 @@ def attn_decode_paged(p, x, cfg, pool: dict, page_table: jax.Array,
     from repro.serving import kv_pool
     paged_impl = ops.resolve_impl(paged_impl)
     q, k, v = _qkv(p, x, cfg, pos[:, None], qcfg, impl, None, "")
-    pool = kv_pool.write_token(pool, page_table, pos, k[:, 0], v[:, 0])
+    with jax.named_scope("kv_write"):
+        pool = kv_pool.write_token(pool, page_table, pos, k[:, 0], v[:, 0])
     kv_len = jnp.maximum(pos + 1, 1)      # dead slots attend scratch page 0
     ks, vs = pool.get("k_s"), pool.get("v_s")
-    if paged_impl != "xla":
-        out = paged_attn.paged_decode_attention(
-            q[:, 0], pool["k"], pool["v"], ks, vs, page_table, kv_len,
-            interpret=paged_impl == "pallas_interpret")
-    else:
-        out = paged_attn.paged_decode_attention_ref(
-            q[:, 0], pool["k"], pool["v"], ks, vs, page_table, kv_len)
+    with jax.named_scope("paged_attention"):
+        if paged_impl != "xla":
+            out = paged_attn.paged_decode_attention(
+                q[:, 0], pool["k"], pool["v"], ks, vs, page_table, kv_len,
+                interpret=paged_impl == "pallas_interpret")
+        else:
+            out = paged_attn.paged_decode_attention_ref(
+                q[:, 0], pool["k"], pool["v"], ks, vs, page_table, kv_len)
     out = out.reshape(x.shape[0], 1, -1).astype(x.dtype)
     out = qlinear.apply(p["wo"], out, qcfg, impl)
     return out, pool
@@ -407,16 +409,18 @@ def attn_prefill_chunk_paged(p, x, cfg, pool: dict, page_table: jax.Array,
     b, c = x.shape[0], x.shape[1]
     positions = q_start[:, None] + jnp.arange(c)[None, :]
     q, k, v = _qkv(p, x, cfg, positions, qcfg, impl, None, "")
-    pool = kv_pool.write_chunk(pool, k, v, window_rows, q_start, n_new)
+    with jax.named_scope("kv_write"):
+        pool = kv_pool.write_chunk(pool, k, v, window_rows, q_start, n_new)
     kv_len = jnp.maximum(q_start + n_new, 1)  # idle slots attend scratch
     ks, vs = pool.get("k_s"), pool.get("v_s")
-    if paged_impl != "xla":
-        out = paged_prefill.paged_prefill_attention(
-            q, pool["k"], pool["v"], ks, vs, page_table, q_start, kv_len,
-            interpret=paged_impl == "pallas_interpret")
-    else:
-        out = paged_prefill.paged_prefill_attention_ref(
-            q, pool["k"], pool["v"], ks, vs, page_table, q_start, kv_len)
+    with jax.named_scope("paged_attention"):
+        if paged_impl != "xla":
+            out = paged_prefill.paged_prefill_attention(
+                q, pool["k"], pool["v"], ks, vs, page_table, q_start, kv_len,
+                interpret=paged_impl == "pallas_interpret")
+        else:
+            out = paged_prefill.paged_prefill_attention_ref(
+                q, pool["k"], pool["v"], ks, vs, page_table, q_start, kv_len)
     out = out.reshape(b, c, -1).astype(x.dtype)
     out = qlinear.apply(p["wo"], out, qcfg, impl)
     return out, pool
@@ -446,8 +450,10 @@ def attn_verify_paged(p, x, cfg, pool: dict, page_table: jax.Array,
     positions = q_start[:, None] + jnp.arange(c)[None, :]
     q, k, v = _qkv(p, x, cfg, positions, qcfg, impl, None, "")
     ks, vs = pool.get("k_s"), pool.get("v_s")
-    out = ref.paged_verify_attention_ref(
-        q, pool["k"], pool["v"], ks, vs, page_table, q_start, n_new, k, v)
+    with jax.named_scope("paged_attention"):
+        out = ref.paged_verify_attention_ref(
+            q, pool["k"], pool["v"], ks, vs, page_table, q_start, n_new,
+            k, v)
     out = out.reshape(b, c, -1).astype(x.dtype)
     out = qlinear.apply(p["wo"], out, qcfg, impl)
     return out, (k, v)

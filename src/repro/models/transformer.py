@@ -492,36 +492,61 @@ def init_paged_pools(cfg, n_pages: int, page_size: int, kv_bits: int = 16,
     return pools
 
 
+# The paged step programs carry named scopes (embed; attention, with
+# kv_write and paged_attention inside it; mlp; lm_head), so that a profile
+# or a compiled program's op metadata names the part of the step an op
+# belongs to. Scopes are metadata only: they change no op.
+
+def _paged_embed(params, tokens, dtype):
+    with jax.named_scope("embed"):
+        return params["embed"]["w"].astype(dtype)[tokens]
+
+
+def _paged_layers(params, pools, x, cfg, attend, qcfg, impl):
+    """The blocks of a paged step, scanned over groups. `attend(p, h,
+    pool)` is the step's attention; it returns (out, aux), and the scan
+    returns x and each block's aux."""
+    def body(x, scanned):
+        gp, gpool = scanned
+        new = {}
+        for i, btype in enumerate(cfg.pattern):
+            p = gp[str(i)]
+            with jax.named_scope("attention"):
+                h = rms_norm(x, p["ln1"]["g"], cfg.norm_eps)
+                a, new[str(i)] = attend(p["attn"], h, gpool[str(i)])
+            x = x + a
+            with jax.named_scope("mlp"):
+                h = rms_norm(x, p["ln2"]["g"], cfg.norm_eps)
+                if btype == "moe":
+                    m, _ = moe_mod.moe_ffn(p["moe"], h, cfg, qcfg, impl)
+                else:
+                    m = mlp(p["mlp"], h, cfg.act, qcfg, impl)
+            x = x + m
+        return x, new
+
+    return jax.lax.scan(body, x, (params["blocks"], pools))
+
+
+def _paged_head(params, x, cfg):
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"]["g"], cfg.norm_eps)
+        return _lm_logits(params, x, cfg)
+
+
 def decode_step_paged(params, pools, page_table, tokens, pos, cfg, *,
                       qcfg=None, impl=None, paged_impl=None,
                       dtype=jnp.bfloat16):
     """One decode step against paged KV pools. tokens: (B,) int32; pos: (B,)
     absolute write positions (inactive slots: 0 with a scratch page-table
     row). Returns (logits (B,V) f32, pools)."""
-    x = params["embed"]["w"].astype(dtype)[tokens][:, None, :]
-
-    def body(x, scanned):
-        gp, gpool = scanned
-        new = {}
-        for i, btype in enumerate(cfg.pattern):
-            p = gp[str(i)]
-            h = rms_norm(x, p["ln1"]["g"], cfg.norm_eps)
-            a, pool = attn.attn_decode_paged(
-                p["attn"], h, cfg, gpool[str(i)], page_table, pos,
-                qcfg=qcfg, impl=impl, paged_impl=paged_impl)
-            x = x + a
-            h = rms_norm(x, p["ln2"]["g"], cfg.norm_eps)
-            if btype == "moe":
-                m, _ = moe_mod.moe_ffn(p["moe"], h, cfg, qcfg, impl)
-                x = x + m
-            else:
-                x = x + mlp(p["mlp"], h, cfg.act, qcfg, impl)
-            new[str(i)] = pool
-        return x, new
-
-    x, new_pools = jax.lax.scan(body, x, (params["blocks"], pools))
-    x = rms_norm(x, params["final_norm"]["g"], cfg.norm_eps)
-    logits = _lm_logits(params, x, cfg)[:, 0]
+    x = _paged_embed(params, tokens, dtype)[:, None, :]
+    x, new_pools = _paged_layers(
+        params, pools, x, cfg,
+        lambda p, h, pool: attn.attn_decode_paged(
+            p, h, cfg, pool, page_table, pos, qcfg=qcfg, impl=impl,
+            paged_impl=paged_impl),
+        qcfg, impl)
+    logits = _paged_head(params, x, cfg)[:, 0]
     return logits, new_pools
 
 
@@ -545,32 +570,18 @@ def prefill_chunk_paged(params, pools, page_table, window_rows, tokens,
     plus the in-flight chunk. Returns (logits (B, V) f32 at each slot's
     last valid token, pools)."""
     c = tokens.shape[1]
-    x = params["embed"]["w"].astype(dtype)[tokens]            # (B, C, d)
-
-    def body(x, scanned):
-        gp, gpool = scanned
-        new = {}
-        for i, btype in enumerate(cfg.pattern):
-            p = gp[str(i)]
-            h = rms_norm(x, p["ln1"]["g"], cfg.norm_eps)
-            a, pool = attn.attn_prefill_chunk_paged(
-                p["attn"], h, cfg, gpool[str(i)], page_table, window_rows,
-                q_start, n_new, qcfg=qcfg, impl=impl, paged_impl=paged_impl)
-            x = x + a
-            h = rms_norm(x, p["ln2"]["g"], cfg.norm_eps)
-            if btype == "moe":
-                m, _ = moe_mod.moe_ffn(p["moe"], h, cfg, qcfg, impl)
-                x = x + m
-            else:
-                x = x + mlp(p["mlp"], h, cfg.act, qcfg, impl)
-            new[str(i)] = pool
-        return x, new
-
-    x, new_pools = jax.lax.scan(body, x, (params["blocks"], pools))
-    x = rms_norm(x, params["final_norm"]["g"], cfg.norm_eps)
-    last = jnp.clip(n_new - 1, 0, c - 1)
-    x_last = x[jnp.arange(x.shape[0]), last]
-    logits = _lm_logits(params, x_last[:, None], cfg)[:, 0]
+    x = _paged_embed(params, tokens, dtype)                   # (B, C, d)
+    x, new_pools = _paged_layers(
+        params, pools, x, cfg,
+        lambda p, h, pool: attn.attn_prefill_chunk_paged(
+            p, h, cfg, pool, page_table, window_rows, q_start, n_new,
+            qcfg=qcfg, impl=impl, paged_impl=paged_impl),
+        qcfg, impl)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"]["g"], cfg.norm_eps)
+        last = jnp.clip(n_new - 1, 0, c - 1)
+        x_last = x[jnp.arange(x.shape[0]), last]
+        logits = _lm_logits(params, x_last[:, None], cfg)[:, 0]
     return logits, new_pools
 
 
@@ -593,30 +604,14 @@ def verify_step_paged(params, pools, page_table, tokens, q_start, n_new,
     window pages sized by `kv_pool.verify_window_pages` — C unaligned,
     unlike the prefill chunk). Returns (logits (B, C, V) f32 at *every*
     window position, kv_win = {block: (k, v) (G, B, C, nkv, hd)})."""
-    x = params["embed"]["w"].astype(dtype)[tokens]            # (B, C, d)
-
-    def body(x, scanned):
-        gp, gpool = scanned
-        kvs = {}
-        for i, btype in enumerate(cfg.pattern):
-            p = gp[str(i)]
-            h = rms_norm(x, p["ln1"]["g"], cfg.norm_eps)
-            a, kv = attn.attn_verify_paged(
-                p["attn"], h, cfg, gpool[str(i)], page_table,
-                q_start, n_new, qcfg=qcfg, impl=impl)
-            x = x + a
-            h = rms_norm(x, p["ln2"]["g"], cfg.norm_eps)
-            if btype == "moe":
-                m, _ = moe_mod.moe_ffn(p["moe"], h, cfg, qcfg, impl)
-                x = x + m
-            else:
-                x = x + mlp(p["mlp"], h, cfg.act, qcfg, impl)
-            kvs[str(i)] = kv
-        return x, kvs
-
-    x, kv_win = jax.lax.scan(body, x, (params["blocks"], pools))
-    x = rms_norm(x, params["final_norm"]["g"], cfg.norm_eps)
-    logits = _lm_logits(params, x, cfg)
+    x = _paged_embed(params, tokens, dtype)                   # (B, C, d)
+    x, kv_win = _paged_layers(
+        params, pools, x, cfg,
+        lambda p, h, pool: attn.attn_verify_paged(
+            p, h, cfg, pool, page_table, q_start, n_new, qcfg=qcfg,
+            impl=impl),
+        qcfg, impl)
+    logits = _paged_head(params, x, cfg)
     return logits, kv_win
 
 

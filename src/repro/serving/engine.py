@@ -41,7 +41,7 @@ import numpy as np
 
 from repro.kernels import ops
 from repro.models import transformer
-from repro.serving import cot, sampling
+from repro.serving import cot, sampling, telemetry
 from repro.serving.draft import NgramDrafter
 from repro.serving.kv_pool import (SCRATCH_PAGE, chunk_window_pages,
                                    verify_window_pages)
@@ -279,6 +279,7 @@ class ContinuousBatchingEngine:
         self._acc_est = 0.5                         # per-proposal EMA, optimistic
         self._last_tok = np.zeros(max_batch, np.int32)
         self._requests: Dict[int, Request] = {}
+        self.telemetry = telemetry.Recorder(self.sched)
         self._policies: Dict[int, cot.StopPolicy] = {}
         self._next_rid = 0
         self.steps_run = 0
@@ -293,24 +294,36 @@ class ContinuousBatchingEngine:
             partial(transformer.prefill, cfg=cfg, qcfg=qcfg, impl=impl,
                     kv_bits=16, dtype=dtype),
             static_argnames=("max_len",))
-        # The pool buffers are donated into every steady-state program:
-        # each step rewrites a page or two of multi-MB pools, and without
-        # input-output aliasing XLA copies every pool leaf per step. All
-        # call sites immediately rebind self.pools to the returned pools.
-        self._decode = jax.jit(
-            partial(transformer.decode_step_paged, cfg=cfg, qcfg=qcfg,
-                    impl=impl, paged_impl=paged_impl, dtype=dtype),
-            donate_argnums=(1,))
-        self._mixed = jax.jit(
-            partial(transformer.prefill_chunk_paged, cfg=cfg, qcfg=qcfg,
-                    impl=impl, paged_impl=paged_impl, dtype=dtype),
-            donate_argnums=(1,))
-        self._sample = jax.jit(lambda lg: jnp.argmax(lg, -1).astype(jnp.int32))
+        # The step programs are named (serve_decode, serve_mixed,
+        # serve_verify, serve_sample) so that traces and compile logs show
+        # which one ran. The pool buffers are donated into every
+        # steady-state program: each step rewrites a page or two of
+        # multi-MB pools, and without input-output aliasing XLA copies
+        # every pool leaf per step. All call sites immediately rebind
+        # self.pools to the returned pools.
+        def serve_decode(params, pools, page_table, tokens, pos):
+            return transformer.decode_step_paged(
+                params, pools, page_table, tokens, pos, cfg, qcfg=qcfg,
+                impl=impl, paged_impl=paged_impl, dtype=dtype)
+
+        def serve_mixed(params, pools, page_table, window_rows, tokens,
+                        q_start, n_new):
+            return transformer.prefill_chunk_paged(
+                params, pools, page_table, window_rows, tokens, q_start,
+                n_new, cfg, qcfg=qcfg, impl=impl, paged_impl=paged_impl,
+                dtype=dtype)
+
+        def serve_sample(logits):
+            return jnp.argmax(logits, -1).astype(jnp.int32)
+
+        self._decode = jax.jit(serve_decode, donate_argnums=(1,))
+        self._mixed = jax.jit(serve_mixed, donate_argnums=(1,))
+        self._sample = jax.jit(serve_sample)
         self._sample_t = jax.jit(partial(sampling.top_p, p=top_p,
                                          temp=temperature))
 
-        def verify_fn(params, pools, page_table, window_rows, tokens,
-                      q_start, n_new, key):
+        def serve_verify(params, pools, page_table, window_rows, tokens,
+                         q_start, n_new, key):
             # score the whole draft window read-only (the window's raw K/V
             # is spliced into the attention read, so a rejected suffix
             # never touches the pool), accept a prefix, then commit only
@@ -324,15 +337,16 @@ class ContinuousBatchingEngine:
                 mode=self.sampler, temp=temperature, top_p=top_p)
             n_keep = jnp.where(n_new > 0, acc + 1, 0)
             out_pools = {}
-            for i in pools:
-                kw, vw = kv_win[i]
-                out_pools[i] = jax.vmap(
-                    kv_pool.write_chunk,
-                    in_axes=(0, 0, 0, None, None, None))(
-                    pools[i], kw, vw, window_rows, q_start, n_keep)
+            with jax.named_scope("kv_write"):
+                for i in pools:
+                    kw, vw = kv_win[i]
+                    out_pools[i] = jax.vmap(
+                        kv_pool.write_chunk,
+                        in_axes=(0, 0, 0, None, None, None))(
+                        pools[i], kw, vw, window_rows, q_start, n_keep)
             return emit, acc, out_pools
 
-        self._verify = jax.jit(verify_fn, donate_argnums=(1,))
+        self._verify = jax.jit(serve_verify, donate_argnums=(1,))
         self._zero_key = jax.random.PRNGKey(0)
 
         def to_pages(pools, caches, page_rows, lengths):
@@ -417,40 +431,72 @@ class ContinuousBatchingEngine:
                 f"max_seq_len {cap}; raise max_seq_len or lower max_new")
         rid = self._next_rid
         self._next_rid += 1
-        req = Request(rid=rid, prompt=full, mode=mode, budget=budget)
+        req = Request(rid=rid, prompt=full, mode=mode, budget=budget,
+                      t_submit=telemetry.clock())
         self._requests[rid] = req
+        self.telemetry.track(req)
         self._policies[rid] = cot.policy_for(mode, len(full), max_new,
                                              eos_id=self.eos_id)
         self.sched.submit(req)
         return rid
 
-    def _prefill_one(self, slot: int, req: Request) -> None:
+    def request(self, rid: int) -> telemetry.RequestView:
+        """A frozen view of a request: the prompt as served, its tokens so
+        far, its times (submit, first admission, first token, done) and
+        counts (admissions, preemptions, prefill steps). A finished request
+        stays readable until `telemetry.REQUEST_RING` newer ones finish."""
+        return self.telemetry.request(rid)
+
+    def _emit(self, st, slot: int, req: Request, tok: int, t: float) -> None:
+        """Give `req`, in `slot`, its next token, read back by the
+        `serve.wait` that ended at `t`."""
+        req.out.append(tok)
+        self._last_tok[slot] = tok
+        if req.t_first is None:
+            req.t_first = t
+        if not st.emitted or st.emitted[-1] != req.rid:
+            st.emitted.append(req.rid)
+
+    def _complete(self, slot: int, t: float) -> None:
+        req = self.sched.complete(slot)
+        req.t_done = t
+        self.telemetry.finish(req)
+
+    def _prefill_one(self, st, slot: int, req: Request) -> None:
         """Legacy one-shot prefill, bucketed to the next power-of-two page
         count so the compile count is O(log max_seq_len) rather than one
         program per distinct prompt-page count."""
+        span = self.telemetry.span
         page = self.page_size
         n = len(req.prompt)
         need = -(-n // page)
         bucket_pages = 1 << (need - 1).bit_length()
         bucket = bucket_pages * page
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :n] = req.prompt
-        lens = jnp.asarray([n], jnp.int32)
-        logits, caches = self._prefill(
-            self.params, {"tokens": jnp.asarray(toks), "lengths": lens},
-            max_len=bucket)
-        # bucket rows beyond the prompt's allocation scatter into scratch
-        # (write_prefill zeroes positions >= lengths, so the writes are 0s)
-        rows = np.full((1, bucket_pages), SCRATCH_PAGE, np.int32)
-        rows[0, :need] = self.sched.page_table[slot, :need]
-        self.pools = self._to_pages(self.pools, caches, jnp.asarray(rows),
-                                    lens)
-        self.prefill_tokens += n
-        tok = int(self._sample_tokens(logits)[0])
-        req.out.append(tok)
-        self._last_tok[slot] = tok
-        if self._policies[req.rid].done(req.out):
-            self.sched.complete(slot)
+        with span("serve.inputs"):
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, :n] = req.prompt
+            lens = jnp.asarray([n], jnp.int32)
+            toks = jnp.asarray(toks)
+            # bucket rows beyond the prompt's allocation scatter into
+            # scratch (write_prefill zeroes positions >= lengths, so the
+            # writes are 0s)
+            rows = np.full((1, bucket_pages), SCRATCH_PAGE, np.int32)
+            rows[0, :need] = self.sched.page_table[slot, :need]
+            rows = jnp.asarray(rows)
+        with span("serve.launch"):
+            logits, caches = self._prefill(
+                self.params, {"tokens": toks, "lengths": lens},
+                max_len=bucket)
+            self.pools = self._to_pages(self.pools, caches, rows, lens)
+        st.ran("mixed", [(slot, 0, n)], bucket)
+        with span("serve.wait") as wait:
+            tok = int(self._sample_tokens(logits)[0])
+        with span("serve.commit"):
+            self.prefill_tokens += n
+            req.prefill_steps += 1
+            self._emit(st, slot, req, tok, wait.t1)
+            if self._policies[req.rid].done(req.out):
+                self._complete(slot, wait.t1)
 
     def _sample_tokens(self, logits) -> np.ndarray:
         """Sample next tokens per lane under the engine's sampler: greedy
@@ -463,38 +509,47 @@ class ContinuousBatchingEngine:
 
     def step(self) -> bool:
         """One engine step. Returns whether any progress was made."""
-        if self.prefill_mode == "legacy":
-            return self._step_legacy()
-        return self._step_chunked()
+        with self.telemetry.step() as st:
+            if self.prefill_mode == "legacy":
+                return self._step_legacy(st)
+            return self._step_chunked(st)
 
-    def _step_legacy(self) -> bool:
+    def _step_legacy(self, st) -> bool:
         """Admit + one-shot prefill per admission, then one batched decode."""
         sched = self.sched
+        span = self.telemetry.span
         progressed = False
         while True:
             # re-admit after prefill-time completions free their slots
-            admitted = sched.admit()
+            with span("serve.admit"):
+                admitted = sched.admit()
             if not admitted:
                 break
             progressed = True
             for slot, req in admitted:
-                self._prefill_one(slot, req)
-        sched.ensure_decode_capacity()
+                self._prefill_one(st, slot, req)
+        with span("serve.plan"):
+            sched.ensure_decode_capacity()
         if not sched.active:
             return progressed
-        logits, self.pools = self._decode(
-            self.params, self.pools, *self._decode_args())
+        with span("serve.inputs"):
+            args = self._decode_args()
+        with span("serve.launch"):
+            logits, self.pools = self._decode(self.params, self.pools, *args)
         self.steps_run += 1
-        nxt = self._sample_tokens(logits)
-        for slot in list(sched.active):
-            req = sched.active[slot]
-            sched.lengths[slot] += 1
-            tok = int(nxt[slot])
-            req.out.append(tok)
-            self._last_tok[slot] = tok
-            self.decode_tokens += 1
-            if self._policies[req.rid].done(req.out):
-                sched.complete(slot)
+        st.ran("mixed" if st.lanes else "decode",
+               [(s, int(sched.lengths[s]), 1) for s in sorted(sched.active)],
+               sched.n_slots)
+        with span("serve.wait") as wait:
+            nxt = self._sample_tokens(logits)
+        with span("serve.commit"):
+            for slot in list(sched.active):
+                req = sched.active[slot]
+                sched.lengths[slot] += 1
+                self._emit(st, slot, req, int(nxt[slot]), wait.t1)
+                self.decode_tokens += 1
+                if self._policies[req.rid].done(req.out):
+                    self._complete(slot, wait.t1)
         return True
 
     # -- chunked prefill ------------------------------------------------------
@@ -572,64 +627,80 @@ class ContinuousBatchingEngine:
             fill_window(slot, start)
         return sched.page_table, windows, toks, q_start, n_new
 
-    def _step_chunked(self) -> bool:
+    def _step_chunked(self, st) -> bool:
         """Admit lazily (first chunk's pages only), then run one fixed-shape
         mixed step: prompt chunks for advancing prefill slots, one token for
         each decode slot, idle lanes masked out with n_new = 0."""
         sched = self.sched
-        progressed = bool(sched.admit(
-            max_prefill_pages=self.chunk_tokens // self.page_size))
+        span = self.telemetry.span
+        with span("serve.admit"):
+            progressed = bool(sched.admit(
+                max_prefill_pages=self.chunk_tokens // self.page_size))
         if not sched.active:
             return progressed
-        advancing, decoding = self._plan_chunked()
+        with span("serve.plan"):
+            advancing, decoding = self._plan_chunked()
 
+        b = sched.n_slots
         if not advancing:
             # pure-decode steady state: speculate when enabled and warm
             if self.spec_k and decoding:
                 if self._spec_off > 0:
                     self._spec_off -= 1
-                elif self._try_spec_step(decoding):
+                elif self._try_spec_step(st, decoding):
                     return True
             # steady-state decode: same compiled program as legacy decode
-            logits, self.pools = self._decode(
-                self.params, self.pools, *self._decode_args())
+            with span("serve.inputs"):
+                args = self._decode_args()
+            with span("serve.launch"):
+                logits, self.pools = self._decode(self.params, self.pools,
+                                                  *args)
             self.steps_run += 1
+            st.ran("decode", [(s, int(sched.lengths[s]), 1)
+                              for s in decoding], b)
         else:
-            args = self._mixed_args(advancing, decoding)
-            n_new = args[-1]
-            logits, self.pools = self._mixed(
-                self.params, self.pools, *map(jnp.asarray, args))
+            with span("serve.inputs"):
+                args = self._mixed_args(advancing, decoding)
+                n_new = args[-1]
+                dev_args = [jnp.asarray(a) for a in args]
+            with span("serve.launch"):
+                logits, self.pools = self._mixed(self.params, self.pools,
+                                                 *dev_args)
             self.mixed_steps += 1
+            q_start = args[-2]
+            st.ran("mixed", [(s, int(q_start[s]), int(n_new[s]))
+                             for s in range(b) if n_new[s] > 0],
+                   b * self.chunk_tokens)
 
-        nxt = np.asarray(self._sample(logits))
-        for slot in advancing:
-            req = sched.active[slot]
-            n = int(n_new[slot])
-            sched.prefill_progress[slot] += n
-            sched.lengths[slot] += n
-            self.prefill_tokens += n
-            if int(sched.prefill_progress[slot]) == len(req.prompt):
-                # prompt fully in cache: logits at its last token yield the
-                # first generated token (as legacy prefill does)
-                tok = int(nxt[slot])
-                req.out.append(tok)
-                self._last_tok[slot] = tok
+        with span("serve.wait") as wait:
+            nxt = np.asarray(self._sample(logits))
+        t = wait.t1
+        with span("serve.commit"):
+            for slot in advancing:
+                req = sched.active[slot]
+                n = int(n_new[slot])
+                sched.prefill_progress[slot] += n
+                sched.lengths[slot] += n
+                self.prefill_tokens += n
+                req.prefill_steps += 1
+                if int(sched.prefill_progress[slot]) == len(req.prompt):
+                    # prompt fully in cache: logits at its last token yield
+                    # the first generated token (as legacy prefill does)
+                    self._emit(st, slot, req, int(nxt[slot]), t)
+                    if self._policies[req.rid].done(req.out):
+                        self._complete(slot, t)
+            for slot in decoding:
+                req = sched.active[slot]
+                sched.lengths[slot] += 1
+                self._emit(st, slot, req, int(nxt[slot]), t)
+                self.decode_tokens += 1
                 if self._policies[req.rid].done(req.out):
-                    sched.complete(slot)
-        for slot in decoding:
-            req = sched.active[slot]
-            sched.lengths[slot] += 1
-            tok = int(nxt[slot])
-            req.out.append(tok)
-            self._last_tok[slot] = tok
-            self.decode_tokens += 1
-            if self._policies[req.rid].done(req.out):
-                sched.complete(slot)
+                    self._complete(slot, t)
         return True
 
     # -- speculative decoding -------------------------------------------------
 
-    def _try_spec_step(self, decoding: List[int]) -> bool:
+    def _try_spec_step(self, st, decoding: List[int]) -> bool:
         """One speculative verify step over the pure-decode lanes: draft up
         to k tokens per lane by prompt lookup, score the k+1-token windows
         read-only in the single jitted verify program, and commit each
@@ -646,22 +717,24 @@ class ContinuousBatchingEngine:
         n_new = 1, which is bit-exact with a vanilla decode write
         (write_chunk with one token == write_token)."""
         sched = self.sched
+        span = self.telemetry.span
         page = self.page_size
         cap = self.max_pages_per_seq * page
         cs, wcv = self.spec_tokens, self.spec_window_pages
 
         drafts: Dict[int, List[int]] = {}
-        for slot in decoding:
-            req = sched.active[slot]
-            length = int(sched.lengths[slot])
-            budget_left = self._policies[req.rid].budget - len(req.out)
-            # the pending token costs one cache slot and one budget slot;
-            # clamp so accept-all can neither overrun the sequence cap nor
-            # outlive the stop policy's budget
-            room = min(self.spec_k, budget_left - 1, cap - length - 1)
-            drafts[slot] = (self._drafter.propose(
-                list(req.prompt) + list(req.out), k=room)
-                if room >= 1 else [])
+        with span("serve.draft"):
+            for slot in decoding:
+                req = sched.active[slot]
+                length = int(sched.lengths[slot])
+                budget_left = self._policies[req.rid].budget - len(req.out)
+                # the pending token costs one cache slot and one budget
+                # slot; clamp so accept-all can neither overrun the
+                # sequence cap nor outlive the stop policy's budget
+                room = min(self.spec_k, budget_left - 1, cap - length - 1)
+                drafts[slot] = (self._drafter.propose(
+                    list(req.prompt) + list(req.out), k=room)
+                    if room >= 1 else [])
         total = sum(len(d) for d in drafts.values())
         if total * self._acc_est < self.spec_gate * len(decoding):
             # expected extra tokens don't cover the verify's cost premium
@@ -683,16 +756,18 @@ class ContinuousBatchingEngine:
         # growth can preempt lanes (including a drafting lane itself) —
         # replan until a pass allocates without evicting
         try:
-            while True:
-                evicted = False
-                for slot in decoding:
-                    if slot not in sched.active:
-                        continue
-                    target = int(sched.lengths[slot]) + 1 + len(drafts[slot])
-                    if sched.grow_to(slot, target):
-                        evicted = True
-                if not evicted:
-                    break
+            with span("serve.plan"):
+                while True:
+                    evicted = False
+                    for slot in decoding:
+                        if slot not in sched.active:
+                            continue
+                        target = (int(sched.lengths[slot]) + 1
+                                  + len(drafts[slot]))
+                        if sched.grow_to(slot, target):
+                            evicted = True
+                    if not evicted:
+                        break
         except RuntimeError:
             # pool too tight for even one lane's window — surplus pages
             # already granted stay with their lanes (reused by later
@@ -704,55 +779,56 @@ class ContinuousBatchingEngine:
             return False
 
         b = sched.n_slots
-        toks = np.zeros((b, cs), np.int32)
-        q_start = np.zeros(b, np.int32)
-        n_new = np.zeros(b, np.int32)
-        windows = np.full((b, wcv), SCRATCH_PAGE, np.int32)
-        for slot in decoding:
-            d = drafts[slot]
-            start = int(sched.lengths[slot])
-            toks[slot, 0] = self._last_tok[slot]
-            toks[slot, 1:1 + len(d)] = d
-            q_start[slot] = start
-            n_new[slot] = 1 + len(d)
-            pidx0 = start // page
-            row = sched.page_table[slot]
-            take = min(wcv, row.shape[0] - pidx0)
-            windows[slot, :take] = row[pidx0:pidx0 + take]
-
-        if self.sampler == "greedy":
-            key = self._zero_key
-        else:
-            self._key, key = jax.random.split(self._key)
-        emit, acc, self.pools = self._verify(
-            self.params, self.pools, jnp.asarray(sched.page_table),
-            jnp.asarray(windows), jnp.asarray(toks), jnp.asarray(q_start),
-            jnp.asarray(n_new), key)
-        emit, acc = np.asarray(emit), np.asarray(acc)
+        with span("serve.inputs"):
+            toks = np.zeros((b, cs), np.int32)
+            q_start = np.zeros(b, np.int32)
+            n_new = np.zeros(b, np.int32)
+            windows = np.full((b, wcv), SCRATCH_PAGE, np.int32)
+            for slot in decoding:
+                d = drafts[slot]
+                start = int(sched.lengths[slot])
+                toks[slot, 0] = self._last_tok[slot]
+                toks[slot, 1:1 + len(d)] = d
+                q_start[slot] = start
+                n_new[slot] = 1 + len(d)
+                pidx0 = start // page
+                row = sched.page_table[slot]
+                take = min(wcv, row.shape[0] - pidx0)
+                windows[slot, :take] = row[pidx0:pidx0 + take]
+            if self.sampler == "greedy":
+                key = self._zero_key
+            else:
+                self._key, key = jax.random.split(self._key)
+            dev_args = [jnp.asarray(a) for a in (sched.page_table, windows,
+                                                 toks, q_start, n_new)]
+        with span("serve.launch"):
+            emit, acc, self.pools = self._verify(self.params, self.pools,
+                                                 *dev_args, key)
         self.spec_steps += 1
+        st.ran("verify", [(s, int(q_start[s]), int(n_new[s]))
+                          for s in decoding], b * cs)
+        with span("serve.wait") as wait:
+            emit, acc = np.asarray(emit), np.asarray(acc)
+        t = wait.t1
 
         step_scored = step_accepted = 0
-        for slot in decoding:
-            req = sched.active[slot]
-            a = int(acc[slot])
-            self.draft_tokens += int(n_new[slot]) - 1
-            self.accepted_tokens += a
-            step_scored += int(n_new[slot]) - 1
-            step_accepted += a
-            new_len = int(q_start[slot]) + 1 + a
-            sched.lengths[slot] = new_len
-            sched.truncate_to(slot, new_len)
-            done = False
-            for j in range(a + 1):
-                tok = int(emit[slot, j])
-                req.out.append(tok)
-                self.decode_tokens += 1
-                self._last_tok[slot] = tok
-                if self._policies[req.rid].done(req.out):
-                    done = True
-                    break
-            if done:
-                sched.complete(slot)
+        with span("serve.commit"):
+            for slot in decoding:
+                req = sched.active[slot]
+                a = int(acc[slot])
+                self.draft_tokens += int(n_new[slot]) - 1
+                self.accepted_tokens += a
+                step_scored += int(n_new[slot]) - 1
+                step_accepted += a
+                new_len = int(q_start[slot]) + 1 + a
+                sched.lengths[slot] = new_len
+                sched.truncate_to(slot, new_len)
+                for j in range(a + 1):
+                    self._emit(st, slot, req, int(emit[slot, j]), t)
+                    self.decode_tokens += 1
+                    if self._policies[req.rid].done(req.out):
+                        self._complete(slot, t)
+                        break
         # per-proposal acceptance EMA feeding the gate; the 0.2 floor
         # keeps a cold streak from pinning the gate shut forever (the
         # doubling cooldown, not the EMA, owns long-horizon backoff)

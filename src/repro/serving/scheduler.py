@@ -49,6 +49,7 @@ import numpy as np
 
 from repro.serving.kv_pool import PageAllocator, SCRATCH_PAGE
 from repro.serving.prefix_cache import PrefixCache, page_hashes
+from repro.serving.telemetry import clock
 
 
 @dataclasses.dataclass
@@ -61,6 +62,13 @@ class Request:
     budget: int
     out: List[int] = dataclasses.field(default_factory=list)
     preemptions: int = 0
+    # times on telemetry.clock, read by the engine's recorder
+    t_submit: float = 0.0
+    t_admit: Optional[float] = None      # first admission
+    t_first: Optional[float] = None      # first token (kept across preemption)
+    t_done: Optional[float] = None
+    n_admits: int = 0
+    prefill_steps: int = 0               # steps that wrote part of the prompt
 
 
 class PagedScheduler:
@@ -175,6 +183,9 @@ class PagedScheduler:
                 self.prefix_hit_tokens += hit_tokens
                 self.prefix_prompt_tokens += len(req.prompt)
             self.active[slot] = req
+            if req.t_admit is None:
+                req.t_admit = clock()
+            req.n_admits += 1
             self._admit_order[slot] = self._admit_seq
             self._admit_seq += 1
             admitted.append((slot, req))
