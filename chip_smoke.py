@@ -43,6 +43,11 @@ SEED = 0
 SETTINGS = (("bf16", 16), ("int8", 8), ("w4a8", 4))   # (weights, kv_bits)
 LANES, PAGE, CHUNK_PAGES = 8, 16, 2
 PROMPT, SHARED, MAX_NEW, MAX_SEQ = 256, 128, 32, 320
+# Kernel parity's own table and lanes' key counts: one key, one page, a
+# compute block (256 keys), one page past it, the whole table, and ragged
+# counts in each of the three blocks.
+PARITY_COLS = 40
+PARITY_KEYS = (1, 16, 256, 272, PARITY_COLS * PAGE, 520, 100, 383)
 WAVE = 4               # requests per wave; wave 2 reuses wave 1's prefix
 CALIB_BATCHES = 2
 # Kernel vs oracle, unit-normal q/K/V: both outputs are rounded to bf16,
@@ -104,13 +109,15 @@ def make_prompts(vocab: int):
 
 def kernel_parity(cfg, kv_bits: int) -> None:
     """Each paged kernel vs its jnp oracle on the chip, at the serving
-    geometry: 8 lanes of ragged lengths over a pool of this pool dtype."""
+    widths: 8 lanes over a pool of this pool dtype, with a 40-column page
+    table (three compute blocks of the kernels' walk: 16, 16 and 8 pages)
+    and key counts on the blocks' edges (PARITY_KEYS)."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import paged_attn, paged_prefill
     from repro.serving import kv_pool
 
-    cols = MAX_SEQ // PAGE
+    cols = PARITY_COLS
     t = cols * PAGE
     c = CHUNK_PAGES * PAGE
     ks = jax.random.split(jax.random.PRNGKey(SEED + kv_bits), 4)
@@ -122,11 +129,10 @@ def kernel_parity(cfg, kv_bits: int) -> None:
     pool = jax.jit(kv_pool.write_prefill)(
         pool, k, v, table, jnp.full((LANES,), t, jnp.int32))
     leaves = (pool["k"], pool["v"], pool.get("k_s"), pool.get("v_s"), table)
-    lens = jnp.asarray([t - 37 * i for i in range(LANES)], jnp.int32)
-    q_start = jnp.asarray([(40 * i) % (t - c) for i in range(LANES)],
-                          jnp.int32)
-    kv_len = q_start + jnp.asarray([c if i % 2 == 0 else 1
-                                    for i in range(LANES)], jnp.int32)
+    lens = jnp.asarray(PARITY_KEYS, jnp.int32)
+    # chunk lanes (n_new = c) beside decode lanes (n_new = 1), same key counts
+    n_new = jnp.minimum(lens, jnp.asarray([c, 1] * (LANES // 2), jnp.int32))
+    q_start, kv_len = lens - n_new, lens
     q1 = jax.random.normal(ks[2], (LANES, cfg.n_heads, cfg.hd), jnp.bfloat16)
     qc = jax.random.normal(ks[3], (LANES, c, cfg.n_heads, cfg.hd),
                            jnp.bfloat16)

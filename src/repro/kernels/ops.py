@@ -23,7 +23,6 @@ from typing import Optional, Set
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.kernels import int8_gemm, w4a8_gemm, quantize_act, hadamard, ref
 
@@ -84,43 +83,6 @@ def _pad_m(x: jax.Array, mult: int):
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
     return x, m
-
-
-# ---------------------------------------------------------------------------
-# Paged-attention kernel plumbing (shared by paged_attn / paged_prefill)
-# ---------------------------------------------------------------------------
-
-def paged_pool_scales(k_pages, k_scale, v_scale):
-    """Normalize per-(page, head) scale inputs for the paged kernels:
-    quantized pools pass their real (P, nkv) scales through; float pools
-    get dummy all-ones scales so one kernel signature serves all dtypes.
-    Scales come back as a (P, nkv, 1, 1) view, so that each grid step can
-    block one scale as (1, 1, 1, 1) — the last two dims equal the array's,
-    as the TPU (8, 128) block rule requires. `packed` flags uint8 nibble
-    pages (kv_bits=4) whose last dim is head_dim // 2 — kernel bodies must
-    shift-unpack before dequantizing. Returns
-    (k_scale, v_scale, quantized, packed)."""
-    quantized = k_pages.dtype in (jnp.int8, jnp.uint8)
-    packed = k_pages.dtype == jnp.uint8
-    n_pages, nkv = k_pages.shape[:2]
-    if not quantized:
-        ones = jnp.ones((n_pages, nkv), jnp.float32)
-        k_scale, v_scale = ones, ones
-    return (k_scale.reshape(n_pages, nkv, 1, 1),
-            v_scale.reshape(n_pages, nkv, 1, 1), quantized, packed)
-
-
-def paged_block_specs(w: int, page: int, hd: int):
-    """(page-data, scale) BlockSpecs shared by the paged kernels on the
-    (B, n_kv_heads, W) grid over head-major (P, nkv, page, hd) pages and
-    (P, nkv, 1, 1) scales: index_maps dereference the scalar-prefetched
-    flat page table `pt`; `*_` absorbs the kernel-specific trailing
-    prefetch refs (lengths, q_start, ...)."""
-    def page_map(bi, h, j, pt, *_):
-        return (pt[bi * w + j], h, 0, 0)
-
-    return (pl.BlockSpec((1, 1, page, hd), page_map),
-            pl.BlockSpec((1, 1, 1, 1), page_map))
 
 
 # ---------------------------------------------------------------------------

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+from repro.kernels.paged_attn import pages_walked
 from repro.models import transformer
 from repro.serving import cot, sampling, telemetry
 from repro.serving.draft import NgramDrafter
@@ -539,7 +540,7 @@ class ContinuousBatchingEngine:
         self.steps_run += 1
         st.ran("mixed" if st.lanes else "decode",
                [(s, int(sched.lengths[s]), 1) for s in sorted(sched.active)],
-               sched.n_slots)
+               sched.n_slots, self._pages_walked(sched.lengths + 1))
         with span("serve.wait") as wait:
             nxt = self._sample_tokens(logits)
         with span("serve.commit"):
@@ -585,6 +586,12 @@ class ContinuousBatchingEngine:
                 advancing = [s for s in advancing if s in sched.active]
                 decoding = [s for s in decoding if s in sched.active]
                 return advancing, decoding
+
+    def _pages_walked(self, kv_len) -> int:
+        """Pages the paged-attention kernels walk per layer for every lane
+        of a step whose lanes hold `kv_len` keys (an idle lane 1, as the
+        step programs clamp it)."""
+        return int(pages_walked(np.maximum(kv_len, 1), self.page_size).sum())
 
     def _decode_args(self):
         """The decode step's (page_table, tokens, pos) for every lane."""
@@ -657,7 +664,8 @@ class ContinuousBatchingEngine:
                                                   *args)
             self.steps_run += 1
             st.ran("decode", [(s, int(sched.lengths[s]), 1)
-                              for s in decoding], b)
+                              for s in decoding], b,
+                   self._pages_walked(sched.lengths + 1))
         else:
             with span("serve.inputs"):
                 args = self._mixed_args(advancing, decoding)
@@ -670,7 +678,8 @@ class ContinuousBatchingEngine:
             q_start = args[-2]
             st.ran("mixed", [(s, int(q_start[s]), int(n_new[s]))
                              for s in range(b) if n_new[s] > 0],
-                   b * self.chunk_tokens)
+                   b * self.chunk_tokens,
+                   self._pages_walked(q_start + n_new))
 
         with span("serve.wait") as wait:
             nxt = np.asarray(self._sample(logits))

@@ -18,8 +18,11 @@ idle enter and exit.
 Step records. One per `serve.step`: its kind (mixed, decode, verify or
 idle), the lanes it ran as (slot, q_start, n_new), the real tokens it fed
 against the token slots of the program's shape, the rids that got a
-token, and the active lanes, waiting requests and live pool pages after
-it.
+token, the active lanes, waiting requests and live pool pages after it,
+and `kv_pages_walked`: the pages the paged-attention kernels copy and
+compute per layer, summed over the program's lanes (`ceil(kv_len / page)`
+a lane, an idle lane 1), against the lanes x page-table width
+(`engine.sched.page_table.size`) that a walk of every column would take.
 
 Request records. The engine's `Request` objects carry their own times
 (`t_submit`, `t_admit` stamped in `PagedScheduler.admit`, `t_first` and
@@ -72,6 +75,7 @@ class StepRecord(NamedTuple):
     active: int                      # after the step: lanes in use,
     waiting: int                     # requests queued,
     pages_live: int                  # and pool pages held
+    kv_pages_walked: int = 0         # kernel pages per layer, all lanes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,20 +129,24 @@ class _Span:
 
 class _Step(_Span):
     """`serve.step`: the engine fills in what ran (`ran`, `emitted`)."""
-    __slots__ = ("kind", "lanes", "tokens", "token_slots", "emitted")
+    __slots__ = ("kind", "lanes", "tokens", "token_slots", "emitted",
+                 "kv_pages_walked")
 
     def __init__(self, rec: "Recorder"):
         super().__init__(rec, "serve.step", None)
         self.kind, self.lanes, self.emitted = "idle", [], []
-        self.tokens = self.token_slots = 0
+        self.tokens = self.token_slots = self.kv_pages_walked = 0
 
-    def ran(self, kind: str, lanes, token_slots: int) -> None:
+    def ran(self, kind: str, lanes, token_slots: int,
+            kv_pages_walked: int = 0) -> None:
         """The program this step ran, its working lanes as (slot,
-        q_start, n_new), and the tokens its shape holds."""
+        q_start, n_new), the tokens its shape holds, and the pages its
+        paged-attention kernel walks per layer."""
         self.kind = kind
         self.lanes += lanes
         self.tokens += sum(n for _, _, n in lanes)
         self.token_slots += token_slots
+        self.kv_pages_walked += kv_pages_walked
 
     def __enter__(self) -> "_Step":
         self._rec.n_steps += 1
@@ -153,7 +161,7 @@ class _Step(_Span):
         rec.steps.append(StepRecord(
             rec.n_steps - 1, self.kind, tuple(self.lanes), self.tokens,
             self.token_slots, tuple(self.emitted), len(sched.active),
-            len(sched.waiting), sched.alloc.n_live))
+            len(sched.waiting), sched.alloc.n_live, self.kv_pages_walked))
 
 
 class Recorder:

@@ -143,16 +143,26 @@ def test_write_chunk_decode_matches_write_token(kv_bits):
 # chunk attention kernel: interpret vs oracle vs dense SDPA
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("b,t,nq,nkv,hd,page,c", [
-    (2, 128, 4, 4, 64, 16, 32),      # MHA
-    (3, 96, 8, 2, 32, 16, 16),       # GQA 4x
-    (1, 128, 4, 1, 64, 32, 32),      # MQA
+# (q_start, n_new) lanes whose key counts q_start + n_new sit on the edges
+# of the kernels' compute blocks (16-token pages, 16 pages a block, 36
+# columns): one key, one page, a block, one page past a block, the whole
+# table, a ragged count; chunk lanes beside decode lanes (n_new = 1).
+BLOCK_EDGE_LANES = ((0, 1), (15, 1), (224, 32), (271, 1), (544, 32),
+                    (269, 32))
+
+
+@pytest.mark.parametrize("b,t,nq,nkv,hd,page,c,lanes", [
+    pytest.param(2, 128, 4, 4, 64, 16, 32, None, id="2-128-4-4-64-16-32"),
+    pytest.param(3, 96, 8, 2, 32, 16, 16, None, id="3-96-8-2-32-16-16"),
+    pytest.param(1, 128, 4, 1, 64, 32, 32, None, id="1-128-4-1-64-32-32"),
+    pytest.param(6, 576, 4, 2, 64, 16, 32, BLOCK_EDGE_LANES, id="blocks"),
 ])
-def test_paged_prefill_kernel_matches_ref(b, t, nq, nkv, hd, page, c,
+def test_paged_prefill_kernel_matches_ref(b, t, nq, nkv, hd, page, c, lanes,
                                           kv_bits):
     """Chunk queries at staggered q_start against a long paged cache:
     Pallas interpret == jnp oracle, both within quantization tolerance of
-    the dense causal SDPA over the original K/V."""
+    the dense causal SDPA over the original K/V. Cases: MHA, GQA 4x, MQA,
+    and lanes on the edges of the walk's blocks."""
     rng = np.random.default_rng(3)
     k = rng.normal(size=(b, t, nkv, hd)).astype(np.float32)
     v = rng.normal(size=(b, t, nkv, hd)).astype(np.float32)
@@ -162,8 +172,10 @@ def test_paged_prefill_kernel_matches_ref(b, t, nq, nkv, hd, page, c,
                                  jnp.full((b,), t, jnp.int32))
     q = jax.random.normal(jax.random.PRNGKey(1), (b, c, nq, hd), jnp.float32)
     # stagger chunk starts per sequence; mix full and partial (decode) lanes
-    q_start = jnp.asarray([(i * 24) % (t - c) for i in range(b)], jnp.int32)
-    n_new = jnp.asarray([c if i % 2 == 0 else 1 for i in range(b)], jnp.int32)
+    lanes = lanes or [((i * 24) % (t - c), c if i % 2 == 0 else 1)
+                      for i in range(b)]
+    q_start = jnp.asarray([q0 for q0, _ in lanes], jnp.int32)
+    n_new = jnp.asarray([n for _, n in lanes], jnp.int32)
     kv_len = q_start + n_new
 
     ks, vs = pool.get("k_s"), pool.get("v_s")

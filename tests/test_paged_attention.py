@@ -9,8 +9,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_arch, reduced
-from repro.kernels.paged_attn import (paged_decode_attention,
-                                      paged_decode_attention_ref)
+from repro.kernels.paged_attn import (BLOCK_KEYS, block_pages,
+                                      paged_decode_attention,
+                                      paged_decode_attention_ref,
+                                      pages_walked)
+from repro.kernels.paged_prefill import (paged_prefill_attention,
+                                         paged_prefill_attention_ref)
 from repro.models import attention as attn
 from repro.models import transformer
 from repro.serving import kv_pool
@@ -35,17 +39,27 @@ def make_pool_and_dense(b, t, nkv, hd, page, seed=0, kv_bits=8):
     return pool, jnp.asarray(page_table), jnp.asarray(k), jnp.asarray(v)
 
 
-@pytest.mark.parametrize("b,t,nq,nkv,hd,page", [
-    (2, 128, 4, 4, 64, 16),      # MHA, long cache
-    (3, 96, 8, 2, 32, 16),       # GQA 4x, ragged lengths below
-    (1, 256, 4, 1, 64, 32),      # MQA, longest cache
+# Lane lengths across several compute blocks of the kernels' walk (16-token
+# pages, 16 pages a block, a 36-column table: blocks of 16, 16 and 4
+# pages): one key, one page, a block, one page past a block, a ragged
+# length in the second block, the whole table.
+BLOCK_EDGES = (1, 16, BLOCK_KEYS, BLOCK_KEYS + 16, 300, 576)
+
+
+@pytest.mark.parametrize("b,t,nq,nkv,hd,page,lens", [
+    pytest.param(2, 128, 4, 4, 64, 16, None, id="2-128-4-4-64-16"),   # MHA
+    pytest.param(3, 96, 8, 2, 32, 16, None, id="3-96-8-2-32-16"),  # GQA 4x
+    pytest.param(1, 256, 4, 1, 64, 32, None, id="1-256-4-1-64-32"),  # MQA
+    pytest.param(6, 576, 4, 2, 64, 16, BLOCK_EDGES, id="blocks"),
 ])
 @pytest.mark.parametrize("kv_bits", [16, 8, 4])
-def test_paged_decode_matches_dense(b, t, nq, nkv, hd, page, kv_bits):
-    """q_len=1 against a long paged cache == dense masked SDPA."""
+def test_paged_decode_matches_dense(b, t, nq, nkv, hd, page, lens, kv_bits):
+    """q_len=1 against a long paged cache == dense masked SDPA; lengths
+    ragged below the table width (default: t, 3t/4, ...)."""
     pool, pt, k, v = make_pool_and_dense(b, t, nkv, hd, page, kv_bits=kv_bits)
     q = jax.random.normal(jax.random.PRNGKey(1), (b, nq, hd), jnp.float32)
-    lens = jnp.asarray([t - i * (t // 4) for i in range(b)], jnp.int32)
+    lens = jnp.asarray(lens or [t - i * (t // 4) for i in range(b)],
+                       jnp.int32)
 
     ks, vs = pool.get("k_s"), pool.get("v_s")
     ref = paged_decode_attention_ref(q, pool["k"], pool["v"], ks, vs, pt,
@@ -62,6 +76,56 @@ def test_paged_decode_matches_dense(b, t, nq, nkv, hd, page, kv_bits):
     tol = {16: 0.03, 8: 0.12, 4: 0.5}[kv_bits]
     np.testing.assert_allclose(np.asarray(got).reshape(b, -1),
                                np.asarray(dense), rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+@pytest.mark.parametrize("kv_bits", [16, 8, 4])
+def test_dead_columns_never_read(kernel, kv_bits):
+    """Every page-table column at or past ceil(kv_len / page) points at a
+    poison page: NaN K/V (bf16 pool) or NaN scales over finite codes
+    (int8, packed int4). The kernel's output is finite and equals the
+    oracle's over a table whose dead columns hold real pages, so the walk
+    never read a dead column (a NaN value row read into a block would
+    reach the output through p @ v)."""
+    b, t, nq, nkv, hd, page, c = 6, 576, 4, 2, 64, 16, 32
+    pool, pt, _, _ = make_pool_and_dense(b, t, nkv, hd, page, seed=2,
+                                         kv_bits=kv_bits)
+    poison = pool["k"].shape[0]
+    grow = lambda x, fill: jnp.concatenate(                 # noqa: E731
+        [x, jnp.full((1,) + x.shape[1:], fill, x.dtype)])
+    if kv_bits == 16:
+        bad = {n: grow(pool[n], jnp.nan) for n in ("k", "v")}
+    else:
+        bad = {n: grow(pool[n], 17) for n in ("k", "v")}
+        bad.update({n: grow(pool[n], jnp.nan) for n in ("k_s", "v_s")})
+    clean = {n: grow(x, 0) for n, x in pool.items()}
+    kv_len = jnp.asarray(BLOCK_EDGES, jnp.int32)
+    live = jnp.arange(pt.shape[1])[None, :] < pages_walked(
+        kv_len, page)[:, None]
+    pt_bad = jnp.where(live, pt, poison)
+    assert block_pages(page, pt.shape[1]) * page == BLOCK_KEYS
+
+    def run(fn, pools, table):
+        return fn(pools["k"], pools["v"], pools.get("k_s"),
+                  pools.get("v_s"), table)
+
+    if kernel == "decode":
+        q = jax.random.normal(jax.random.PRNGKey(4), (b, nq, hd))
+        got = run(lambda *a: paged_decode_attention(
+            q, *a, kv_len, interpret=True), bad, pt_bad)
+        want = run(lambda *a: paged_decode_attention_ref(q, *a, kv_len),
+                   clean, pt)
+    else:
+        n_new = jnp.minimum(kv_len, jnp.asarray([1, c] * (b // 2)))
+        q_start = kv_len - n_new
+        q = jax.random.normal(jax.random.PRNGKey(4), (b, c, nq, hd))
+        got = run(lambda *a: paged_prefill_attention(
+            q, *a, q_start, kv_len, interpret=True), bad, pt_bad)
+        want = run(lambda *a: paged_prefill_attention_ref(
+            q, *a, q_start, kv_len), clean, pt)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
 
 
 def test_paged_write_token_roundtrip():

@@ -76,6 +76,27 @@ def test_request_times_are_ordered_and_outputs_whole(cfg_params, kv_bits):
         v.out = ()
 
 
+def test_step_records_count_the_pages_walked(cfg_params, kv_bits):
+    """A kernel step's `kv_pages_walked` is ceil(kv_len / page) summed over
+    the program's lanes, an idle lane walking its one scratch page: less
+    than the lanes x page-table width a walk of every column takes."""
+    cfg, params = cfg_params
+    eng = make_engine(params, cfg, kv_bits=kv_bits)
+    eng.run(PROMPTS, mode="no_think", max_new=12)
+    page, table = eng.page_size, eng.sched.page_table
+    lanes, width = table.shape
+    kernel_steps = [r for r in eng.telemetry.steps
+                    if r.kind in ("mixed", "decode")]
+    assert kernel_steps
+    for r in eng.telemetry.steps:
+        if r.kind not in ("mixed", "decode"):
+            assert r.kv_pages_walked == 0
+            continue
+        want = sum(-(-(q0 + n) // page) for _, q0, n in r.lanes)
+        assert r.kv_pages_walked == want + lanes - len(r.lanes)
+        assert r.kv_pages_walked < lanes * width
+
+
 def test_a_tight_pool_counts_preemptions_and_keeps_the_first_admit(
         cfg_params, kv_bits):
     cfg, params = cfg_params

@@ -1,6 +1,10 @@
-"""Compile the main-path Pallas kernels for a described TPU v5e chip at
-pangu_1b widths (d_model 1536, 12/4 heads, head_dim 128, d_ff 5632) and the
-chip smoke's serving geometry (8 lanes, 16-token pages, 32-token chunks).
+"""Compile the main-path Pallas kernels for a described TPU v5e chip: the
+GEMM kernels at pangu_1b widths (d_model 1536, d_ff 5632) and the chip
+smoke's mixed-step rows (8 lanes of 32-token chunks); the paged-attention
+kernels at the chip benchmark's serving geometry (openPangu-Embedded-1B's
+12 query and 6 KV heads of 128, 16 lanes, a 289-column table of 16-token
+pages, 128-token chunks), so the VMEM their page walk takes and the shapes
+of its DMAs meet the chip's compiler at the size the benchmark runs.
 
 Nothing runs: the TPU compiler, which is installed here, compiles for a
 chip that is described and not attached, and refuses what the chip's
@@ -19,9 +23,11 @@ from repro.configs import get_arch
 from repro.kernels import ops, paged_attn, paged_prefill
 
 CFG = get_arch("pangu_1b")
-LANES, PAGE, COLS, CHUNK = 8, 16, 20, 32
+M = 8 * 32                             # rows of a mixed step's GEMMs
+# bench/configs/pangu-1b-w8a8.json as served: heads, lanes, table, chunk
+NQ, NKV, HD = 12, 6, 128
+LANES, PAGE, COLS, CHUNK = 16, 16, 289, 128
 N_PAGES = 1 + LANES * COLS
-M = LANES * CHUNK                      # rows of a mixed step's GEMMs
 POOL_DTYPES = {16: jnp.bfloat16, 8: jnp.int8, 4: jnp.uint8}
 
 
@@ -61,7 +67,7 @@ def _compile(fn, one_chip, *shapes):
 @pytest.mark.parametrize("kernel", ["paged_decode_attention",
                                     "paged_prefill_attention"])
 def test_paged_kernel_compiles_for_v5e(one_chip, kernel, kv_bits):
-    nq, nkv, hd = CFG.n_heads, CFG.n_kv_heads, CFG.hd
+    nq, nkv, hd = NQ, NKV, HD
     stored_hd = hd // 2 if kv_bits == 4 else hd
     pages = ((N_PAGES, nkv, PAGE, stored_hd), POOL_DTYPES[kv_bits])
     scales = None if kv_bits == 16 else ((N_PAGES, nkv), jnp.float32)
