@@ -2,9 +2,10 @@
 
 After the window, a sample of the requests the run finished (drawn from the
 seed, the one with the most served tokens always in it) is run through the
-plain reference (`bench/reference.py`) over its prompt as served and its
-served tokens. At each served token, the gap by which the reference's logit
-of that token lies below the reference's best logit is read; the run is
+plain reference (`bench/reference.py`, with the layers of the
+configuration's architecture) over its prompt as served and its served
+tokens. At each served token, the gap by which the reference's logit of
+that token lies below the reference's best logit is read; the run is
 correct when every finished request has exactly the tokens it asked for and
 the widest gap is within the configuration's limit.
 
@@ -36,11 +37,11 @@ def sample(finished: List[dict], seed: int, k: int = SAMPLE) -> List[dict]:
     return [order[0]] + [rest[i] for i in sorted(pick)]
 
 
-def gaps(seed: int, spec: dict, reqs: List[dict], control: bool = False
-         ) -> Dict[str, float]:
-    """Widest gap of the served tokens under the reference (`logit_gap`),
-    and with `control`, the widest gap of the control's first choices
-    (`control_gap`)."""
+def gaps(arch, seed: int, spec: dict, reqs: List[dict],
+         control: bool = False) -> Dict[str, float]:
+    """Widest gap of the served tokens under the reference of architecture
+    `arch` (`logit_gap`), and with `control`, the widest gap of the
+    control's first choices (`control_gap`)."""
     m, page = spec["model"], spec["engine"]["page_size"]
     padded = reference.padded_len(spec["engine"]["max_seq_len"])
     prec = {k: spec["quant"][k] for k in
@@ -49,10 +50,11 @@ def gaps(seed: int, spec: dict, reqs: List[dict], control: bool = False
     for r in reqs:
         judge = ()
         if control:
-            low = reference.logits_summary(seed, m, spec["control"], page,
-                                           padded, r["prompt"], r["served"])
+            low = reference.logits_summary(arch, seed, m, spec["control"],
+                                           page, padded, r["prompt"],
+                                           r["served"])
             judge = low["argmax"]
-        ref = reference.logits_summary(seed, m, prec, page, padded,
+        ref = reference.logits_summary(arch, seed, m, prec, page, padded,
                                        r["prompt"], r["served"], judge)
         worst = max(worst, float(np.max(ref["best"] - ref["served"])))
         if control:

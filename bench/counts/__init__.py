@@ -2,12 +2,16 @@
 from shapes and the lanes' real lengths, whatever implements them.
 
 Each kernel has a module of its own here, `bench/counts/<kernel>.py`, with
-`calls(model, quant, step)`: the (ops, bytes) of each call that one engine
-step makes of that kernel. `step` is a `bench.drive.StepRecord`: its kind
-("mixed" or "decode") and its working lanes (q_start, n_new). A kernel's
-least time at the chip's peaks is the sum over its calls of
-max(ops / peak rate, bytes / bandwidth), which is never more than the time
-the calls took, so a share of it cannot pass 100% on correct counts.
+`calls(arch, model, quant, step)`: the (ops, bytes) of each call that one
+engine step makes of that kernel. `arch` is the configuration's
+architecture (`bench/arch/<arch>/`, as `bench.run.load_arch` loads it),
+which gives the shapes the formulas need (its linears, its cache bytes,
+its attention operations per key, its layers); `step` is a
+`bench.drive.StepRecord`: its kind ("mixed" or "decode") and its working
+lanes (q_start, n_new). A kernel's least time at the chip's peaks is the
+sum over its calls of max(ops / peak rate, bytes / bandwidth), which is
+never more than the time the calls took, so a share of it cannot pass 100%
+on correct counts.
 """
 from __future__ import annotations
 
@@ -34,30 +38,11 @@ def roofline_share(ctx, kernel: str, op_rate: str):
     spent = ctx.trace["kernel_s"].get(kernel, 0.0)
     mod = load(kernel)
     calls = [c for s in ctx.record.steps if s.traced
-             for c in mod.calls(ctx.model, ctx.quant, s)]
+             for c in mod.calls(ctx.arch, ctx.model, ctx.quant, s)]
     if spent <= 0 or not calls:
         return None
     need = least_time(calls, ctx.peaks[op_rate], ctx.peaks["hbm_bw"])
     return 100.0 * need / spent
-
-
-def gemm_sites(m: dict):
-    """(K, N) of each quantized linear of one layer: [q|k|v], o,
-    [gate|up], down."""
-    d, ff = m["hidden_size"], m["intermediate_size"]
-    nq, nkv, hd = (m["num_attention_heads"], m["num_key_value_heads"],
-                   m["head_dim"])
-    return [(d, (nq + 2 * nkv) * hd), (nq * hd, d), (d, 2 * ff), (ff, d)]
-
-
-def kv_bytes_per_token(m: dict, kv_bits: int) -> float:
-    """Bytes of keys and values one token takes in one layer's pages."""
-    return 2 * m["num_key_value_heads"] * m["head_dim"] * kv_bits / 8
-
-
-def scale_bytes_per_page(m: dict, kv_bits: int) -> int:
-    """Per-(page, kv head) f32 scales of keys and values, for one layer."""
-    return 0 if kv_bits == 16 else 2 * m["num_key_value_heads"] * 4
 
 
 def context_keys(q_start: int, n_new: int) -> int:

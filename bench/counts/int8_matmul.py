@@ -1,9 +1,8 @@
 """int8_matmul: int8 activations (M, K) x int8 weights (K, N), int32
 accumulation, f32 output with per-token and per-channel scales. One call per
-quantized linear per layer, with M = the step's real tokens."""
+quantized linear per layer (the architecture's `gemms`), with M = the rows
+of the step's real tokens it gets."""
 from __future__ import annotations
-
-from bench.counts import gemm_sites
 
 
 def call(m_rows: int, k: int, n: int):
@@ -12,9 +11,8 @@ def call(m_rows: int, k: int, n: int):
     return ops, nbytes
 
 
-def calls(model: dict, quant: dict, step):
+def calls(arch, model: dict, quant: dict, step):
     if quant["weight_bits"] != 8:
         return []
     rows = sum(n for _, n in step.lanes)
-    return [call(rows, k, n) for k, n in gemm_sites(model)] * \
-        model["num_hidden_layers"]
+    return [call(r, k, n) for r, k, n in arch.gemms(model, rows)]

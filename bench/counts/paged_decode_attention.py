@@ -1,27 +1,26 @@
 """paged_decode_attention: one query per lane against the lane's pages.
-Needed per lane of context `n` keys: the q.k and p.v products
-(4 nq hd n operations) and one read of the lane's keys, values and page
-scales, with the query read and the output written in bf16. One call per
+Needed per lane of context `n` keys: the architecture's operations per key
+for each of them (q.k and p.v) and one read of the lane's cache and page
+scales, with the query read and the output written. One call per attention
 layer in each decode step."""
 from __future__ import annotations
 
 import math
 
-from bench.counts import kv_bytes_per_token, scale_bytes_per_page
 
-
-def call(model: dict, kv_bits: int, page: int, contexts):
-    nq, hd = model["num_attention_heads"], model["head_dim"]
-    ops = sum(4 * nq * hd * n for n in contexts)
-    nbytes = sum(n * kv_bytes_per_token(model, kv_bits)
-                 + math.ceil(n / page) * scale_bytes_per_page(model, kv_bits)
-                 + 2 * 2 * nq * hd for n in contexts)
+def call(arch, model: dict, kv_bits: int, page: int, contexts):
+    per_key = arch.attention_ops_per_key(model)
+    ops = sum(per_key * n for n in contexts)
+    nbytes = sum(n * arch.kv_bytes_per_token(model, kv_bits)
+                 + math.ceil(n / page)
+                 * arch.scale_bytes_per_page(model, kv_bits)
+                 + arch.query_bytes(model) for n in contexts)
     return ops, nbytes
 
 
-def calls(model: dict, quant: dict, step):
+def calls(arch, model: dict, quant: dict, step):
     if step.kind != "decode":
         return []
     contexts = [q_start + n for q_start, n in step.lanes]
-    one = call(model, quant["kv_bits"], quant["page_size"], contexts)
-    return [one] * model["num_hidden_layers"]
+    one = call(arch, model, quant["kv_bits"], quant["page_size"], contexts)
+    return [one] * arch.attention_layers(model)

@@ -3,5 +3,7 @@
 value, or None where the run has nothing to read (the harness then leaves
 the metric out of the result line). `ctx` is `bench.run.Context`: the run's
 record (`bench.drive.Record`), the model's sizes, the quantization, the
-engine settings, the chip's peaks, `setup_s` and, in a traced run, the
-reduced trace (`bench.trace.reduce`)."""
+engine settings, the chip's peaks, `setup_s`, in a traced run the reduced
+trace (`bench.trace.reduce`), and the configuration's architecture
+(`bench/arch/<arch>/`), through which a reader that needs a shape gets
+it."""

@@ -9,5 +9,6 @@ def read(ctx):
     wall = sum(s.t1 - s.t0 for s in steps)
     if not steps or wall <= 0:
         return None
-    need = sum(step.least_time(ctx.model, s, ctx.peaks) for s in steps)
+    need = sum(step.least_time(ctx.arch, ctx.model, s, ctx.peaks)
+               for s in steps)
     return 100.0 * need / wall

@@ -1,8 +1,13 @@
-"""Plain reference of a served dense GQA decoder (RMSNorm, RoPE, SwiGLU),
-with the quantization the configuration states, for the check that decides
-`correct`. It imports nothing of the program and takes nothing the program
-made: the float32 weights come again from `bench/weights.py` and the seed,
-one layer at a time, and every scale is computed here.
+"""Plain reference of a served decoder, with the quantization the
+configuration states, for the check that decides `correct`. This module
+holds what every architecture shares: the integer and bfloat16 arithmetic
+of a linear, the paged store of keys and values, the blocked causal
+attention over it, RMSNorm and RoPE, the embedding, the LM head and the
+walk over a sequence. An architecture's layer equations and its loop over
+layers are in its package, `bench/arch/<arch>/` (`forward`). Nothing here
+imports the program or takes anything the program made: the float32
+weights come again from the seed (`bench/weights.py` and the
+architecture's own), one layer at a time, and every scale is computed here.
 
 What it computes, for one sequence (prompt as served + served tokens), in
 float32 with matmuls at HIGHEST precision:
@@ -55,11 +60,11 @@ def _codes(x, s, bits):
     return jnp.clip(jnp.round(x / s), -lim, lim)
 
 
-def _rms_norm(x, g, eps):
+def rms_norm(x, g, eps):
     return bf(x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * g)
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x (T, H, hd); rotate-half RoPE at absolute positions pos (T,)."""
     hd = x.shape[-1]
     freq = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
@@ -169,45 +174,19 @@ def attention(q, k, v, n_real, l0, page, bits):
     return out.reshape(t, nq * hd)
 
 
-def layer(x, w, n_real, l0, m, p, page):
-    """One decoder layer over the whole sequence x (T, d)."""
-    nq, nkv, hd = (m["num_attention_heads"], m["num_key_value_heads"],
-                   m["head_dim"])
-    eps, theta = m["rms_norm_eps"], m["rope_theta"]
-    t = x.shape[0]
-    pos = jnp.arange(t)
-    h = _rms_norm(x, w["ln1"], eps)
-    qkv = bf(linear(h, w["wqkv"], p))
-    q = qkv[:, : nq * hd].reshape(t, nq, hd)
-    k = qkv[:, nq * hd:(nq + nkv) * hd].reshape(t, nkv, hd)
-    v = qkv[:, (nq + nkv) * hd:].reshape(t, nkv, hd)
-    q, k = _rope(q, pos, theta), _rope(k, pos, theta)
-    a = bf(attention(q, k, v, n_real, l0, page, p["kv_bits"]))
-    x = bf(x + bf(linear(a, w["wo"], p)))
-    h = _rms_norm(x, w["ln2"], eps)
-    gu = bf(linear(h, w["w_in"], p))
-    gate, up = jnp.split(gu, 2, axis=-1)
-    act = bf(bf(jax.nn.silu(gate)) * up)
-    return bf(x + bf(linear(act, w["w_out"], p)))
-
-
 @functools.lru_cache(maxsize=None)
-def _programs(m_items, p_items, page):
-    m, p = dict(m_items), dict(p_items)
+def _programs(m_items):
+    m = dict(m_items)
 
     @jax.jit
     def embed(key, tokens):
         return bf(weights.embed(key, m))[tokens]
 
     @jax.jit
-    def one_layer(x, key, i, n_real, l0):
-        return layer(x, weights.layer(key, i, m), n_real, l0, m, p, page)
-
-    @jax.jit
     def head(x, key, rows, served):
         """Reference logits at `rows` (R,): (max, logit of `served`,
         argmax), over the real vocabulary."""
-        h = _rms_norm(x[rows], weights.final_norm(key, m), m["rms_norm_eps"])
+        h = rms_norm(x[rows], weights.final_norm(key, m), m["rms_norm_eps"])
         w = (weights.embed(key, m).T if m["tie_word_embeddings"]
              else weights.lm_head(key, m))
         logits = jnp.dot(h, bf(w), precision=HI)
@@ -215,7 +194,7 @@ def _programs(m_items, p_items, page):
         got = jnp.take_along_axis(logits, served[:, None], -1)[:, 0]
         return best, got, logits.argmax(-1).astype(jnp.int32)
 
-    return embed, one_layer, head
+    return embed, head
 
 
 def padded_len(max_seq_len: int) -> int:
@@ -224,17 +203,17 @@ def padded_len(max_seq_len: int) -> int:
     return -(-max_seq_len // Q_BLOCK) * Q_BLOCK
 
 
-def logits_summary(seed: int, m: dict, p: dict, page: int, padded: int,
-                   prompt: Sequence[int], served: Sequence[int],
+def logits_summary(arch, seed: int, m: dict, p: dict, page: int,
+                   padded: int, prompt: Sequence[int], served: Sequence[int],
                    judge: Sequence[int] = ()) -> Dict[str, np.ndarray]:
-    """Run the reference over prompt + served tokens (padded to `padded`
-    positions, a multiple of Q_BLOCK). At each position that produced a
-    served token returns the reference's best logit, its logit of the
-    served token, and its argmax;
+    """Run the reference of architecture `arch` (its package, as
+    `bench.run.load_arch` loads it) over prompt + served tokens (padded to
+    `padded` positions, a multiple of Q_BLOCK). At each position that
+    produced a served token returns the reference's best logit, its logit
+    of the served token, and its argmax;
     with `judge` tokens given (another precision's choices), also its logit
     of each of those."""
-    embed, one_layer, head = _programs(tuple(sorted(m.items())),
-                                       tuple(sorted(p.items())), page)
+    embed, head = _programs(tuple(sorted(m.items())))
     key = weights.base_key(seed)
     seq = list(prompt) + list(served)
     n_real, l0 = len(seq), len(prompt)
@@ -243,8 +222,7 @@ def logits_summary(seed: int, m: dict, p: dict, page: int, padded: int,
     toks = np.zeros(padded, np.int32)
     toks[:n_real] = seq
     x = embed(key, jnp.asarray(toks))
-    for i in range(m["num_hidden_layers"]):
-        x = one_layer(x, key, i, n_real, l0)
+    x = arch.forward(x, key, m, p, page, n_real, l0)
     out: Dict[str, List[np.ndarray]] = {"best": [], "served": [],
                                         "argmax": [], "judged": []}
     n = len(served)

@@ -4,8 +4,9 @@
         --trace <0|1>
 
 The cell (`BENCHMARK.json` "workloads") names a configuration file and a
-traffic mix; the harness finds everything else by name: the mix's data in
-`bench/traffic/<mix>.json`, each metric's reader in
+traffic mix; the harness finds everything else by name: the architecture
+the configuration names under "arch" in `bench/arch/<arch>/`, the mix's
+data in `bench/traffic/<mix>.json`, each metric's reader in
 `bench/metrics/<metric>.py`. It makes the weights from the seed, warms up the
 cell's programs, drives the traffic for `--seconds`, checks what the timed
 path produced against the plain reference, and prints one JSON line: with
@@ -23,6 +24,7 @@ PROCESS_START = time.time()
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
 import gc  # noqa: E402
+import hashlib  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -50,6 +52,7 @@ class Context:
     peaks: dict
     setup_s: float
     trace: Optional[dict] = None
+    arch: object = None               # the configuration's architecture
 
 
 class CompileMeter:
@@ -88,6 +91,38 @@ def load_reader(root: str, name: str):
     return mod.read
 
 
+def load_arch(root: str, spec: dict, where: str = "the configuration"):
+    """The architecture that a configuration names under its top-level
+    "arch": the package `bench/arch/<arch>/` under `root`, loaded once per
+    directory. A configuration without the key, or naming a package that
+    is not there, is an error that says which."""
+    base = os.path.join(root, "bench", "arch")
+    have = sorted(d for d in (os.listdir(base) if os.path.isdir(base)
+                              else [])
+                  if os.path.isfile(os.path.join(base, d, "__init__.py")))
+    if "arch" not in spec:
+        raise ValueError(f"{where} names no architecture: it needs a "
+                         f"top-level \"arch\", one of {have} "
+                         f"(bench/arch/<arch>/)")
+    if spec["arch"] not in have:
+        raise ValueError(f"{where} names architecture {spec['arch']!r}, "
+                         f"which {base} does not hold; it holds {have}")
+    path = os.path.realpath(os.path.join(base, spec["arch"]))
+    name = "bench_arch_" + hashlib.sha1(path.encode()).hexdigest()[:16]
+    if name not in sys.modules:
+        found = importlib.util.spec_from_file_location(
+            name, os.path.join(path, "__init__.py"),
+            submodule_search_locations=[path])
+        mod = importlib.util.module_from_spec(found)
+        sys.modules[name] = mod
+        try:
+            found.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
+
+
 def cell_metrics(bench: dict, cell: str, trace: bool) -> list:
     """The metrics this cell reports in a run of this kind."""
     group = bench["per_layer"] if trace else bench["end_to_end"]
@@ -112,6 +147,7 @@ def run_cell(root: str, cell_name: str, seed: int, seconds: float,
     conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
     with open(os.path.join(root, conf["file"])) as f:
         spec = json.load(f)
+    arch = load_arch(root, spec, conf["file"])
     mix = traffic.load(root, cell["traffic"])
     if persistent_cache:
         from repro.launch.jax_cache import enable_compile_cache
@@ -119,7 +155,7 @@ def run_cell(root: str, cell_name: str, seed: int, seconds: float,
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     meter = CompileMeter(jax)
 
-    server = adapter.Server(conf["name"], spec, seed)
+    server = adapter.Server(conf["name"], spec, seed, arch)
     jax.block_until_ready(server.engine.params)
     eng = spec["engine"]
     drive.warm_shapes(server, mix["mode"],
@@ -171,7 +207,7 @@ def run_cell(root: str, cell_name: str, seed: int, seconds: float,
 
     quant = dict(spec["quant"], page_size=eng["page_size"])
     ctx = Context(rec, spec["model"], quant, eng, peaks,
-                  marks["t"] - PROCESS_START, reduced)
+                  marks["t"] - PROCESS_START, reduced, arch)
     metrics = {}
     for m in cell_metrics(bench, cell_name, trace):
         value = load_reader(root, m["name"])(ctx)
@@ -193,7 +229,7 @@ def run_cell(root: str, cell_name: str, seed: int, seconds: float,
     del server
     gc.collect()
     t_ref = time.time()
-    got = check.gaps(seed, spec, check.sample(finished, seed),
+    got = check.gaps(arch, seed, spec, check.sample(finished, seed),
                      control=control)
     log(f"reference took {time.time() - t_ref!r} s")
     limit = spec["check"]["logit_gap_limit"]

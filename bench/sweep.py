@@ -41,10 +41,11 @@ def main(argv=None) -> int:
     conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
     with open(os.path.join(run.ROOT, conf["file"])) as f:
         spec = json.load(f)
+    arch = run.load_arch(run.ROOT, spec, conf["file"])
     mix = traffic.load(run.ROOT, cell["traffic"])
     eng = spec["engine"]
     for rate in args.rates:
-        server = adapter.Server(conf["name"], spec, args.seed)
+        server = adapter.Server(conf["name"], spec, args.seed, arch)
         drive.warm_shapes(server, mix["mode"],
                           eng["chunk_pages"] * eng["page_size"])
         m = dict(mix, rate_per_s=rate)

@@ -18,7 +18,7 @@ from bench import trace as trace_mod  # noqa: E402
 from bench.counts import (int8_matmul, paged_decode_attention,  # noqa: E402
                           paged_prefill_attention, step)
 from bench.drive import Record, RequestRecord, StepRecord  # noqa: E402
-from bench.run import Context, load_reader  # noqa: E402
+from bench.run import Context, load_arch, load_reader  # noqa: E402
 
 PEAKS = {"bf16_flops": 197e12, "int8_ops": 393e12, "hbm_bw": 819e9}
 MODEL = {"hidden_size": 8, "num_hidden_layers": 2, "num_attention_heads": 4,
@@ -27,6 +27,7 @@ MODEL = {"hidden_size": 8, "num_hidden_layers": 2, "num_attention_heads": 4,
 QUANT = {"weight_bits": 8, "weight_group": 0, "act_bits": 8, "kv_bits": 8,
          "page_size": 4}
 TRACE = os.path.join(ROOT, "bench", "tests", "data", "small_trace.json")
+ARCH = load_arch(ROOT, {"arch": "dense_gqa"})
 
 
 # -- percentiles and rates ----------------------------------------------------
@@ -238,45 +239,88 @@ def test_decode_attention_counts_by_hand():
     # one lane of 5 keys, 4 q heads of 4, 2 kv heads, int8, pages of 4:
     # ops 4*4*4*5; K and V 5*2*2*4 bytes; 2 pages of 2 heads' 2 scales;
     # q in and out 2*2*4*4 bytes
-    assert paged_decode_attention.call(MODEL, 8, 4, [5]) == (320, 80 + 32
-                                                             + 64)
+    assert paged_decode_attention.call(ARCH, MODEL, 8, 4, [5]) == \
+        (320, 80 + 32 + 64)
 
 
 def test_prefill_attention_counts_by_hand():
     # 3 queries at 2..4 see 3 + 4 + 5 = 12 keys; context 5 tokens
-    ops, nbytes = paged_prefill_attention.call(MODEL, 4, 4, [(2, 3)])
+    ops, nbytes = paged_prefill_attention.call(ARCH, MODEL, 4, 4, [(2, 3)])
     assert ops == 4 * 4 * 4 * 12
     assert nbytes == 5 * 2 * 2 * 4 * 0.5 + 2 * 16 + 3 * 64
 
 
 def test_step_work_by_hand():
     s = SimpleNamespace(kind="mixed", lanes=[(0, 2), (5, 1)], emitted=1)
-    int8_ops, bf16_ops = step.work(MODEL, s)
+    int8_ops, bf16_ops = step.work(ARCH, MODEL, s)
     per_token = 8 * 32 + 16 * 8 + 8 * 32 + 16 * 8
     assert int8_ops == 2 * 3 * 2 * per_token
     assert bf16_ops == 4 * 4 * 4 * 2 * (3 + 6) + 2 * 8 * 100
 
 
+# A cache that is not (nkv, hd) keys and values: one latent vector of 576
+# (512 compressed + 64 rotary) per token and layer, one f32 scale per page,
+# attended in absorbed form by 128 heads (q.k over 576, p.v over 512), in
+# 5 layers.
+LATENT = SimpleNamespace(
+    attention_layers=lambda m: 5,
+    gemms=lambda m, tokens: [(tokens, 7680, 1536)],
+    attention_ops_per_key=lambda m: 2 * 128 * (576 + 512),
+    query_bytes=lambda m: 2 * 128 * (576 + 512),
+    kv_bytes_per_token=lambda m, kv_bits: 576 * kv_bits / 8,
+    scale_bytes_per_page=lambda m, kv_bits: 0 if kv_bits == 16 else 4)
+
+
+def test_latent_cache_counts_by_hand():
+    # decode, 40 keys, int8, pages of 16: 278528 ops a key; 40 latents of
+    # 576 bytes, 3 pages' scales, a 128 x 576 query in and 128 x 512 out
+    assert paged_decode_attention.call(LATENT, {}, 8, 16, [40]) == \
+        (11141120, 23040 + 12 + 278528)
+    # 8 queries at 32..39 see 33 + ... + 40 = 292 keys; bf16 latents
+    ops, nbytes = paged_prefill_attention.call(LATENT, {}, 16, 16, [(32, 8)])
+    assert (ops, nbytes) == (278528 * 292, 40 * 1152 + 8 * 278528)
+    dec = SimpleNamespace(kind="decode", lanes=[(39, 1)], emitted=1)
+    assert paged_decode_attention.calls(LATENT, {}, QUANT, dec) == \
+        [paged_decode_attention.call(LATENT, {}, 8, 4, [40])] * 5
+    assert int8_matmul.calls(LATENT, {}, QUANT, dec) == \
+        [int8_matmul.call(1, 7680, 1536)]
+    model = {"hidden_size": 7680, "vocab_size": 19200}
+    assert step.work(LATENT, model, dec) == (
+        2 * 7680 * 1536, 278528 * 5 * 40 + 2 * 7680 * 19200)
+    # the readers every cell reports read it too
+    rec = Record((0.0, 10.0), steps=[StepRecord(1.0, 1.5, "decode",
+                                                [(39, 1)], 1, True)])
+    ctx = Context(rec, model, QUANT, {}, PEAKS, 1.0,
+                  {"kernel_s": {"paged_decode_attention": 1.0},
+                   "busy_s": 1.0, "window_s": 2.0}, LATENT)
+    for name in ("step_ms.decode", "mfu.decode_step",
+                 "paged_decode_attention_roofline", "idle_share"):
+        assert load_reader(ROOT, name)(ctx) > 0
+
+
 def test_calls_follow_the_step_kind_and_weight_bits():
     dec = SimpleNamespace(kind="decode", lanes=[(9, 1)], emitted=1)
     mix = SimpleNamespace(kind="mixed", lanes=[(0, 4)], emitted=0)
-    assert len(paged_decode_attention.calls(MODEL, QUANT, dec)) == 2
-    assert paged_decode_attention.calls(MODEL, QUANT, mix) == []
-    assert len(paged_prefill_attention.calls(MODEL, QUANT, mix)) == 2
-    assert len(int8_matmul.calls(MODEL, QUANT, mix)) == 8
-    assert int8_matmul.calls(MODEL, dict(QUANT, weight_bits=4), mix) == []
+    assert len(paged_decode_attention.calls(ARCH, MODEL, QUANT, dec)) == 2
+    assert paged_decode_attention.calls(ARCH, MODEL, QUANT, mix) == []
+    assert len(paged_prefill_attention.calls(ARCH, MODEL, QUANT, mix)) == 2
+    assert len(int8_matmul.calls(ARCH, MODEL, QUANT, mix)) == 8
+    assert int8_matmul.calls(ARCH, MODEL, dict(QUANT, weight_bits=4),
+                             mix) == []
 
 
 def _ctx(steps, trace=None, requests=(), window=(0.0, 10.0)):
     rec = Record(window, stop=window[1], requests=list(requests),
                  steps=steps)
-    return Context(rec, MODEL, QUANT, {"max_batch": 4}, PEAKS, 12.5, trace)
+    return Context(rec, MODEL, QUANT, {"max_batch": 4}, PEAKS, 12.5, trace,
+                   ARCH)
 
 
 def test_roofline_share_is_100_when_the_kernel_takes_its_least_time():
     s = StepRecord(1.0, 2.0, "decode", [(99, 1), (9, 1)], 2, True)
-    need = counts.least_time(paged_decode_attention.calls(MODEL, QUANT, s),
-                             PEAKS["bf16_flops"], PEAKS["hbm_bw"])
+    need = counts.least_time(
+        paged_decode_attention.calls(ARCH, MODEL, QUANT, s),
+        PEAKS["bf16_flops"], PEAKS["hbm_bw"])
     ctx = _ctx([s], {"kernel_s": {"paged_decode_attention": need}})
     read = load_reader(ROOT, "paged_decode_attention_roofline")
     assert read(ctx) == pytest.approx(100.0)
@@ -320,7 +364,7 @@ def test_host_readers():
     assert load_reader(ROOT, "setup_s")(ctx) == 12.5
     mfu = load_reader(ROOT, "mfu.decode_step")(ctx)
     assert 0 < mfu < 100
-    assert mfu == pytest.approx(100 * step.least_time(MODEL, steps[0],
+    assert mfu == pytest.approx(100 * step.least_time(ARCH, MODEL, steps[0],
                                                       PEAKS) / 0.5)
 
 

@@ -27,6 +27,7 @@ TOY_MODEL = {"hidden_size": 128, "num_hidden_layers": 2,
              "tie_word_embeddings": False, "rope_theta": 10000.0,
              "rms_norm_eps": 1e-05}
 TOY_SPEC = {
+    "arch": "dense_gqa",
     "model": TOY_MODEL,
     "quant": {"weights": "int8", "weight_bits": 8, "weight_group": 0,
               "act_bits": 8, "kv_bits": 8},
@@ -60,9 +61,13 @@ def read(ctx):
 def _toy_root(root, spec):
     """A checkout-like directory that holds only data: a BENCHMARK.json
     naming a toy configuration, a toy traffic mix and a toy metric, whose
-    files lie where the harness looks for them by name."""
+    files lie where the harness looks for them by name, beside a copy of
+    the configuration's architecture."""
     for sub in ("configs", "traffic", "metrics"):
         (root / "bench" / sub).mkdir(parents=True)
+    shutil.copytree(os.path.join(ROOT, "bench", "arch", spec["arch"]),
+                    root / "bench" / "arch" / spec["arch"],
+                    ignore=shutil.ignore_patterns("__pycache__"))
     (root / "bench" / "configs" / "toy.json").write_text(json.dumps(spec))
     (root / "bench" / "traffic" / "toy-mix.json").write_text(
         json.dumps(TOY_MIX))
@@ -140,7 +145,8 @@ def test_the_control_is_not_correct(seed):
     """The reference at the control precision (every int8 of W8A8 with
     int8 KV taken to int4), in the program's place, fails the limit that
     the program's own tokens pass."""
-    server = adapter.Server("toy", TOY_SPEC, seed)
+    arch = bench_run.load_arch(ROOT, TOY_SPEC)
+    server = adapter.Server("toy", TOY_SPEC, seed, arch)
     mix = dict(TOY_MIX, loop="closed", sessions=4, staggered=True,
                mode="slow_think",
                output_tokens={"dist": "uniform", "min": 20, "max": 60})
@@ -152,7 +158,7 @@ def test_the_control_is_not_correct(seed):
     done = [{"rid": r.rid, "n_out": r.n_out, "prompt": server.prompt(r.rid),
              "served": server.out(r.rid)} for r in loop.all]
     server.close()
-    got = check.gaps(seed, TOY_SPEC, done, control=True)
+    got = check.gaps(arch, seed, TOY_SPEC, done, control=True)
     limit = TOY_SPEC["check"]["logit_gap_limit"]
     assert got["logit_gap"] <= limit < got["control_gap"]
 
