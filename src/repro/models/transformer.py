@@ -556,9 +556,10 @@ def supports_paged(cfg) -> bool:
 
 def init_paged_pools(cfg, n_pages: int, page_size: int, kv_bits: int = 16,
                      dtype=jnp.bfloat16) -> dict:
-    """Per-block page pools with the (G, ...) stacked structure the decode
-    scan expects (mirrors init_caches), and (first_k_dense, ...) ones under
-    "dense" for the leading dense layers. kv_bits selects the pool dtype —
+    """Per-block page pools stacked (G, N, ...) over the groups (mirrors
+    init_caches), and (first_k_dense, N, ...) ones under "dense" for the
+    leading dense layers; the step programs view each stack as one pool of
+    G*N pages (`_paged_layers`). kv_bits selects the pool dtype —
     16 (dense `dtype`), 8 (int8 + per-(page, head) scales) or 4 (uint8
     nibble-packed int4, head_dim halved in storage); every jitted step
     below reads the pool dtype back off the leaves, so the same step
@@ -587,12 +588,12 @@ def _paged_embed(params, tokens, dtype):
         return params["embed"]["w"].astype(dtype)[tokens]
 
 
-def _paged_block(p, x, pool, btype, cfg, attend, qcfg, impl, valid):
-    """One block of a paged step: (x, the block's attention aux, the
-    expert layer's counters or None)."""
+def _paged_block(p, x, pool, off, btype, cfg, attend, qcfg, impl, valid):
+    """One block of a paged step: (x, what `attend` returns beside its
+    output, the expert layer's counters or None)."""
     with jax.named_scope("attention"):
         h = rms_norm(x, p["ln1"]["g"], cfg.norm_eps)
-        a, aux = attend(p["attn"], h, pool)
+        a, r = attend(p["attn"], h, pool, off)
         a = _post(p, "ln1_post", a, cfg)
     x = x + a
     stats = None
@@ -605,41 +606,63 @@ def _paged_block(p, x, pool, btype, cfg, attend, qcfg, impl, valid):
             m = mlp(p["mlp"], h, cfg.act, qcfg, impl)
         m = _post(p, "ln2_post", m, cfg)
     x = x + m
-    return x, aux, stats
+    return x, r, stats
 
 
-def _paged_layers(params, pools, x, cfg, attend, qcfg, impl, valid=None):
+def _flat_pool(stack: dict) -> dict:
+    """A stack's pools (L, N, ...) viewed as one pool of L*N pages (a
+    bitcast): layer l's page p is page l*N + p."""
+    return jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), stack)
+
+
+def _paged_layers(params, pools, x, cfg, attend, qcfg, impl, valid=None,
+                  writes: bool = True):
     """The blocks of a paged step: the leading dense layers, then the
-    pattern scanned over groups. `attend(p, h, pool)` is the step's
-    attention; it returns (out, aux). `valid` (B, C) marks the real tokens
-    (the expert layer routes the others nowhere). Returns (x, each stack's
-    aux, the expert layers' counters: {"expert_rows": (layers, E_held),
+    pattern scanned over groups. Each pool stack (L, N, ...) rides its scan
+    in the carry as one flat pool of L*N pages (`_flat_pool`), so a layer
+    writes its pages in place and no layer's pool is sliced out of the
+    stack or written back into it. `attend(p, h, pool, off)` is the step's
+    attention over that flat pool, the layer's page p at p + off (off =
+    l*N); with `writes` it returns (out, the written pool), else (out, aux)
+    and the pools are only read. `valid` (B, C) marks the real tokens (the
+    expert layer routes the others nowhere). Returns (x, the pools as
+    given (L, N, ...), each stack's aux stacked over its layers (read-only
+    steps), the expert layers' counters: {"expert_rows": (layers, E_held),
     "expert_absent": (layers,)}, or {} without a layer of experts)."""
-    out = {}
+    new_pools, aux = {}, {}
+
+    def scan(x, ps, btypes):
+        """Scan the stacks `btypes` ({key: block type}) of one layer
+        stack's params `ps` ({key: (L, ...)})."""
+        n_layers, n = pools[next(iter(btypes))]["k"].shape[:2]
+
+        def body(carry, scanned):
+            (x, flat), (p, off) = carry, scanned
+            flat, out, stats = dict(flat), {}, {}
+            for k, btype in btypes.items():
+                x, r, st = _paged_block(p[k], x, flat[k], off, btype, cfg,
+                                        attend, qcfg, impl, valid)
+                if writes:
+                    flat[k] = r
+                else:
+                    out[k] = r
+                if st is not None:
+                    stats[k] = st
+            return (x, flat), (out, stats)
+
+        flat = {k: _flat_pool(pools[k]) for k in btypes}
+        offs = jnp.arange(n_layers, dtype=jnp.int32) * n
+        (x, flat), (out, stats) = jax.lax.scan(body, (x, flat), (ps, offs))
+        for k in btypes:
+            new_pools[k] = jax.tree.map(lambda a, b: a.reshape(b.shape),
+                                        flat[k], pools[k])
+        aux.update(out)
+        return x, stats
+
     if cfg.first_k_dense:
-        def dense_body(x, scanned):
-            p, pool = scanned
-            x, aux, _ = _paged_block(p, x, pool, "self", cfg, attend, qcfg,
-                                     impl, valid)
-            return x, aux
-
-        x, out["dense"] = jax.lax.scan(dense_body, x,
-                                       (params["dense"], pools["dense"]))
-        pools = {k: v for k, v in pools.items() if k != "dense"}
-
-    def body(x, scanned):
-        gp, gpool = scanned
-        new, stats = {}, {}
-        for i, btype in enumerate(cfg.pattern):
-            x, new[str(i)], st = _paged_block(gp[str(i)], x, gpool[str(i)],
-                                              btype, cfg, attend, qcfg, impl,
-                                              valid)
-            if st is not None:
-                stats[str(i)] = st
-        return x, (new, stats)
-
-    x, (new, stats) = jax.lax.scan(body, x, (params["blocks"], pools))
-    out.update(new)
+        x, _ = scan(x, {"dense": params["dense"]}, {"dense": "self"})
+    x, stats = scan(x, params["blocks"],
+                    {str(i): b for i, b in enumerate(cfg.pattern)})
     counters = {}
     if stats:             # (G, positions) -> layers in order
         moe_pos = sorted(stats, key=int)
@@ -648,7 +671,7 @@ def _paged_layers(params, pools, x, cfg, attend, qcfg, impl, valid=None):
             "expert_rows": rows.reshape(-1, rows.shape[-1]),
             "expert_absent": jnp.stack([stats[i]["absent"]
                                         for i in moe_pos], 1).reshape(-1)}
-    return x, out, counters
+    return x, new_pools, aux, counters
 
 
 def _paged_head(params, x, cfg):
@@ -669,17 +692,17 @@ def decode_step_paged(params, pools, page_table, tokens, pos, cfg, *,
     if cfg.mla is not None:
         ones = jnp.ones_like(pos)
 
-        def attend(p, h, pool):
-            return attn.attn_mla_paged(p, h, cfg, pool, page_table, pos,
-                                       ones, qcfg=qcfg, impl=impl,
+        def attend(p, h, pool, off):
+            return attn.attn_mla_paged(p, h, cfg, pool, page_table + off,
+                                       pos, ones, qcfg=qcfg, impl=impl,
                                        paged_impl=paged_impl)
     else:
-        def attend(p, h, pool):
-            return attn.attn_decode_paged(p, h, cfg, pool, page_table, pos,
-                                          qcfg=qcfg, impl=impl,
+        def attend(p, h, pool, off):
+            return attn.attn_decode_paged(p, h, cfg, pool, page_table + off,
+                                          pos, qcfg=qcfg, impl=impl,
                                           paged_impl=paged_impl)
-    x, new_pools, counters = _paged_layers(params, pools, x, cfg, attend,
-                                           qcfg, impl, (pos > 0)[:, None])
+    x, new_pools, _, counters = _paged_layers(params, pools, x, cfg, attend,
+                                              qcfg, impl, (pos > 0)[:, None])
     logits = _paged_head(params, x, cfg)[:, 0]
     if with_stats:
         return logits, new_pools, counters
@@ -710,18 +733,19 @@ def prefill_chunk_paged(params, pools, page_table, window_rows, tokens,
     c = tokens.shape[1]
     x = _paged_embed(params, tokens, dtype)                   # (B, C, d)
     if cfg.mla is not None:
-        def attend(p, h, pool):
-            return attn.attn_mla_paged(p, h, cfg, pool, page_table, q_start,
-                                       n_new, window_rows, qcfg=qcfg,
-                                       impl=impl, paged_impl=paged_impl)
+        def attend(p, h, pool, off):
+            return attn.attn_mla_paged(p, h, cfg, pool, page_table + off,
+                                       q_start, n_new, window_rows + off,
+                                       qcfg=qcfg, impl=impl,
+                                       paged_impl=paged_impl)
     else:
-        def attend(p, h, pool):
+        def attend(p, h, pool, off):
             return attn.attn_prefill_chunk_paged(
-                p, h, cfg, pool, page_table, window_rows, q_start, n_new,
-                qcfg=qcfg, impl=impl, paged_impl=paged_impl)
+                p, h, cfg, pool, page_table + off, window_rows + off,
+                q_start, n_new, qcfg=qcfg, impl=impl, paged_impl=paged_impl)
     valid = jnp.arange(c)[None, :] < n_new[:, None]
-    x, new_pools, counters = _paged_layers(params, pools, x, cfg, attend,
-                                           qcfg, impl, valid)
+    x, new_pools, _, counters = _paged_layers(params, pools, x, cfg, attend,
+                                              qcfg, impl, valid)
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["final_norm"]["g"], cfg.norm_eps)
         last = jnp.clip(n_new - 1, 0, c - 1)
@@ -755,12 +779,12 @@ def verify_step_paged(params, pools, page_table, tokens, q_start, n_new,
     assert cfg.mla is None, "no speculative verify under latent attention"
     c = tokens.shape[1]
     x = _paged_embed(params, tokens, dtype)                   # (B, C, d)
-    x, kv_win, _ = _paged_layers(
+    x, _, kv_win, _ = _paged_layers(
         params, pools, x, cfg,
-        lambda p, h, pool: attn.attn_verify_paged(
-            p, h, cfg, pool, page_table, q_start, n_new, qcfg=qcfg,
+        lambda p, h, pool, off: attn.attn_verify_paged(
+            p, h, cfg, pool, page_table + off, q_start, n_new, qcfg=qcfg,
             impl=impl),
-        qcfg, impl, jnp.arange(c)[None, :] < n_new[:, None])
+        qcfg, impl, jnp.arange(c)[None, :] < n_new[:, None], writes=False)
     logits = _paged_head(params, x, cfg)
     return logits, kv_win
 

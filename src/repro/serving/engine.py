@@ -306,8 +306,10 @@ class ContinuousBatchingEngine:
         # layer of experts); they come back with the sampled tokens. The
         # pool buffers are donated into every steady-state program: each
         # step rewrites a page or two of multi-MB pools, and without
-        # input-output aliasing XLA copies every pool leaf per step. All
-        # call sites immediately rebind self.pools to the returned pools.
+        # input-output aliasing XLA copies every pool leaf per step (the
+        # layer scans write them in place, `transformer._paged_layers`).
+        # All call sites immediately rebind self.pools to the returned
+        # pools.
         def serve_decode(params, pools, page_table, tokens, pos):
             return transformer.decode_step_paged(
                 params, pools, page_table, tokens, pos, cfg, qcfg=qcfg,

@@ -1,8 +1,10 @@
 """Paged KV cache: a fixed pool of fixed-size pages with per-page int8 or
 packed-int4 quantization (per-head scales) and free-list reuse.
 
-Layout per transformer block (leading scan-group axis G added by
-`transformer.init_paged_pools`):
+Layout per transformer block (leading layer axis L added by
+`transformer.init_paged_pools`; inside a step program the layer scan views
+a stack as one pool of L*N pages, layer l's page p at l*N + p, and every
+helper below serves that view as it serves one layer's pool):
 
     k, v   : (P, n_kv_heads, page_size, head_dim)      int8 | cache dtype
              (P, n_kv_heads, page_size, head_dim // 2) uint8, kv_bits=4:
@@ -152,7 +154,7 @@ class PageAllocator:
 
 
 # ---------------------------------------------------------------------------
-# Device-side pool (single block, no G axis; callers vmap/scan over G)
+# Device-side pool (no layer axis: one layer's pages, or a stack's flat view)
 # ---------------------------------------------------------------------------
 
 def init_pool(cfg, n_pages: int, page_size: int, kv_bits: int = 16,

@@ -7,7 +7,9 @@ pages, 128-token chunks), so the VMEM their page walk takes and the shapes
 of its DMAs meet the chip's compiler at the size the benchmark runs; and
 openPangu-Ultra-MoE's two kernels at its cell's geometry (latent pages of
 576 for 128 heads, 64 lanes, a 641-column table; 8 held experts of 7680 ->
-2 x 2048 -> 7680, the rows of a decode and of a mixed step).
+2 x 2048 -> 7680, the rows of a decode and of a mixed step). The engine's
+decode and mixed step programs compile too, at both cells' page counts, to
+check that they write the KV pools in place.
 
 Nothing runs: the TPU compiler, which is installed here, compiles for a
 chip that is described and not attached, and refuses what the chip's
@@ -17,6 +19,10 @@ inside a module fixture, never at import, so every pytest-xdist worker
 collects the same tests and only the worker running this file loads the
 TPU library.
 """
+import dataclasses
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -144,3 +150,145 @@ def test_grouped_gemm_compiles_for_v5e(one_chip, tokens, k, n):
         ((rows, 1), jnp.float32), ((HELD, 1, n), jnp.float32),
         ((HELD,), jnp.int32))
     assert ops.compiled_kernels(text) == {"grouped_int8_matmul"}
+
+
+# -- the step programs write the pools in place --------------------------------
+
+_ARRAY = re.compile(r"\b([a-z]+[0-9]*)\[([0-9,]*)\]")
+_INSTR = re.compile(r"^\s*(ROOT\s+)?%([\w.-]+)\s*=\s*(.*?)\s([a-z][\w-]*)"
+                    r"\(([^)]*)\)(.*)$")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%([\w.-]+)\s.*\{$")
+_ALIAS = re.compile(r"\{(\d+)\}:\s*\((\d+),")
+_VIEWS = ("parameter", "get-tuple-element", "bitcast", "tuple", "while")
+_HLO_DTYPES = {"int8": "s8", "uint8": "u8", "bfloat16": "bf16"}
+
+
+def _instructions(text):
+    """Each instruction of a compiled module's text: {name: (the arrays of
+    its result as (element type, dims), opcode, operand names,
+    attributes)}, and {computation: its root's name}."""
+    ins, roots, comp = {}, {}, None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        root, name, shape, op, operands, attrs = m.groups()
+        arrays = [(t, tuple(int(d) for d in dims.split(",") if d))
+                  for t, dims in _ARRAY.findall(shape)]
+        ins[name] = (arrays, op, re.findall(r"%([\w.-]+)", operands), attrs)
+        if root:
+            roots[comp] = name
+    return ins, roots
+
+
+def _pool_sized_copies(text, pages):
+    """The instructions that make an array of pages (the pool's element
+    type, its last two dims a page's) as large as one layer's pages or
+    larger, other than views of a buffer (parameters, tuple elements,
+    bitcasts, the loop) and writes of a few pages into one in place (a
+    scatter or dynamic-update-slice, alone or as a fusion's root, whose
+    other operands are small). `pages`: one pool leaf (L, N, ...)."""
+    ins, roots = _instructions(text)
+    dtype, tile = _HLO_DTYPES[pages.dtype.name], tuple(pages.shape[-2:])
+    layer = math.prod(pages.shape[1:])
+
+    def big(name):
+        return any(t == dtype and math.prod(dims) >= layer
+                   and tuple(d for d in dims if d != 1)[-2:] == tile
+                   for t, dims in ins[name][0])
+
+    def in_place(name):
+        _, op, operands, _ = ins[name]
+        return (op in ("scatter", "dynamic-update-slice")
+                and not any(big(o) for o in operands[1:]))
+
+    out = []
+    for name, (_, op, _, attrs) in ins.items():
+        if op in _VIEWS or not big(name):
+            continue
+        if op == "fusion":
+            callee = re.search(r"calls=%([\w.-]+)", attrs).group(1)
+            if in_place(roots[callee]):
+                continue
+        elif in_place(name):
+            continue
+        out.append(name)
+    return out
+
+
+def _step_programs(one_chip, cfg, *, lanes, max_seq_len, n_pages,
+                   token_budget):
+    """The engine's decode and mixed step programs as a cell serves them
+    (W8A8, int8 pages of 16, the pools donated), compiled for one v5e chip
+    at the cell's page count: ({program: compiled text}, params, pools) with
+    params and pools as shapes. The engine itself holds a two-page pool;
+    its jitted programs take the pools' shapes from their arguments."""
+    from repro.core.quant import ptq
+    from repro.core.quant.qtypes import preset
+    from repro.models import transformer
+    from repro.serving.engine import ContinuousBatchingEngine
+    qcfg = preset("int8")
+    params = jax.eval_shape(lambda k: ptq.quantize_model(
+        transformer.init_params(k, cfg), cfg, qcfg), jax.random.PRNGKey(0))
+    pools = jax.eval_shape(
+        lambda: transformer.init_paged_pools(cfg, n_pages, PAGE, 8))
+    on_chip = lambda tree: jax.tree.map(                    # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    resolve = ops.resolve_impl
+    with pytest.MonkeyPatch.context() as mp:
+        # "auto" takes the kernels, as it does on the chip
+        mp.setattr(ops, "resolve_impl", lambda impl: "pallas"
+                   if (impl or "auto") == "auto" else resolve(impl))
+        eng = ContinuousBatchingEngine(
+            params, cfg, qcfg=qcfg, kv_bits=8, page_size=PAGE,
+            max_batch=lanes, max_seq_len=max_seq_len, n_pages=2,
+            chunk_pages=CHUNK // PAGE, token_budget=token_budget)
+        args = {"decode": eng._decode_args(),
+                "mixed": tuple(map(jnp.asarray, eng._mixed_args([], [])))}
+        texts = {name: prog.lower(on_chip(params), on_chip(pools),
+                                  *on_chip(args[name])).compile().as_text()
+                 for name, prog in (("decode", eng._decode),
+                                    ("mixed", eng._mixed))}
+    return texts, params, pools
+
+
+@pytest.mark.parametrize("geometry", ["pangu-1b", "pangu-ultra-moe"])
+def test_step_programs_write_the_pools_in_place(one_chip, geometry):
+    """The decode and mixed programs at a cell's page count make no copy of
+    a pool stack or of one layer's pool: no whole-pool copy, no layer's
+    pool sliced out of its stack or written back into it, only the pages a
+    step writes, in place; and every pool buffer passed in is the buffer
+    that comes out. Two layers in a scanned stack, the fewest at which the
+    scan stays a loop (a one-layer scan compiles to straight-line code)."""
+    if geometry == "pangu-1b":
+        # bench/configs/pangu-1b-w8a8.json's widths, two of its 26 layers
+        cfg = dataclasses.replace(get_arch("pangu_1b"), n_layers=2,
+                                  n_kv_heads=NKV, d_ff=6144)
+        texts, params, pools = _step_programs(
+            one_chip, cfg, lanes=LANES, max_seq_len=4624, n_pages=4625,
+            token_budget=2048)
+    else:
+        # bench/configs/pangu-ultra-moe-w8a8.json's block: its leading
+        # dense layer and two expert layers, its 8 held experts
+        base = get_arch("pangu_ultra_moe")
+        cfg = dataclasses.replace(
+            base, n_layers=3, first_k_dense=1, vocab=19200,
+            tie_embeddings=False,
+            moe=dataclasses.replace(base.moe, held=(0, HELD)))
+        texts, params, pools = _step_programs(
+            one_chip, cfg, lanes=MLA_LANES, max_seq_len=10256,
+            n_pages=MLA_PAGES, token_budget=8192)
+    n_params = len(jax.tree.leaves(params))
+    n_pools = len(jax.tree.leaves(pools))
+    for name, text in texts.items():
+        for stack in pools.values():
+            assert _pool_sized_copies(text, stack["k"]) == [], name
+        aliased = {int(o): int(p)
+                   for o, p in _ALIAS.findall(text.split("\n", 1)[0])}
+        # outputs (logits, pools, counters); arguments (params, pools, ...)
+        assert aliased == {1 + i: n_params + i for i in range(n_pools)}, name
