@@ -21,7 +21,7 @@ from repro.core.quant import calibrate, preset, ptq
 from repro.data import DataConfig, SyntheticLM, make_prompts
 from repro.models import transformer
 from repro.optim import adamw
-from repro.serving import ServingEngine
+from repro.serving import ContinuousBatchingEngine
 from repro.train import trainer
 
 BENCH_DIR = os.path.join(os.path.dirname(__file__), "..", "results", "bench")
@@ -167,9 +167,9 @@ def successor_accuracy(data, prompts, generations):
     return ok / max(total, 1)
 
 
-def engines_for(cfg, variants, kv_bits=16):
-    return {name: ServingEngine(p, cfg, qcfg=q, impl="xla" if q else None,
-                                kv_bits=kv_bits)
+def engines_for(cfg, variants):
+    return {name: ContinuousBatchingEngine(p, cfg, qcfg=q,
+                                           impl="xla" if q else None)
             for name, (q, p) in variants.items()}
 
 

@@ -29,8 +29,7 @@ def _rates(cfg, params, stats, data, prompts):
     for name, eng in engines.items():
         per_mode = {}
         for mode in cot.MODES:
-            res = eng.generate(prompts, max_new=64, mode=mode,
-                               sampler="greedy")
+            res = eng.run(prompts, mode=mode, max_new=64)
             per_mode[mode] = cot.repetition_rate(res.tokens)
         out[name] = per_mode
     return out

@@ -30,7 +30,7 @@ def main(print_rows=True):
     for mode in cot.MODES:
         for name in ("fp16", "int8"):
             t0 = time.time()
-            res = engines[name].generate(prompts, max_new=24, mode=mode)
+            res = engines[name].run(prompts, mode=mode, max_new=24)
             us = (time.time() - t0) / len(prompts) * 1e6
             acc = common.successor_accuracy(data, prompts, res.tokens)
             accs[(mode, name)] = acc

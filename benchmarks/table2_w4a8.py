@@ -28,7 +28,7 @@ def main(print_rows=True):
         us = (time.time() - t0) / 4 * 1e6
         top1, kl = common.agreement_and_kl(ref, pairs)
         kls[name] = kl
-        res = engines[name].generate(prompts, max_new=24, mode="slow_think")
+        res = engines[name].run(prompts, mode="slow_think", max_new=24)
         acc = common.successor_accuracy(data, prompts, res.tokens)
         rows.append(common.row(f"table2/{name}/ppl", us,
                                f"{common.perplexity(pairs):.3f}"))

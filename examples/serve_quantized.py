@@ -16,7 +16,7 @@ from repro.configs import get_arch, reduced
 from repro.core.quant import INT8, calibrate, ptq
 from repro.data import DataConfig, SyntheticLM, make_prompts
 from repro.optim import adamw
-from repro.serving import ServingEngine
+from repro.serving import ContinuousBatchingEngine
 from repro.train import trainer
 
 ap = argparse.ArgumentParser()
@@ -47,7 +47,8 @@ prompts = make_prompts(DataConfig(vocab=cfg.vocab, seq_len=64),
                        args.requests, 12)
 for name, (q, p) in {"fp16": (None, state.params),
                      "int8": (INT8, params_q)}.items():
-    eng = ServingEngine(p, cfg, qcfg=q, impl="xla" if q else None)
+    eng = ContinuousBatchingEngine(p, cfg, qcfg=q,
+                                   impl="xla" if q else None)
     study = eng.cot_study(prompts, max_new=args.max_new)
     for mode, r in study.items():
         print(f"[{name}] {mode:11s} mean_len={r['mean_len']:5.1f} "

@@ -2,21 +2,8 @@
 # Tier-1 CI: the exact commands the roadmap gates on.
 #   1. quantlint — AST rules + jaxpr dtype-flow invariants over src/ (blocking)
 #   2. pytest    — the tier-1 test suite
-#   3. serving bench (smoke) — KV bytes ratios (int8 <= 0.55, packed int4
-#      <= 0.30 of bf16), chunked-prefill speedup, prefix-cache
-#      warm-TTFT/hit-rate/decode-floor gates, speculative decoding gates
-#      (friendly speedup + bit-exact greedy, adversarial regression bound),
-#      int4 functional/bit-exactness gates, decode-latency and
-#      compile-count gates, pallas==xla token parity; metrics land in
-#      bench_smoke.json (uploaded as a CI artifact)
-#   4. serving bench (smoke, --kv-bits 4) — the same engine-level legs run
-#      entirely on packed-int4 pages; metrics land in bench_smoke_int4.json
-#      (uploaded as a separate CI artifact)
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH}
 python -m repro.analysis src
 python -m pytest -x -q "$@"
-python benchmarks/bench_serving.py --smoke --json bench_smoke.json
-python benchmarks/bench_serving.py --smoke --kv-bits 4 \
-    --json bench_smoke_int4.json

@@ -4,8 +4,8 @@ Each builder returns a `TraceSpec` over the *real* production code paths:
 the kernel ref oracles (the numerical contract the Pallas kernels are
 pinned to), the jitted Pallas kernels themselves (structural int8-accum
 check inside the kernel bodies), the PTQ-swapped transformer block, and the
-paged-serving decode step — the same path `benchmarks/bench_serving.py`
-drives through the continuous-batching engine.
+paged-serving decode and mixed steps — the programs the continuous-
+batching engine (`serving/engine.py`) jits and serves with.
 
 Input tagging is automatic for pytree arguments (`auto_tags`): QTensor
 leaves tag as quant data / per-channel scales, int8 pool pages as quant
@@ -188,7 +188,7 @@ def spec_ptq_block(qname: str = "int8") -> TraceSpec:
 
 
 def spec_serving_decode(kv_bits: int = 8) -> TraceSpec:
-    """The paged serving decode step (the path bench_serving.py measures):
+    """The paged serving decode step (the engine's `serve_decode`):
     int8 (or packed-int4 uint8, kv_bits=4) page pools + per-(page, head)
     scales through decode_step_paged — the int4 trace makes the
     packed-int4-never-upcast-before-shift invariant bite on serving."""
@@ -214,8 +214,8 @@ def spec_serving_decode(kv_bits: int = 8) -> TraceSpec:
 
 
 def spec_serving_prefill_chunk(kv_bits: int = 8) -> TraceSpec:
-    """The chunked mixed prefill/decode step (the chunked-engine path
-    bench_serving.py measures): fused quantize-on-write into int8 (or
+    """The chunked mixed prefill/decode step (the engine's `serve_mixed`):
+    fused quantize-on-write into int8 (or
     packed-int4, kv_bits=4) pages — scale-once and int8-accum must hold
     through write_chunk's dequant -> merge -> requantize (for int4: the
     shift-unpack, then pack-on-store) as well as the attention read."""

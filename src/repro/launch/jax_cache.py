@@ -1,8 +1,7 @@
 """JAX's persistent compilation cache for the entry points.
 
-Only entry points call `enable_compile_cache` (chip_smoke.py,
-`launch/serve.py` main, `benchmarks/bench_serving.py` main): importing a
-library module never changes where JAX caches, and the tests never do.
+Only entry points call `enable_compile_cache` (chip_smoke.py and
+`launch/serve.py` main): importing a library module never changes where JAX caches, and the tests never do.
 """
 from __future__ import annotations
 

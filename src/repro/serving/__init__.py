@@ -1,4 +1,4 @@
-from repro.serving.engine import (ServingEngine, GenerationResult,  # noqa
-                                  ContinuousBatchingEngine, ContinuousResult)
+from repro.serving.engine import (ContinuousBatchingEngine,  # noqa
+                                  ContinuousResult)
 from repro.serving import cot, kv_pool, prefix_cache, sampling, \
     scheduler  # noqa

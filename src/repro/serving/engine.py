@@ -1,33 +1,21 @@
-"""Serving engines: the legacy padded-batch engine and the continuous-
-batching engine over the paged, optionally int8-quantized KV pool.
+"""The serving engine: continuous batching over the paged, optionally
+int8- or packed-int4-quantized KV pool.
 
-`ServingEngine` (legacy): requests are packed left-aligned into fixed batch
-slots with a per-request `lengths` vector against dense per-slot caches;
-the whole batch enters and leaves together.
+A PagedScheduler admits and evicts requests *each step* into fixed batch
+slots; KV lives in fixed-size pages (serving/kv_pool.py) handed out from a
+free list, so memory scales with tokens actually held rather than slots x
+max_len, and finished sequences' pages are immediately reusable. The three
+CoT think modes are just different (directive token, stop policy) pairs
+feeding the same scheduler (cot.StopPolicy).
 
-`ContinuousBatchingEngine` (tentpole): a PagedScheduler admits/evicts
-requests *each step* into fixed batch slots; KV lives in fixed-size pages
-(serving/kv_pool.py) handed out from a free list, so memory scales with
-tokens actually held rather than slots x max_len, and finished sequences'
-pages are immediately reusable. The three CoT think modes are just
-different (directive token, stop policy) pairs feeding the same scheduler
-(cot.StopPolicy).
-
-Prefill admission comes in two modes:
-
-  * "chunked" (default, Sarathi/vLLM-style): prompts stream through the
-    scheduler in fixed-shape page-aligned chunks of `chunk_pages` pages.
-    Each step batches prompt chunks from up to `token_budget` worth of
-    prefilling slots *together with* every ongoing decode slot into one
-    jitted mixed step (`transformer.prefill_chunk_paged`) whose K/V is
-    quantized directly into int8 pages (`kv_pool.write_chunk`) — no dense
-    bf16 cache and no second `_to_pages` pass. Steady state compiles
-    exactly two programs: the mixed step (any prefill in flight) and the
-    pure decode step.
-  * "legacy" (per-admission prefill, kept for A/B): each admitted request
-    runs a one-shot dense prefill at a power-of-two page bucket, then its
-    cache is scattered into pages. One extra compilation per distinct
-    bucket; decode stalls while prefill runs.
+Prompts stream through the scheduler in fixed-shape page-aligned chunks of
+`chunk_pages` pages (Sarathi/vLLM-style chunked prefill). Each step batches
+prompt chunks from up to `token_budget` worth of prefilling slots
+*together with* every ongoing decode slot into one jitted mixed step
+(`transformer.prefill_chunk_paged`) whose K/V is quantized directly into
+the pool's pages (`kv_pool.write_chunk`), with no dense cache. Steady state
+compiles exactly two programs: the mixed step (any prefill in flight) and
+the pure decode step.
 """
 from __future__ import annotations
 
@@ -50,113 +38,6 @@ from repro.serving.scheduler import PagedScheduler, Request
 
 
 @dataclasses.dataclass
-class GenerationResult:
-    tokens: List[List[int]]          # generated tokens per request
-    modes: List[str]
-    prompt_lens: List[int]
-    steps_run: int
-
-
-class ServingEngine:
-    def __init__(self, params, cfg, *, qcfg=None, impl=None, kv_bits=16,
-                 eos_id: Optional[int] = None, dtype=jnp.bfloat16):
-        self.params = params
-        self.cfg = cfg
-        self.qcfg = qcfg
-        self.impl = impl
-        self.kv_bits = kv_bits
-        self.eos_id = eos_id
-        self.dtype = dtype
-        self._prefill = jax.jit(
-            partial(transformer.prefill, cfg=cfg, qcfg=qcfg, impl=impl,
-                    kv_bits=kv_bits, dtype=dtype),
-            static_argnames=("max_len",))
-        self._decode = jax.jit(
-            partial(transformer.decode_step, cfg=cfg, qcfg=qcfg, impl=impl,
-                    dtype=dtype))
-
-    # -- request packing ------------------------------------------------------
-
-    def _pack(self, prompts: Sequence[Sequence[int]]):
-        b = len(prompts)
-        lens = np.array([len(p) for p in prompts], np.int32)
-        s = int(lens.max())
-        toks = np.zeros((b, s), np.int32)
-        for i, p in enumerate(prompts):
-            toks[i, :len(p)] = p
-        return jnp.asarray(toks), jnp.asarray(lens)
-
-    # -- generation -----------------------------------------------------------
-
-    def generate(self, prompts: Sequence[Sequence[int]], *, max_new: int = 32,
-                 mode: str = "slow_think", sampler: str = "greedy",
-                 seed: int = 0, ctx=None) -> GenerationResult:
-        """Generate under a CoT mode. Directive token appended per paper §4.1;
-        per-request budgets follow the mode policy (auto_think adapts)."""
-        cfg = self.cfg
-        prompts = [cot.apply_mode(p, mode, cfg.vocab) for p in prompts]
-        budgets = np.array([cot.budget_for(mode, len(p), max_new)
-                            for p in prompts], np.int32)
-        toks, lens = self._pack(prompts)
-        b, s = toks.shape
-        max_len = s + int(budgets.max()) + 1
-
-        batch = {"tokens": toks, "lengths": lens}
-        if ctx is not None:
-            batch["ctx"] = ctx
-        logits, caches = self._prefill(self.params, batch, max_len=max_len)
-
-        sample = sampling.SAMPLERS[sampler]
-        key = jax.random.PRNGKey(seed)
-        pos = lens                       # next position to write per request
-        cur = (sample(logits) if sampler == "greedy"
-               else sample(logits, key))
-        out = [[] for _ in range(b)]
-        active = np.ones(b, bool)
-        steps = 0
-        for step in range(int(budgets.max())):
-            cur_np = np.asarray(cur)
-            for i in range(b):
-                if active[i]:
-                    out[i].append(int(cur_np[i]))
-                    if self.eos_id is not None and cur_np[i] == self.eos_id:
-                        active[i] = False
-                    if len(out[i]) >= budgets[i]:
-                        active[i] = False
-            if not active.any():
-                break
-            logits, caches = self._decode(self.params, caches, cur, pos)
-            key, sub = jax.random.split(key)
-            cur = (sample(logits) if sampler == "greedy"
-                   else sample(logits, sub))
-            pos = pos + 1
-            steps += 1
-        return GenerationResult(tokens=out, modes=[mode] * b,
-                                prompt_lens=[len(p) for p in prompts],
-                                steps_run=steps)
-
-    # -- paper-style analysis -------------------------------------------------
-
-    def cot_study(self, prompts, *, max_new=32, sampler="greedy", seed=0):
-        """Run all three CoT modes; return per-mode generations + stats
-        (Figure 2 lengths / Figure 4 repetition inputs)."""
-        results = {}
-        for mode in cot.MODES:
-            r = self.generate(prompts, max_new=max_new, mode=mode,
-                              sampler=sampler, seed=seed)
-            results[mode] = {
-                "generations": r.tokens,
-                "mean_len": float(np.mean([len(t) for t in r.tokens])),
-                "repetition_rate": cot.repetition_rate(r.tokens),
-            }
-        return results
-
-
-# ---------------------------------------------------------------------------
-# Continuous batching over the paged KV pool
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass
 class ContinuousResult:
     tokens: List[List[int]]          # generated tokens, submission order
     modes: List[str]
@@ -177,19 +58,22 @@ class ContinuousBatchingEngine:
 
     max_batch slots x ceil(max_seq_len / page_size) page-table columns; the
     pool defaults to full occupancy (every slot can reach max_seq_len) —
-    pass a smaller n_pages to exercise preemption. Greedy sampling (the
-    deterministic serving path the paper's CoT study measures).
+    pass a smaller n_pages to exercise preemption. sampler="greedy" (the
+    default) takes the argmax: the deterministic serving path the
+    benchmark and the paper's CoT study measure. sampler="temperature"
+    samples at `temperature` under a `top_p` nucleus, from a key seeded by
+    `seed` and split once a step.
 
-    prefix_cache=True (chunked mode only) shares quantized prompt pages
-    across requests via the page table: admission maps the longest cached
-    prefix of full prompt pages in bit-exact (no recompute), and only the
-    uncached tail is chunk-prefilled; finished requests promote their
-    prompt pages. Cache hits change page-table *contents*, never step
-    shapes, so compile_counts() stays at the two-program steady state.
+    prefix_cache=True shares quantized prompt pages across requests via
+    the page table: admission maps the longest cached prefix of full prompt
+    pages in bit-exact (no recompute), and only the uncached tail is
+    chunk-prefilled; finished requests promote their prompt pages. Cache
+    hits change page-table *contents*, never step shapes, so
+    compile_counts() stays at the two-program steady state.
 
-    spec_decode=k (chunked mode only) turns on draft-free self-speculative
-    decoding: in pure-decode steps an n-gram prompt-lookup drafter
-    (serving/draft.py) proposes up to k tokens per lane and one jitted
+    spec_decode=k turns on draft-free self-speculative decoding: in
+    pure-decode steps an n-gram prompt-lookup drafter (serving/draft.py)
+    proposes up to k tokens per lane and one jitted
     verify program (fixed k+1 window — at most one extra compilation)
     scores them all, committing accepted prefixes through the fused
     quantize-on-write path and rolling back rejected suffixes page-exactly
@@ -211,8 +95,8 @@ class ContinuousBatchingEngine:
                  page_size: int = 16, max_batch: int = 8,
                  max_seq_len: int = 256, n_pages: Optional[int] = None,
                  eos_id: Optional[int] = None, dtype=jnp.bfloat16,
-                 paged_impl: str = "auto", prefill_mode: str = "chunked",
-                 chunk_pages: int = 2, token_budget: Optional[int] = None,
+                 paged_impl: str = "auto", chunk_pages: int = 2,
+                 token_budget: Optional[int] = None,
                  prefix_cache: bool = False, spec_decode: int = 0,
                  sampler: str = "greedy", temperature: float = 0.8,
                  top_p: float = 1.0, seed: int = 0,
@@ -225,10 +109,9 @@ class ContinuousBatchingEngine:
             f"frontend={cfg.frontend!r} (need 'tokens')")
         assert kv_bits in (16, 8, 4), \
             f"kv_bits must be 16, 8 or 4 (packed int4); got {kv_bits}"
-        assert cfg.mla is None or (prefill_mode == "chunked"
-                                   and not spec_decode), \
-            "latent attention serves through chunked prefill, without " \
-            "speculative decoding (its pages are latent rows, no K and V)"
+        assert cfg.mla is None or not spec_decode, \
+            "latent attention serves without speculative decoding (its " \
+            "pages are latent rows, no K and V)"
         self.params = params
         self.cfg = cfg
         self.page_size = page_size
@@ -240,16 +123,11 @@ class ContinuousBatchingEngine:
             n_pages = 1 + max_batch * self.max_pages_per_seq
         self.pools = transformer.init_paged_pools(
             cfg, n_pages, page_size, kv_bits, dtype)
-        assert prefill_mode in ("chunked", "legacy"), prefill_mode
-        assert not (prefix_cache and prefill_mode == "legacy"), \
-            "prefix caching needs chunked prefill (one-shot prefill would " \
-            "rewrite shared pages)"
         self.prefix_cache = prefix_cache
         self.sched = PagedScheduler(
             n_slots=max_batch, n_pages=n_pages, page_size=page_size,
             max_pages_per_seq=self.max_pages_per_seq,
             prefix_cache=prefix_cache)
-        self.prefill_mode = prefill_mode
         self.chunk_tokens = chunk_pages * page_size
         if self.chunk_tokens > max_seq_len:
             raise ValueError(
@@ -262,9 +140,6 @@ class ContinuousBatchingEngine:
                              else self.chunk_tokens + max_batch)
         assert sampler in ("greedy", "temperature"), sampler
         assert spec_decode >= 0, spec_decode
-        assert not (spec_decode and prefill_mode == "legacy"), \
-            "speculative decoding needs chunked prefill (the verify step " \
-            "reuses the chunk-attention machinery)"
         self.sampler = sampler
         self.temperature = temperature
         self.top_p = top_p
@@ -295,10 +170,6 @@ class ContinuousBatchingEngine:
         self.draft_tokens = 0
         self.accepted_tokens = 0
 
-        self._prefill = jax.jit(
-            partial(transformer.prefill, cfg=cfg, qcfg=qcfg, impl=impl,
-                    kv_bits=16, dtype=dtype),
-            static_argnames=("max_len",))
         # The step programs are named (serve_decode, serve_mixed,
         # serve_verify, serve_sample) so that traces and compile logs show
         # which one ran. The decode and mixed programs return the expert
@@ -359,17 +230,6 @@ class ContinuousBatchingEngine:
         self._verify = jax.jit(serve_verify, donate_argnums=(1,))
         self._zero_key = jax.random.PRNGKey(0)
 
-        def to_pages(pools, caches, page_rows, lengths):
-            from repro.serving import kv_pool
-            new = dict(pools)
-            for i, c in caches.items():
-                new[i] = jax.vmap(kv_pool.write_prefill,
-                                  in_axes=(0, 0, 0, None, None))(
-                    pools[i], c["k"], c["v"], page_rows, lengths)
-            return new
-
-        self._to_pages = jax.jit(to_pages)
-
     # -- accounting -----------------------------------------------------------
 
     def kv_bytes_per_token(self) -> float:
@@ -381,13 +241,10 @@ class ContinuousBatchingEngine:
             / (n_pages * self.page_size)
 
     def compile_counts(self) -> Dict[str, int]:
-        """Compilation-cache sizes of the jitted step functions. Chunked
-        steady state is exactly {mixed: 1, decode: 1, prefill: 0,
-        verify: 0}; --spec-decode adds at most one `verify` program
-        (fixed k+1 window shape); legacy pays one `prefill` entry per
-        distinct power-of-two page bucket."""
-        return {"prefill": self._prefill._cache_size(),
-                "mixed": self._mixed._cache_size(),
+        """Compilation-cache sizes of the jitted step functions. Steady
+        state is exactly {mixed: 1, decode: 1, verify: 0}; spec_decode adds
+        at most one `verify` program (fixed k+1 window shape)."""
+        return {"mixed": self._mixed._cache_size(),
                 "decode": self._decode._cache_size(),
                 "verify": self._verify._cache_size()}
 
@@ -472,105 +329,27 @@ class ContinuousBatchingEngine:
         req.t_done = t
         self.telemetry.finish(req)
 
-    def _prefill_one(self, st, slot: int, req: Request) -> None:
-        """Legacy one-shot prefill, bucketed to the next power-of-two page
-        count so the compile count is O(log max_seq_len) rather than one
-        program per distinct prompt-page count."""
-        span = self.telemetry.span
-        page = self.page_size
-        n = len(req.prompt)
-        need = -(-n // page)
-        bucket_pages = 1 << (need - 1).bit_length()
-        bucket = bucket_pages * page
-        with span("serve.inputs"):
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, :n] = req.prompt
-            lens = jnp.asarray([n], jnp.int32)
-            toks = jnp.asarray(toks)
-            # bucket rows beyond the prompt's allocation scatter into
-            # scratch (write_prefill zeroes positions >= lengths, so the
-            # writes are 0s)
-            rows = np.full((1, bucket_pages), SCRATCH_PAGE, np.int32)
-            rows[0, :need] = self.sched.page_table[slot, :need]
-            rows = jnp.asarray(rows)
-        with span("serve.launch"):
-            logits, caches = self._prefill(
-                self.params, {"tokens": toks, "lengths": lens},
-                max_len=bucket)
-            self.pools = self._to_pages(self.pools, caches, rows, lens)
-        st.ran("mixed", [(slot, 0, n)], bucket)
-        with span("serve.wait") as wait:
-            tok = int(self._sample_tokens(logits)[0])
-        with span("serve.commit"):
-            self.prefill_tokens += n
-            req.prefill_steps += 1
-            self._emit(st, slot, req, tok, wait.t1)
-            if self._policies[req.rid].done(req.out):
-                self._complete(slot, wait.t1)
-
-    @staticmethod
-    def _read_counters(st, counters) -> None:
-        """The expert layers' counters of a step whose tokens were just
-        read back (the legacy loop's decode step)."""
-        if counters:
-            got = jax.device_get(counters)
-            st.experts(got["expert_rows"], got["expert_absent"])
-
-    def _sample_tokens(self, logits) -> np.ndarray:
-        """Sample next tokens per lane under the engine's sampler: greedy
-        argmax (keyless, deterministic — the path the CoT study measures)
-        or temperature with nucleus filtering (top_p=1.0 disables it)."""
+    def _sample_tokens(self, st, logits, counters) -> np.ndarray:
+        """Sample next tokens per lane under the engine's sampler and read
+        them back, with the expert layers' counters, in one transfer:
+        greedy argmax (keyless, deterministic — the path the benchmark and
+        the CoT study measure) or temperature with nucleus filtering
+        (top_p=1.0 disables it) on a fresh split of the engine's key."""
         if self.sampler == "greedy":
-            return np.asarray(self._sample(logits))
-        self._key, sub = jax.random.split(self._key)
-        return np.asarray(self._sample_t(logits, sub))
+            nxt = self._sample(logits)
+        else:
+            self._key, sub = jax.random.split(self._key)
+            nxt = self._sample_t(logits, sub)
+        if not counters:
+            return np.asarray(nxt)
+        nxt, got = jax.device_get((nxt, counters))
+        st.experts(got["expert_rows"], got["expert_absent"])
+        return nxt
 
     def step(self) -> bool:
         """One engine step. Returns whether any progress was made."""
         with self.telemetry.step() as st:
-            if self.prefill_mode == "legacy":
-                return self._step_legacy(st)
             return self._step_chunked(st)
-
-    def _step_legacy(self, st) -> bool:
-        """Admit + one-shot prefill per admission, then one batched decode."""
-        sched = self.sched
-        span = self.telemetry.span
-        progressed = False
-        while True:
-            # re-admit after prefill-time completions free their slots
-            with span("serve.admit"):
-                admitted = sched.admit()
-            if not admitted:
-                break
-            progressed = True
-            for slot, req in admitted:
-                self._prefill_one(st, slot, req)
-        with span("serve.plan"):
-            sched.ensure_decode_capacity()
-        if not sched.active:
-            return progressed
-        with span("serve.inputs"):
-            args = self._decode_args()
-        with span("serve.launch"):
-            logits, self.pools, counters = self._decode(self.params,
-                                                        self.pools, *args)
-        self.steps_run += 1
-        st.ran("mixed" if st.lanes else "decode",
-               [(s, int(sched.lengths[s]), 1) for s in sorted(sched.active)],
-               sched.n_slots, self._pages_walked(sched.lengths + 1))
-        with span("serve.wait") as wait:
-            nxt = self._sample_tokens(logits)
-            self._read_counters(st, counters)
-        with span("serve.commit"):
-            for slot in list(sched.active):
-                req = sched.active[slot]
-                sched.lengths[slot] += 1
-                self._emit(st, slot, req, int(nxt[slot]), wait.t1)
-                self.decode_tokens += 1
-                if self._policies[req.rid].done(req.out):
-                    self._complete(slot, wait.t1)
-        return True
 
     # -- chunked prefill ------------------------------------------------------
 
@@ -675,7 +454,7 @@ class ContinuousBatchingEngine:
                     self._spec_off -= 1
                 elif self._try_spec_step(st, decoding):
                     return True
-            # steady-state decode: same compiled program as legacy decode
+            # steady-state decode
             with span("serve.inputs"):
                 args = self._decode_args()
             with span("serve.launch"):
@@ -701,11 +480,7 @@ class ContinuousBatchingEngine:
                    self._pages_walked(q_start + n_new))
 
         with span("serve.wait") as wait:
-            if counters:     # one read-back: the tokens and the counters
-                nxt, got = jax.device_get((self._sample(logits), counters))
-                st.experts(got["expert_rows"], got["expert_absent"])
-            else:
-                nxt = np.asarray(self._sample(logits))
+            nxt = self._sample_tokens(st, logits, counters)
         t = wait.t1
         with span("serve.commit"):
             for slot in advancing:
@@ -717,7 +492,7 @@ class ContinuousBatchingEngine:
                 req.prefill_steps += 1
                 if int(sched.prefill_progress[slot]) == len(req.prompt):
                     # prompt fully in cache: logits at its last token yield
-                    # the first generated token (as legacy prefill does)
+                    # the first generated token
                     self._emit(st, slot, req, int(nxt[slot]), t)
                     if self._policies[req.rid].done(req.out):
                         self._complete(slot, t)
@@ -918,3 +693,18 @@ class ContinuousBatchingEngine:
             spec_steps=self.spec_steps - spec0,
             draft_tokens=self.draft_tokens - dr0,
             accepted_tokens=self.accepted_tokens - acc0)
+
+    def cot_study(self, prompts: Sequence[Sequence[int]], *,
+                  max_new: int = 32) -> Dict[str, dict]:
+        """Serve `prompts` under each CoT mode in turn: per mode the
+        generations, their mean length and their repetition rate (the
+        paper's Figure 2 lengths and Figure 4 repetition)."""
+        study = {}
+        for mode in cot.MODES:
+            r = self.run(prompts, mode=mode, max_new=max_new)
+            study[mode] = {
+                "generations": r.tokens,
+                "mean_len": float(np.mean([len(t) for t in r.tokens])),
+                "repetition_rate": cot.repetition_rate(r.tokens),
+            }
+        return study

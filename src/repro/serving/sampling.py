@@ -1,5 +1,5 @@
-"""Token samplers for the serving engine (greedy / temperature / top-k /
-top-p) and rejection-sampling acceptance for speculative decoding.
+"""Token samplers for the serving engine (greedy / temperature / top-p)
+and rejection-sampling acceptance for speculative decoding.
 
 `speculative_accept` scores a verified draft window: position 0 holds the
 last committed token, positions 1..n_new-1 hold drafter proposals, and
@@ -27,12 +27,6 @@ def temperature(logits, key, temp: float = 0.8):
     return jax.random.categorical(key, logits / temp, axis=-1).astype(jnp.int32)
 
 
-def top_k(logits, key, k: int = 40, temp: float = 0.8):
-    vals, idx = jax.lax.top_k(logits, k)
-    choice = jax.random.categorical(key, vals / temp, axis=-1)
-    return jnp.take_along_axis(idx, choice[..., None], -1)[..., 0].astype(jnp.int32)
-
-
 def filter_top_p(logits, p: float = 0.9):
     """Nucleus filter: keep the smallest set of top tokens whose probability
     mass reaches p (ties at the threshold are all kept); the rest drop to
@@ -50,10 +44,6 @@ def filter_top_p(logits, p: float = 0.9):
 def top_p(logits, key, p: float = 0.9, temp: float = 0.8):
     return jax.random.categorical(
         key, filter_top_p(logits / temp, p), axis=-1).astype(jnp.int32)
-
-
-SAMPLERS = {"greedy": greedy, "temperature": temperature, "top_k": top_k,
-            "top_p": top_p}
 
 
 def speculative_accept(logits, draft, n_new, key, *, mode: str = "greedy",

@@ -2,37 +2,32 @@
 
 Host-side control plane: requests wait in a FIFO, get admitted into one of
 `n_slots` fixed batch slots when a slot and enough pages are free, and
-release everything on completion. Two admission regimes share the slot/page
-machinery:
+release everything on completion. Prefill is chunked: a request is
+admitted with only its *first chunk's* pages; `prefill_progress[slot]`
+tracks how many prompt tokens have been written, pages are granted
+chunk-by-chunk via `grow_to`, and the engine batches chunks from several
+slots with ongoing decode slots into one mixed step under a token budget.
 
-  * legacy (per-admission prefill): a request needs all its prompt pages up
-    front; `lengths`/`prefill_progress` jump straight to the prompt length.
-  * chunked prefill: a request is admitted with only its *first chunk's*
-    pages; `prefill_progress[slot]` tracks how many prompt tokens have been
-    written, pages are granted chunk-by-chunk via `grow_to`, and the engine
-    batches chunks from several slots with ongoing decode slots into one
-    mixed step under a token budget.
+Capacity is ensured every step through `grow_to`: a sequence crossing a
+page boundary gets a fresh page from the free list; when the pool is
+exhausted, cold unreferenced prefix-cache pages are evicted first (LRU —
+the second-chance free list), and only then is the most-recently-admitted
+active request preempted (recompute-style: its pages are released —
+including a partially-prefilled prompt's — and it requeues at the front of
+the FIFO with its progress reset, generation restarting from the prompt:
+the vLLM-style answer to fragmentation-free oversubscription).
 
-Decode capacity is ensured every step: a sequence crossing a page boundary
-gets a fresh page from the free list; when the pool is exhausted, cold
-unreferenced prefix-cache pages are evicted first (LRU — the second-chance
-free list), and only then is the most-recently-admitted active request
-preempted (recompute-style: its pages are released — including a
-partially-prefilled prompt's — and it requeues at the front of the FIFO
-with its progress reset, generation restarting from the prompt: the
-vLLM-style answer to fragmentation-free oversubscription).
-
-With `prefix_cache=True` (chunked admission only), admission walks the
-prompt's chained page hashes (serving/prefix_cache.py) and maps the longest
-cached prefix of *full* pages straight into the request's page table
-(refcount +1 per shared page); `lengths`/`prefill_progress` start at the
-hit length and only the uncached tail is chunk-prefilled. At least one
-prompt token is always recomputed so the last-token logits exist. Finished
-requests promote their full prompt pages into the cache in `complete`.
-Releasing a slot — completion or preemption — only ever *decrements*
-refcounts through the single `_release` choke point: a shared page stays
-mapped for its other holders, and a cached page whose last holder leaves
-parks in the cache LRU instead of the free list.
+With `prefix_cache=True`, admission walks the prompt's chained page hashes
+(serving/prefix_cache.py) and maps the longest cached prefix of *full*
+pages straight into the request's page table (refcount +1 per shared page);
+`lengths`/`prefill_progress` start at the hit length and only the uncached
+tail is chunk-prefilled. At least one prompt token is always recomputed so
+the last-token logits exist. Finished requests promote their full prompt
+pages into the cache in `complete`. Releasing a slot — completion or
+preemption — only ever *decrements* refcounts through the single `_release`
+choke point: a shared page stays mapped for its other holders, and a cached
+page whose last holder leaves parks in the cache LRU instead of the free
+list.
 
 The device never sees any of this: it gets a dense (n_slots, W) page table,
 per-slot lengths, and last tokens. Inactive slots carry length 0 and a
@@ -124,39 +119,29 @@ class PagedScheduler:
 
     # -- admission -----------------------------------------------------------
 
-    def admit(self, max_prefill_pages: Optional[int] = None
-              ) -> List[Tuple[int, Request]]:
+    def admit(self, max_prefill_pages: int) -> List[Tuple[int, Request]]:
         """Admit FIFO-head requests while a slot + enough pages are free.
 
-        max_prefill_pages=None (legacy per-admission prefill): a request
-        needs all its prompt pages up front and enters fully prefilled
-        (the caller runs the one-shot prefill right after). The prefix
-        cache is bypassed — the one-shot prefill would rewrite shared
-        pages.
-
-        max_prefill_pages=k (chunked prefill): the longest cached prefix of
-        full prompt pages (if any) maps directly into the page table with a
-        refcount each, then the request needs only its first uncached
-        chunk's pages — min(tail pages, k) — and enters with
-        prefill_progress at the hit length; later chunks grow the page list
-        via grow_to. At least one prompt token is always left to recompute
-        so the mixed step produces last-token logits."""
+        The longest cached prefix of full prompt pages (if any) maps
+        directly into the page table with a refcount each, then the request
+        needs only its first uncached chunk's pages — min(tail pages,
+        max_prefill_pages) — and enters with prefill_progress at the hit
+        length; later chunks grow the page list via grow_to. At least one
+        prompt token is always left to recompute so the mixed step produces
+        last-token logits."""
         admitted = []
-        use_cache = self.cache is not None and max_prefill_pages is not None
         while self.waiting and self.free_slots:
             req = self.waiting[0]
             total = -(-len(req.prompt) // self.page_size)
             hashes: List[bytes] = []
             hits: List[int] = []
-            if use_cache:
+            if self.cache is not None:
                 hashes = page_hashes(req.prompt, self.page_size)
                 hits = self.cache.lookup(hashes)
                 if len(hits) * self.page_size >= len(req.prompt):
                     hits = hits[:-1]
             n_hit = len(hits)
-            need = total - n_hit
-            if max_prefill_pages is not None:
-                need = min(need, max_prefill_pages)
+            need = min(total - n_hit, max_prefill_pages)
             if hits:
                 # reference the hits before allocating the tail, so tail
                 # eviction can never reclaim them out from under us
@@ -172,13 +157,9 @@ class PagedScheduler:
             self.page_table[slot, :] = SCRATCH_PAGE
             self.page_table[slot, :n_hit + need] = hits + pages
             hit_tokens = n_hit * self.page_size
-            if max_prefill_pages is None:
-                self.lengths[slot] = len(req.prompt)
-                self.prefill_progress[slot] = len(req.prompt)
-            else:
-                self.lengths[slot] = hit_tokens
-                self.prefill_progress[slot] = hit_tokens
-            if use_cache:
+            self.lengths[slot] = hit_tokens
+            self.prefill_progress[slot] = hit_tokens
+            if self.cache is not None:
                 self._hashes[slot] = hashes
                 self.prefix_hit_tokens += hit_tokens
                 self.prefix_prompt_tokens += len(req.prompt)
@@ -191,7 +172,7 @@ class PagedScheduler:
             admitted.append((slot, req))
         return admitted
 
-    # -- slot phases (chunked prefill) ----------------------------------------
+    # -- slot phases ---------------------------------------------------------
 
     def prefilling_slots(self) -> List[int]:
         """Active slots whose prompt is not fully written yet, in admission
@@ -256,17 +237,6 @@ class PagedScheduler:
         self.seq_pages[slot] = pages[:need]
         self.page_table[slot, need:len(pages)] = SCRATCH_PAGE
         self._return_pages(surplus)
-
-    def ensure_decode_capacity(self) -> List[Request]:
-        """Each active decode slot writes position lengths[slot] this step;
-        grow its page list across page boundaries, preempting if the pool
-        is dry. Returns the preempted (requeued) requests."""
-        evicted = []
-        for slot in sorted(list(self.active)):
-            if slot not in self.active:        # evicted by an earlier slot
-                continue
-            evicted.extend(self.grow_to(slot, int(self.lengths[slot]) + 1))
-        return evicted
 
     def _return_pages(self, pages: List[int]) -> None:
         """THE page-release choke point: every refcount decrement the

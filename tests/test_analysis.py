@@ -4,8 +4,6 @@ Every AST rule and every flow invariant must catch its seeded fixture
 violation (tests/fixtures/quantlint/), and the real src/ tree plus the
 default dtype-flow suite must pass clean — the same gate scripts/ci.sh runs.
 """
-import importlib
-import sys
 from pathlib import Path
 
 import jax
@@ -216,24 +214,6 @@ def test_flow_kernel_suite_clean():
     # via `python -m repro.analysis src`)
     got = check_suite(default_specs(fast=True))
     assert got == [], "\n".join(f.format() for f in got)
-
-
-# ---------------------------------------------------------------------------
-# Satellite: benchmarks/bench_serving.py imports without side effects
-# ---------------------------------------------------------------------------
-
-def test_bench_serving_importable():
-    bench_dir = str(REPO / "benchmarks")
-    sys.path.insert(0, bench_dir)
-    try:
-        mod = importlib.import_module("bench_serving")
-        assert callable(mod.main)
-        # PYTHONPATH already resolves repro: the import must not have
-        # prepended its own src path
-        assert not any(p.endswith("benchmarks/../src") for p in sys.path)
-    finally:
-        sys.path.remove(bench_dir)
-        sys.modules.pop("bench_serving", None)
 
 
 # ---------------------------------------------------------------------------

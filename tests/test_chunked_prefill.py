@@ -1,8 +1,8 @@
 """Chunked-prefill regression tests: fused quantize-on-write page writes
 (`kv_pool.write_chunk` vs the one-shot and per-token paths) across all pool
 dtypes (bf16/int8/packed-int4), the chunk attention kernel (Pallas
-interpret vs jnp oracle vs dense causal SDPA), and chunked-vs-one-shot
-engine equivalence including preemption mid-prefill."""
+interpret vs jnp oracle vs dense causal SDPA), and the chunked engine
+against the dense forward, including preemption mid-prefill."""
 from types import SimpleNamespace
 
 import numpy as np
@@ -10,10 +10,11 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from conftest import make_engine, pool_leaves
+from conftest import dense_greedy, make_engine, pool_leaves
 from repro.kernels.paged_prefill import (paged_prefill_attention,
                                          paged_prefill_attention_ref)
 from repro.models import attention as attn
+from repro.models import transformer
 from repro.serving import kv_pool
 
 
@@ -73,7 +74,7 @@ def test_write_chunk_matches_write_prefill(kv_bits):
     """Page-aligned chunks of a prompt land bit-identical to the one-shot
     write_prefill scatter — same quantized codes (int8 bytes or packed int4
     nibbles) *and* same per-(page, head) scales (fused quantize-on-write is
-    not an approximation of the legacy two-pass path on fresh pages)."""
+    not an approximation of the one-shot two-pass path on fresh pages)."""
     page, nkv, hd, b, n = 8, 2, 16, 2, 40          # 5 pages
     c = 2 * page                                   # chunk = 2 pages
     wc = kv_pool.chunk_window_pages(c, page)
@@ -201,7 +202,7 @@ def test_paged_prefill_kernel_matches_ref(b, t, nq, nkv, hd, page, c, lanes,
 
 
 # ---------------------------------------------------------------------------
-# chunked vs one-shot prefill through the full model and engine
+# the chunked engine against the dense forward
 # ---------------------------------------------------------------------------
 
 def _run(engine, prompts, max_new=6):
@@ -209,61 +210,77 @@ def _run(engine, prompts, max_new=6):
 
 
 def test_chunked_engine_matches_legacy_fp16(cfg_params):
-    """fp16 pools: the chunked mixed-step engine reproduces the legacy
-    per-admission engine token-for-token, in exactly two steady-state
-    compilations (mixed + decode, zero one-shot prefills)."""
+    """fp16 pools: the chunked mixed-step engine reproduces the plain dense
+    greedy loop token-for-token, in exactly two steady-state compilations
+    (mixed + decode)."""
     cfg, params = cfg_params
     prompts = [[5, 6, 7], list(range(1, 20)), [9] * 11, [3, 1, 4, 1, 5]]
-    mk = dict(kv_bits=16, max_batch=4)
-    leg = make_engine(params, cfg, prefill_mode="legacy", **mk)
-    ch = make_engine(params, cfg, **mk)
-    want, got = _run(leg, prompts), _run(ch, prompts)
-    assert got.tokens == want.tokens
+    ch = make_engine(params, cfg, kv_bits=16, max_batch=4)
+    got = _run(ch, prompts)
+    assert got.tokens == dense_greedy(params, cfg, prompts, max_new=6)
     assert got.prefill_tokens == sum(got.prompt_lens)
     assert got.mixed_steps > 0
-    assert ch.compile_counts() == {"prefill": 0, "mixed": 1, "decode": 1,
-                                   "verify": 0}
+    assert ch.compile_counts() == {"mixed": 1, "decode": 1, "verify": 0}
 
 
 def test_chunked_engine_first_token_int8(cfg_params):
     """int8 pools: chunked prefill quantizes each chunk once into its pages
-    (the legacy path quantizes the whole prompt in one pass) — identical on
-    fresh aligned pages, so first sampled tokens agree."""
+    and reads them back, so its first sampled tokens agree with the dense
+    bf16 forward's."""
     cfg, params = cfg_params
     prompts = [list(range(1, 20)), [9] * 11, [3, 1, 4, 1, 5]]
-    leg = make_engine(params, cfg, kv_bits=8, prefill_mode="legacy")
-    ch = make_engine(params, cfg, kv_bits=8)
-    want, got = _run(leg, prompts), _run(ch, prompts)
-    first_leg = [t[0] for t in want.tokens]
+    got = _run(make_engine(params, cfg, kv_bits=8), prompts)
+    first_dense = [t[0] for t in dense_greedy(params, cfg, prompts,
+                                              max_new=6)]
     first_ch = [t[0] for t in got.tokens]
-    # legacy computes prompt logits from the dense bf16 forward; chunked
-    # reads the (re-rounded) int8 pages — allow one flip across requests
-    agree = sum(a == b for a, b in zip(first_leg, first_ch))
-    assert agree >= len(prompts) - 1, (first_leg, first_ch)
+    # the dense forward attends over bf16 K/V; chunked reads the
+    # (re-rounded) int8 pages — allow one flip across requests
+    agree = sum(a == b for a, b in zip(first_dense, first_ch))
+    assert agree >= len(prompts) - 1, (first_dense, first_ch)
 
 
 def test_chunked_pools_match_oneshot_pools(cfg_params, kv_bits):
     """After chunked prefill, every block's pages *and scales* (bf16 bytes,
     int8 codes, or packed int4 nibbles) equal the one-shot write_prefill of
-    the same dense prompt K/V."""
+    the dense forward's K/V for the same prompt."""
     cfg, params = cfg_params
     page, n = 8, 19
-    prompts = [list(range(1, n + 1))]
-    mk = dict(kv_bits=kv_bits, max_batch=1, max_seq_len=32)
-    leg = make_engine(params, cfg, prefill_mode="legacy", **mk)
-    ch = make_engine(params, cfg, **mk)
+    ch = make_engine(params, cfg, kv_bits=kv_bits, max_batch=1,
+                     max_seq_len=32)
     # run exactly the prefill portion: submit + step until the first token
-    for eng in (leg, ch):
-        eng.submit(prompts[0], mode="no_think", max_new=4)
-        while not any(r.out for r in eng._requests.values()):
-            eng.step()
-    used = np.asarray(leg.sched.page_table[0][:-(-n // page)])
-    assert (np.asarray(ch.sched.page_table[0][:len(used)]) == used).all()
-    for blk in leg.pools:
+    # (the request stays active, so its page-table row still maps it)
+    rid = ch.submit(list(range(1, n + 1)), mode="slow_think", max_new=4)
+    while not ch.request(rid).out:
+        ch.step()
+    prompt = ch.request(rid).prompt
+    need = -(-len(prompt) // page)
+    used = ch.sched.page_table[0][:need]
+    assert (used != kv_pool.SCRATCH_PAGE).all()
+    toks = np.zeros((1, need * page), np.int32)
+    toks[0, :len(prompt)] = prompt
+    lens = jnp.asarray([len(prompt)], jnp.int32)
+    rows = jnp.asarray(used[None])
+
+    # the dense forward and its one-shot write in one program, as the
+    # engine's mixed step is one: XLA then keeps K/V at the same precision
+    # up to the quantizer on both sides
+    def oneshot(params, toks, pools):
+        _, caches = transformer.prefill(
+            params, {"tokens": toks, "lengths": lens}, cfg,
+            max_len=need * page)
+        return {blk: jax.vmap(kv_pool.write_prefill,
+                              in_axes=(0, 0, 0, None, None))(
+                    pools[blk], c["k"], c["v"], rows, lens)
+                for blk, c in caches.items()}
+
+    want = jax.jit(oneshot)(params, jnp.asarray(toks),
+                            transformer.init_paged_pools(
+                                cfg, ch.sched.alloc.n_pages, page, kv_bits))
+    for blk in want:
         for name in pool_leaves(kv_bits):
             np.testing.assert_array_equal(
                 np.asarray(ch.pools[blk][name][:, used]),
-                np.asarray(leg.pools[blk][name][:, used]),
+                np.asarray(want[blk][name][:, used]),
                 err_msg=f"block {blk} {name}")
 
 

@@ -1,6 +1,7 @@
 """Paged decode-attention regression tests: q_len=1 against a long paged
 cache (Pallas interpret kernel vs jnp oracle vs the dense SDPA path), page
-pool quantization round-trips, and paged-vs-dense engine equivalence."""
+pool quantization round-trips, paged-vs-dense engine equivalence, and the
+engine's tokens under the Pallas kernels against the XLA read path."""
 from types import SimpleNamespace
 
 import numpy as np
@@ -8,6 +9,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from conftest import make_engine
 from repro.configs import get_arch, reduced
 from repro.kernels.paged_attn import (BLOCK_KEYS, block_pages,
                                       paged_decode_attention,
@@ -190,3 +192,15 @@ def test_paged_engine_matches_dense_decode():
         params, pools, jnp.asarray(pt), toks[:, s - 1], pos, cfg)
     np.testing.assert_allclose(np.asarray(d_paged), np.asarray(d_dense),
                                rtol=2e-2, atol=2e-2)
+
+
+def test_pallas_kernels_serve_the_xla_tokens(cfg_params, kv_bits):
+    """The engine with the paged Pallas kernels (interpret mode off the
+    chip) emits the same tokens as with the XLA read path, through mixed
+    and decode steps."""
+    cfg, params = cfg_params
+    prompts = [list(range(1, 20)), [9] * 11]
+    got = {impl: make_engine(params, cfg, kv_bits=kv_bits, max_batch=2,
+                             paged_impl=impl).run(prompts, max_new=8).tokens
+           for impl in ("xla", "pallas_interpret")}
+    assert got["pallas_interpret"] == got["xla"]

@@ -1,5 +1,6 @@
 """Unit tests for serving/sampling.py: the temperature / top-p paths and
-the rejection-sampling acceptance rule speculative decoding builds on.
+the rejection-sampling acceptance rule speculative decoding builds on, and
+the engine's own choice of sampler.
 
 The distributional checks drive one jitted call with a large batch of
 identical rows (speculative_accept draws independent uniforms per batch
@@ -10,6 +11,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from conftest import make_engine
 from repro.serving import sampling
 
 VOCAB = 8
@@ -158,3 +160,25 @@ def test_speculative_accept_preserves_target_distribution():
         freq = np.bincount(np.asarray(emit)[:, 0], minlength=VOCAB) / n
         want = np.asarray(jax.nn.softmax(row / 0.9))
         np.testing.assert_allclose(freq, want, atol=0.015)
+
+
+# -- the engine's sampler ----------------------------------------------------
+
+def test_engine_samples_with_its_sampler(cfg_params, kv_bits):
+    """sampler="temperature" reaches the chunked and decode steps: at a
+    high temperature the engine's tokens leave the greedy ones, and the
+    same seed repeats them exactly. Greedy is keyless: its tokens do not
+    depend on the seed."""
+    cfg, params = cfg_params
+    prompts = [list(range(1, 20)), [9] * 11, [3, 1, 4, 1, 5]]
+
+    def serve(**kw):
+        return make_engine(params, cfg, kv_bits=kv_bits, **kw).run(
+            prompts, max_new=8).tokens
+
+    greedy = serve()
+    assert serve(seed=1) == greedy
+    hot = serve(sampler="temperature", temperature=5.0, seed=3)
+    assert hot != greedy
+    assert serve(sampler="temperature", temperature=5.0, seed=3) == hot
+    assert all(0 <= t < cfg.vocab for toks in hot for t in toks)

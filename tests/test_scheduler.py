@@ -5,9 +5,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from conftest import dense_greedy
 from repro.configs import get_arch, reduced
 from repro.models import transformer
-from repro.serving import ContinuousBatchingEngine, ServingEngine
+from repro.serving import ContinuousBatchingEngine
 from repro.serving.kv_pool import SCRATCH_PAGE, PageAllocator
 from repro.serving.scheduler import PagedScheduler, Request
 
@@ -52,7 +53,7 @@ def test_admission_respects_slots_and_pages():
     s = PagedScheduler(n_slots=2, n_pages=5, page_size=4, max_pages_per_seq=4)
     for rid, n in enumerate([8, 4, 4]):       # 2, 1, 1 pages
         s.submit(mk_req(rid, n))
-    admitted = s.admit()
+    admitted = s.admit(max_prefill_pages=4)   # whole prompts
     # slots bound admission to 2 even though pages remain for the third
     assert [r.rid for _, r in admitted] == [0, 1]
     assert s.alloc.n_free == 1 and len(s.waiting) == 1
@@ -66,39 +67,39 @@ def test_admission_respects_slots_and_pages():
 def test_completion_reclaims_pages_and_slot():
     s = PagedScheduler(n_slots=1, n_pages=4, page_size=4, max_pages_per_seq=3)
     s.submit(mk_req(0, 12))                   # all 3 pages
-    [(slot, _)] = s.admit()
-    assert s.alloc.n_free == 0 and not s.admit()
+    [(slot, _)] = s.admit(max_prefill_pages=3)
+    assert s.alloc.n_free == 0 and not s.admit(max_prefill_pages=3)
     s.submit(mk_req(1, 12))
     s.complete(slot)
     assert s.alloc.n_free == 3
     assert (s.page_table[slot] == SCRATCH_PAGE).all() and s.lengths[slot] == 0
     # freed pages admit the waiting request immediately
-    assert [r.rid for _, r in s.admit()] == [1]
+    assert [r.rid for _, r in s.admit(max_prefill_pages=3)] == [1]
 
 
 def test_decode_capacity_growth_and_preemption():
     s = PagedScheduler(n_slots=2, n_pages=4, page_size=4, max_pages_per_seq=3)
     s.submit(mk_req(0, 3))
     s.submit(mk_req(1, 3))
-    s.admit()                                 # one page each, one free
+    s.admit(max_prefill_pages=3)              # one page each, one free
     # seq 0 crosses a page boundary -> grows from the free list
     s.lengths[0] = 4
-    assert s.ensure_decode_capacity() == []
+    assert s.grow_to(0, s.lengths[0] + 1) == []
     assert len(s.seq_pages[0]) == 2 and s.alloc.n_free == 0
     # seq 1 crosses next: pool dry -> the *newest* active is preempted, and
     # seq 1 is itself the newest: it yields instead of starving seq 0
     s.lengths[1] = 4
-    evicted = s.ensure_decode_capacity()
+    evicted = s.grow_to(1, s.lengths[1] + 1)
     assert [r.rid for r in evicted] == [1]
     assert evicted[0].out == [] and evicted[0].preemptions == 1
     assert s.waiting[0].rid == 1              # requeued at the front
     assert len(s.seq_pages[0]) == 2 and 0 in s.active and 1 not in s.active
     # with seq 1 gone its pages are free again: a re-admitted seq 1 whose
     # growth hits a dry pool is now the victim of choice for seq 0
-    [(slot1, _)] = s.admit()
+    [(slot1, _)] = s.admit(max_prefill_pages=3)
     s.lengths[slot1] = 2
     s.lengths[0] = 8                          # needs a third page
-    evicted = s.ensure_decode_capacity()
+    evicted = s.grow_to(0, s.lengths[0] + 1)
     assert [r.rid for r in evicted] == [1]    # oldest keeps progressing
     assert len(s.seq_pages[0]) == 3
 
@@ -129,16 +130,16 @@ def test_no_cross_sequence_leakage():
 
 
 def test_continuous_matches_legacy_engine():
-    """The paged engine (fp16 pool) reproduces the legacy dense engine."""
+    """The paged engine (fp16 pool) reproduces the plain dense greedy loop
+    on a second architecture (qk-norm, tied embeddings)."""
     cfg = reduced(get_arch("qwen3_0_6b"))
     params = transformer.init_params(jax.random.PRNGKey(0), cfg)
     prompts = [[5, 6, 7], list(range(1, 20)), [9] * 11]
-    ref = ServingEngine(params, cfg).generate(prompts, max_new=6,
-                                              mode="no_think")
     eng = ContinuousBatchingEngine(params, cfg, kv_bits=16, page_size=8,
                                    max_batch=3, max_seq_len=64)
     res = eng.run(prompts, mode="no_think", max_new=6)
-    assert res.tokens == ref.tokens
+    assert res.tokens == dense_greedy(params, cfg, prompts, mode="no_think",
+                                      max_new=6)
 
 
 def test_preemption_preserves_outputs():
@@ -176,3 +177,15 @@ def test_int8_pool_close_to_fp16_pool():
     assert agree >= 0.5, agree
     ratio = engines[8].kv_bytes_per_token() / engines[16].kv_bytes_per_token()
     assert ratio <= 0.55, ratio
+
+
+def test_int4_pool_bytes_under_three_tenths_of_fp16():
+    """Packed-int4 pages (two codes a byte along head_dim, one scale per
+    page and head) hold a token in at most 30% of the fp16 pool's bytes."""
+    cfg = reduced(get_arch("pangu_1b"))
+    params = transformer.init_params(jax.random.PRNGKey(0), cfg)
+    bpt = {kv_bits: ContinuousBatchingEngine(
+               params, cfg, kv_bits=kv_bits, page_size=16, max_batch=1,
+               max_seq_len=64).kv_bytes_per_token()
+           for kv_bits in (16, 4)}
+    assert bpt[4] / bpt[16] <= 0.30, bpt

@@ -14,7 +14,7 @@ from repro.core.quant import INT8, calibrate, ptq
 from repro.kernels.ref import paged_verify_attention_ref
 from repro.models import attention as attn
 from repro.models import transformer
-from repro.serving import ServingEngine, kv_pool
+from repro.serving import ContinuousBatchingEngine, kv_pool
 
 
 def setup(arch="qwen3_0_6b", s=16, b=2):
@@ -74,9 +74,9 @@ def test_engine_variable_length_prompts_quantized():
                                              0, cfg.vocab)}]
     stats = calibrate.collect_stats(params, batches, cfg)
     pq = ptq.quantize_model(params, cfg, INT8, stats)
-    eng = ServingEngine(pq, cfg, qcfg=INT8, impl="xla")
+    eng = ContinuousBatchingEngine(pq, cfg, qcfg=INT8, impl="xla")
     prompts = [[5, 6, 7], list(range(1, 20)), [9] * 11]
-    res = eng.generate(prompts, max_new=6, mode="slow_think")
+    res = eng.run(prompts, mode="slow_think", max_new=6)
     assert len(res.tokens) == 3
     assert all(len(t) == 6 for t in res.tokens)
     assert all(0 <= tok < cfg.vocab for t in res.tokens for tok in t)

@@ -163,8 +163,7 @@ def test_engine_spec_bf16_greedy_bit_exact(cfg_params):
     st = eng.spec_stats()
     assert st["accepted_tokens"] <= st["draft_tokens"]
     assert 0.0 <= st["acceptance_rate"] <= 1.0
-    assert eng.compile_counts() == {"prefill": 0, "mixed": 1, "decode": 1,
-                                    "verify": 1}
+    assert eng.compile_counts() == {"mixed": 1, "decode": 1, "verify": 1}
 
 
 def test_engine_spec_bit_exact_under_preemption(cfg_params):
@@ -212,9 +211,3 @@ def test_engine_spec_quantized_smoke(cfg_params, kv_bits):
         assert eng.compile_counts()["verify"] == 1
     assert sum(eng.compile_counts().values()) <= 3
 
-
-def test_spec_requires_chunked_prefill(cfg_params):
-    cfg, params = cfg_params
-    with pytest.raises(AssertionError, match="chunked"):
-        ContinuousBatchingEngine(params, cfg, prefill_mode="legacy",
-                                 spec_decode=4, **MK)

@@ -13,7 +13,7 @@ from repro.configs import get_arch, reduced
 from repro.data import DataConfig, SyntheticLM
 from repro.models import transformer
 from repro.optim import adamw
-from repro.serving import ServingEngine, cot
+from repro.serving import ContinuousBatchingEngine, cot
 from repro.train import trainer
 
 
@@ -111,7 +111,7 @@ def test_microbatch_equivalent_to_full():
 def test_engine_generates_and_modes_differ():
     cfg, data = tiny_setup()
     params = transformer.init_params(jax.random.PRNGKey(2), cfg)
-    eng = ServingEngine(params, cfg)
+    eng = ContinuousBatchingEngine(params, cfg)
     prompts = [[1, 2, 3, 4], list(range(40))]  # short + long prompt
     study = eng.cot_study(prompts, max_new=16)
     assert set(study) == set(cot.MODES)

@@ -10,7 +10,7 @@ from repro.core.quant import INT8, W4A8_SMOOTH, calibrate, ptq
 from repro.data import DataConfig, SyntheticLM, make_prompts
 from repro.models import transformer
 from repro.optim import adamw
-from repro.serving import ServingEngine, cot
+from repro.serving import ContinuousBatchingEngine, cot
 from repro.train import trainer
 
 
@@ -41,10 +41,10 @@ def test_full_ptq_serving_pipeline_int8(system):
     pq = ptq.quantize_model(params, cfg, INT8, stats)
     prompts = make_prompts(DataConfig(vocab=cfg.vocab, seq_len=48), 4, 10)
 
-    eng_fp = ServingEngine(params, cfg)
-    eng_q = ServingEngine(pq, cfg, qcfg=INT8, impl="xla")
-    out_fp = eng_fp.generate(prompts, max_new=12, mode="slow_think")
-    out_q = eng_q.generate(prompts, max_new=12, mode="slow_think")
+    eng_fp = ContinuousBatchingEngine(params, cfg)
+    eng_q = ContinuousBatchingEngine(pq, cfg, qcfg=INT8, impl="xla")
+    out_fp = eng_fp.run(prompts, mode="slow_think", max_new=12)
+    out_q = eng_q.run(prompts, mode="slow_think", max_new=12)
 
     # The chain picks successors uniformly among 4 branches, so greedy
     # argmax sits on near-ties: trajectories may diverge under quant noise
@@ -70,7 +70,7 @@ def test_full_pipeline_all_cot_modes_w4a8(system):
     semantics intact (budgets ordered, outputs in-vocab)."""
     cfg, params, data, stats = system
     pq = ptq.quantize_model(params, cfg, W4A8_SMOOTH, stats)
-    eng = ServingEngine(pq, cfg, qcfg=W4A8_SMOOTH, impl="xla")
+    eng = ContinuousBatchingEngine(pq, cfg, qcfg=W4A8_SMOOTH, impl="xla")
     prompts = make_prompts(DataConfig(vocab=cfg.vocab, seq_len=48), 3, 8)
     study = eng.cot_study(prompts, max_new=16)
     assert set(study) == set(cot.MODES)
