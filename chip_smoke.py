@@ -194,13 +194,16 @@ def step_logits(eng, cfg, prompts) -> None:
     toks = np.asarray([p[:c] for p in prompts], np.int32)
     zeros = np.zeros(LANES, np.int32)
     n_new = np.full(LANES, c, np.int32)
+    rows = transformer.pack_rows(toks, zeros, n_new, LANES * c)
     table, *args = [jnp.asarray(a)
-                    for a in (table, windows, toks, zeros, n_new)]
+                    for a in (table, windows, *rows, zeros, n_new)]
     pools = transformer.init_paged_pools(cfg, eng.sched.alloc.n_pages, PAGE,
                                          16)
 
-    got = eng._mixed(eng.params, jax.tree.map(jnp.copy, pools), table,
-                     *args)[0]
+    # every lane's chunk is more rows than the engine's own sizes hold: the
+    # engine's mixed program compiled at LANES x c rows, beside its own
+    kernel = eng._mixed.lower(eng.params, pools, table, *args).compile()
+    got = kernel(eng.params, jax.tree.map(jnp.copy, pools), table, *args)[0]
     mixed = jax.jit(partial(transformer.prefill_chunk_paged, cfg=cfg,
                             paged_impl="xla"))
     want, pools = mixed(eng.params, pools, table, *args)
@@ -237,8 +240,10 @@ def run_setting(cfg, quant: str, kv_bits: int, prompts, meter) -> None:
             "decode": {"paged_decode_attention"}}
     gemm = {"bf16": set(), "int8": {"quantize_act_dynamic", "int8_matmul"},
             "w4a8": {"quantize_act_dynamic", "w4a8_matmul"}}[quant]
-    for step, lowered in eng.lower_steps().items():
-        found = ops.compiled_kernels(lowered.compile().as_text())
+    lowered = eng.lower_steps()
+    for step, low in [("decode", lowered["decode"]),
+                      *(("mixed", m) for m in lowered["mixed"].values())]:
+        found = ops.compiled_kernels(low.compile().as_text())
         log(f"  compiled {step} step calls tpu_custom_call kernels: "
             f"{sorted(found)}")
         missing = (want[step] | gemm) - found
@@ -264,8 +269,9 @@ def run_setting(cfg, quant: str, kv_bits: int, prompts, meter) -> None:
     log(f"  sample: {tokens[0][:8]}")
     counts = eng.compile_counts()
     log(f"  compile_counts: {counts}")
-    check(counts["mixed"] == 1 and counts["decode"] == 1,
-          f"steady state is not one mixed + one decode program: {counts}")
+    check(counts["mixed"] == len(eng.row_sizes) and counts["decode"] == 1,
+          f"steady state is not one mixed program per row size "
+          f"{eng.row_sizes} + one decode program: {counts}")
     secs, hits, misses = (a - b for a, b in zip(meter.snapshot(), c0))
     peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
     log(f"  backend compile or cache load {secs!r} s; persistent cache "

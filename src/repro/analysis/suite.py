@@ -213,6 +213,15 @@ def spec_serving_decode(kv_bits: int = 8) -> TraceSpec:
     return TraceSpec(name, step, args, auto_tags(args))
 
 
+def _packed(b, c, q_start, n_new):
+    """A mixed step's packed rows (tokens, pos, lane, grid) for `b` lanes
+    of `c`-token chunks of token 0, on a row axis of b * c rows
+    (`transformer.pack_rows`)."""
+    from repro.models import transformer
+    return tuple(jnp.asarray(a) for a in transformer.pack_rows(
+        np.zeros((b, c), np.int32), q_start, n_new, b * c))
+
+
 def spec_serving_prefill_chunk(kv_bits: int = 8) -> TraceSpec:
     """The chunked mixed prefill/decode step (the engine's `serve_mixed`):
     fused quantize-on-write into int8 (or
@@ -229,19 +238,20 @@ def spec_serving_prefill_chunk(kv_bits: int = 8) -> TraceSpec:
     pools = transformer.init_paged_pools(cfg, n_pages, page, kv_bits=kv_bits)
     page_table = jnp.ones((b, w), jnp.int32)
     window_rows = jnp.ones((b, wc), jnp.int32)
-    tokens = jnp.zeros((b, c), jnp.int32)
     q_start = jnp.zeros((b,), jnp.int32)
     n_new = jnp.full((b,), c, jnp.int32)
+    rows = _packed(b, c, q_start, n_new)
 
-    def step(params, pools, page_table, window_rows, tokens, q_start, n_new):
+    def step(params, pools, page_table, window_rows, tokens, pos, lane, grid,
+             q_start, n_new):
         logits, _ = transformer.prefill_chunk_paged(
-            params, pools, page_table, window_rows, tokens, q_start, n_new,
-            cfg, paged_impl="xla")
+            params, pools, page_table, window_rows, tokens, pos, lane, grid,
+            q_start, n_new, cfg, paged_impl="xla")
         return logits
 
     name = ("serving_prefill_chunk" if kv_bits == 8
             else f"serving_prefill_chunk_int{kv_bits}")
-    args = (params, pools, page_table, window_rows, tokens, q_start, n_new)
+    args = (params, pools, page_table, window_rows, *rows, q_start, n_new)
     return TraceSpec(name, step, args, auto_tags(args))
 
 
@@ -265,17 +275,18 @@ def spec_serving_prefill_chunk_cached() -> TraceSpec:
         np.arange(1, 1 + w, dtype=np.int32)[None].repeat(b, 0))
     window_rows = jnp.asarray(
         np.arange(3, 3 + wc, dtype=np.int32)[None].repeat(b, 0))
-    tokens = jnp.zeros((b, c), jnp.int32)
     q_start = jnp.full((b,), 2 * page, jnp.int32)    # 2 pages served by cache
     n_new = jnp.full((b,), c, jnp.int32)
+    rows = _packed(b, c, q_start, n_new)
 
-    def step(params, pools, page_table, window_rows, tokens, q_start, n_new):
+    def step(params, pools, page_table, window_rows, tokens, pos, lane, grid,
+             q_start, n_new):
         logits, _ = transformer.prefill_chunk_paged(
-            params, pools, page_table, window_rows, tokens, q_start, n_new,
-            cfg, paged_impl="xla")
+            params, pools, page_table, window_rows, tokens, pos, lane, grid,
+            q_start, n_new, cfg, paged_impl="xla")
         return logits
 
-    args = (params, pools, page_table, window_rows, tokens, q_start, n_new)
+    args = (params, pools, page_table, window_rows, *rows, q_start, n_new)
     return TraceSpec("serving_prefill_chunk_cached", step, args,
                      auto_tags(args))
 
@@ -346,17 +357,19 @@ def spec_serving_mla_moe_step() -> TraceSpec:
     pools = transformer.init_paged_pools(cfg, n_pages, page, kv_bits=8)
     page_table = jnp.ones((b, w), jnp.int32)
     window_rows = jnp.ones((b, wc), jnp.int32)
-    tokens = jnp.zeros((b, c), jnp.int32)
     q_start = jnp.zeros((b,), jnp.int32)
     n_new = jnp.asarray([c, 1], jnp.int32)
+    rows = _packed(b, c, q_start, n_new)
 
-    def step(params, pools, page_table, window_rows, tokens, q_start, n_new):
+    def step(params, pools, page_table, window_rows, tokens, pos, lane, grid,
+             q_start, n_new):
         logits, _, counters = transformer.prefill_chunk_paged(
-            params, pools, page_table, window_rows, tokens, q_start, n_new,
-            cfg, qcfg=INT8, impl="xla", paged_impl="xla", with_stats=True)
+            params, pools, page_table, window_rows, tokens, pos, lane, grid,
+            q_start, n_new, cfg, qcfg=INT8, impl="xla", paged_impl="xla",
+            with_stats=True)
         return logits, counters
 
-    args = (params, pools, page_table, window_rows, tokens, q_start, n_new)
+    args = (params, pools, page_table, window_rows, *rows, q_start, n_new)
     return TraceSpec("serving_mla_moe_step", step, args, auto_tags(args))
 
 

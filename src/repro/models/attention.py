@@ -9,7 +9,7 @@ per-request vector (continuous batching).
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -391,33 +391,68 @@ def attn_decode_paged(p, x, cfg, pool: dict, page_table: jax.Array,
     return out, pool
 
 
+class PackedRows(NamedTuple):
+    """The mixed step's tokens on one row axis of T rows: each row's
+    absolute position `pos` (T,) and lane `lane` (T,), B for a padding row;
+    `grid` (B, C) the row that holds each lane's chunk slot, T for an empty
+    slot (`transformer.pack_rows` builds them on the host). The paged
+    kernels take queries, and `kv_pool.write_chunk` K/V, on the (B, C)
+    grid of lanes; everything else runs on the T rows."""
+    pos: jax.Array
+    lane: jax.Array
+    grid: jax.Array
+
+    @property
+    def real(self) -> jax.Array:
+        """(T,) the rows that hold a token."""
+        return self.lane < self.grid.shape[0]
+
+    def to_grid(self, x: jax.Array) -> jax.Array:
+        """(T, ...) -> (B, C, ...); an empty slot reads zeros."""
+        return jnp.take(x, self.grid, axis=0, mode="fill", fill_value=0)
+
+    def from_grid(self, y: jax.Array, q_start: jax.Array) -> jax.Array:
+        """(B, C, ...) -> (T, ...): each row's slot (chunk column pos -
+        q_start of its lane); a padding row reads zeros."""
+        b, c = self.grid.shape
+        lane = jnp.where(self.real, self.lane, 0)
+        slot = jnp.where(self.real, lane * c + self.pos - q_start[lane],
+                         b * c)
+        return jnp.take(y.reshape((b * c,) + y.shape[2:]), slot, axis=0,
+                        mode="fill", fill_value=0)
+
+
 def attn_prefill_chunk_paged(p, x, cfg, pool: dict, page_table: jax.Array,
-                             window_rows: jax.Array, q_start: jax.Array,
-                             n_new: jax.Array, *,
+                             window_rows: jax.Array, rows: PackedRows,
+                             q_start: jax.Array, n_new: jax.Array, *,
                              qcfg: Optional[QuantConfig] = None,
                              impl=None, paged_impl: Optional[str] = None):
     """Mixed chunked-prefill/decode attention step against the paged pool.
 
-    x: (B, C, d) chunk hidden states at absolute positions q_start[b] + i;
-    n_new: (B,) valid tokens this step (C = full prefill chunk, 1 = decode
-    slot riding the mixed step, 0 = idle slot); window_rows: (B, Wc)
-    physical pages covering the chunk's write window (kv_pool.write_chunk).
+    x: (T, d) the step's packed rows (`PackedRows`); n_new: (B,) real
+    tokens of each lane this step (a prefill chunk, 1 for a decode lane
+    riding the mixed step, 0 for an idle lane), from absolute position
+    q_start[b]; window_rows: (B, Wc) physical pages covering the chunk's
+    write window (kv_pool.write_chunk).
 
-    The chunk's K/V is quantized and written *directly* into its pages
-    (fused quantize-on-write — no dense cache), then the chunk queries
+    The projections run on the T rows. Their K/V is gathered onto the
+    lanes' (B, C) grid, quantized and written *directly* into its pages
+    (fused quantize-on-write — no dense cache), then the grid's queries
     attend causally over everything written so far, so intra-chunk
-    attention sees the same (re-rounded) pages decode will. Returns
-    (out (B, C, d), pool). paged_impl resolves as in `attn_decode_paged`."""
+    attention sees the same (re-rounded) pages decode will; the output
+    comes back onto the T rows (padding rows zero) before `wo`. Returns
+    (out (T, d), pool). paged_impl resolves as in `attn_decode_paged`."""
     from repro.kernels import ops, paged_prefill
     from repro.serving import kv_pool
     paged_impl = ops.resolve_impl(paged_impl)
-    b, c = x.shape[0], x.shape[1]
-    positions = q_start[:, None] + jnp.arange(c)[None, :]
-    q, k, v = _qkv(p, x, cfg, positions, qcfg, impl, None, "")
+    q, k, v = _qkv(p, x[None], cfg, rows.pos[None], qcfg, impl, None, "")
     with jax.named_scope("kv_write"):
-        pool = kv_pool.write_chunk(pool, k, v, window_rows, q_start, n_new)
+        pool = kv_pool.write_chunk(pool, rows.to_grid(k[0]),
+                                   rows.to_grid(v[0]), window_rows, q_start,
+                                   n_new)
     kv_len = jnp.maximum(q_start + n_new, 1)  # idle slots attend scratch
     ks, vs = pool.get("k_s"), pool.get("v_s")
+    q = rows.to_grid(q[0])
     with jax.named_scope("paged_attention"):
         if paged_impl != "xla":
             out = paged_prefill.paged_prefill_attention(
@@ -426,8 +461,8 @@ def attn_prefill_chunk_paged(p, x, cfg, pool: dict, page_table: jax.Array,
         else:
             out = paged_prefill.paged_prefill_attention_ref(
                 q, pool["k"], pool["v"], ks, vs, page_table, q_start, kv_len)
-    out = out.reshape(b, c, -1).astype(x.dtype)
-    out = qlinear.apply(p["wo"], out, qcfg, impl)
+    out = rows.from_grid(out, q_start).reshape(x.shape[0], -1)
+    out = qlinear.apply(p["wo"], out.astype(x.dtype), qcfg, impl)
     return out, pool
 
 
@@ -583,23 +618,10 @@ def mla_forward(p, x, cfg, positions, *, lengths=None, qcfg=None, impl=None,
     return qlinear.apply(p["wo"], out, qcfg, impl), lat
 
 
-def attn_mla_paged(p, x, cfg, pool: dict, page_table: jax.Array,
-                   q_start: jax.Array, n_new: jax.Array,
-                   window_rows: Optional[jax.Array] = None, *,
-                   qcfg: Optional[QuantConfig] = None, impl=None,
-                   paged_impl: Optional[str] = None):
-    """Absorbed MLA step against the latent pool (a K-only pool, one
-    latent row per token). x (B, C, d) at positions q_start[b] + i, n_new
-    (B,) real tokens of each lane. A decode step (C = 1, window_rows None)
-    writes its row with `kv_pool.write_token`; a mixed step writes its
-    chunk with `kv_pool.write_chunk` into window_rows. Rows past n_new come
-    out as zeros. paged_impl resolves as in `attn_decode_paged`; packed
-    int4 pools run the jnp oracle under "auto". Returns (out, pool)."""
-    from repro.kernels import ops, paged_mla
-    from repro.serving import kv_pool
-    a = cfg.mla
-    b, c = x.shape[0], x.shape[1]
-    positions = q_start[:, None] + jnp.arange(c)[None, :]
+def _mla_absorbed(p, x, cfg, positions, qcfg, impl):
+    """The absorbed step's projections of x (B, S, d): q_lat (B, S, H,
+    kv_lora_rank), q_rope (B, S, H, rope), the latent rows (B, S,
+    kv_lora_rank + rope) and W_UV."""
     q_nope, q_rope, lat = _mla_project(p, x, cfg, positions, qcfg, impl,
                                        None, "")
     w_uk, w_uv = _mla_up(p, cfg, x.dtype)
@@ -607,33 +629,83 @@ def attn_mla_paged(p, x, cfg, pool: dict, page_table: jax.Array,
         q_lat = jnp.einsum("bshn,chn->bshc", q_nope, w_uk,
                            preferred_element_type=jnp.float32
                            ).astype(x.dtype)
-    with jax.named_scope("kv_write"):
-        lat = jnp.pad(lat, ((0, 0), (0, 0), (0, a.pool_width - lat.shape[-1])))
-        if window_rows is None:
-            pool = kv_pool.write_token(pool, page_table, q_start,
-                                       lat[:, 0, None], None)
-        else:
-            pool = kv_pool.write_chunk(pool, lat[:, :, None], None,
-                                       window_rows, q_start, n_new)
+    return q_lat, q_rope, lat, w_uv
+
+
+def _mla_read(cfg, q_lat, q_rope, pool, page_table, q_start, n_new,
+              paged_impl):
+    """The absorbed queries' (B, C, H, .) context over the latent pages,
+    (B, C, H, kv_lora_rank); rows past n_new are undefined."""
+    from repro.kernels import ops, paged_mla
+    a = cfg.mla
     kv_len = jnp.maximum(q_start + n_new, 1)   # idle lanes attend scratch
     sm = 1.0 / float(a.nope_dim + a.rope_dim) ** 0.5
     impl_p = ops._dispatch(paged_impl, pool["k"].dtype != jnp.uint8,
                            "paged_mla_attention")
     with jax.named_scope("paged_attention"):
         if impl_p != "xla":
-            ctx = paged_mla.paged_mla_attention(
+            return paged_mla.paged_mla_attention(
                 q_lat, q_rope, pool["k"], pool.get("k_s"), page_table,
                 q_start, n_new, kv_len, sm=sm,
                 interpret=impl_p == "pallas_interpret")
-        else:
-            ctx = paged_mla.paged_mla_attention_ref(
-                q_lat, q_rope, pool["k"], pool.get("k_s"), page_table,
-                q_start, kv_len, sm=sm)
+        return paged_mla.paged_mla_attention_ref(
+            q_lat, q_rope, pool["k"], pool.get("k_s"), page_table,
+            q_start, kv_len, sm=sm)
+
+
+def _mla_out(p, ctx, w_uv, dtype, qcfg, impl):
+    """Context (..., H, kv_lora_rank) through W_UV and W_o -> (..., d)."""
     with jax.named_scope("mla_absorb"):
-        o = jnp.einsum("bshc,chv->bshv", ctx, w_uv,
-                       preferred_element_type=jnp.float32).astype(x.dtype)
-    out = qlinear.apply(p["wo"], o.reshape(b, c, -1), qcfg, impl)
-    if c > 1:          # rows past n_new are undefined in the kernel's output
-        valid = jnp.arange(c)[None, :] < n_new[:, None]
-        out = jnp.where(valid[..., None], out, 0)
-    return out, pool
+        o = jnp.einsum("...hc,chv->...hv", ctx, w_uv,
+                       preferred_element_type=jnp.float32).astype(dtype)
+    return qlinear.apply(p["wo"], o.reshape(o.shape[:-2] + (-1,)), qcfg,
+                         impl)
+
+
+def _latent_rows(lat, cfg):
+    """Latent rows zero-padded to the pool's row width."""
+    pad = cfg.mla.pool_width - lat.shape[-1]
+    return jnp.pad(lat, [(0, 0)] * (lat.ndim - 1) + [(0, pad)])
+
+
+def attn_mla_paged(p, x, cfg, pool: dict, page_table: jax.Array,
+                   pos: jax.Array, *, qcfg: Optional[QuantConfig] = None,
+                   impl=None, paged_impl: Optional[str] = None):
+    """Absorbed MLA decode step against the latent pool (a K-only pool,
+    one latent row per token). x (B, 1, d) at positions pos (B,); the row
+    is written with `kv_pool.write_token`. paged_impl resolves as in
+    `attn_decode_paged`; packed int4 pools run the jnp oracle under
+    "auto". Returns (out (B, 1, d), pool)."""
+    from repro.serving import kv_pool
+    q_lat, q_rope, lat, w_uv = _mla_absorbed(p, x, cfg, pos[:, None], qcfg,
+                                             impl)
+    with jax.named_scope("kv_write"):
+        pool = kv_pool.write_token(pool, page_table, pos,
+                                   _latent_rows(lat, cfg), None)
+    ctx = _mla_read(cfg, q_lat, q_rope, pool, page_table, pos,
+                    jnp.ones_like(pos), paged_impl)
+    return _mla_out(p, ctx, w_uv, x.dtype, qcfg, impl), pool
+
+
+def attn_mla_chunk_paged(p, x, cfg, pool: dict, page_table: jax.Array,
+                         window_rows: jax.Array, rows: PackedRows,
+                         q_start: jax.Array, n_new: jax.Array, *,
+                         qcfg: Optional[QuantConfig] = None, impl=None,
+                         paged_impl: Optional[str] = None):
+    """Absorbed MLA mixed step: x (T, d) the step's packed rows, lanes as
+    in `attn_prefill_chunk_paged`. The projections and the absorption run
+    on the T rows; the latent rows go onto the lanes' (B, C) grid and into
+    window_rows through `kv_pool.write_chunk`, the absorbed queries onto
+    the grid for the kernel, and its context back onto the T rows (padding
+    rows zero) before W_UV and W_o. Returns (out (T, d), pool)."""
+    from repro.serving import kv_pool
+    q_lat, q_rope, lat, w_uv = _mla_absorbed(p, x[None], cfg,
+                                             rows.pos[None], qcfg, impl)
+    with jax.named_scope("kv_write"):
+        lat = rows.to_grid(_latent_rows(lat[0], cfg))
+        pool = kv_pool.write_chunk(pool, lat[:, :, None], None, window_rows,
+                                   q_start, n_new)
+    ctx = _mla_read(cfg, rows.to_grid(q_lat[0]), rows.to_grid(q_rope[0]),
+                    pool, page_table, q_start, n_new, paged_impl)
+    ctx = rows.from_grid(ctx, q_start)
+    return _mla_out(p, ctx, w_uv, x.dtype, qcfg, impl), pool

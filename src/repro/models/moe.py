@@ -278,17 +278,18 @@ def dropless_moe(p: dict, x: jax.Array, cfg, qcfg=None, impl=None,
 
     with w_e the router's gates (`route`). Dropless: every (token, expert)
     pair that falls on a held expert is computed, so a token's output does
-    not depend on the rest of its batch; tokens that `valid` (B, S) marks
-    False (padding rows of a step) are routed nowhere. The pairs, sorted by
-    held expert, run as rows of one grouped GEMM per linear; pairs routed
-    to experts this chip does not hold are counted and left out.
+    not depend on the rest of its batch; tokens that `valid` (x's leading
+    shape) marks False (padding rows of a step) are routed nowhere. The
+    pairs, sorted by held expert, run as rows of one grouped GEMM per
+    linear, T * min(top_k, E_held) rows for T tokens; pairs routed to
+    experts this chip does not hold are counted and left out.
 
-    x (B, S, d) -> (y (B, S, d), {"rows": (E_held,) rows each held expert
+    x (..., d) -> (y (..., d), {"rows": (E_held,) rows each held expert
     took, "absent": pairs routed to experts not held})."""
     m = cfg.moe
-    b, s, d = x.shape
+    d = x.shape[-1]
     k = m.top_k
-    xt = x.reshape(b * s, d)
+    xt = x.reshape(-1, d)
     t = xt.shape[0]
     if taps is not None:
         taps.record(tap_prefix + "mlp_in", xt)
@@ -336,4 +337,4 @@ def dropless_moe(p: dict, x: jax.Array, cfg, qcfg=None, impl=None,
                             tap_prefix + "shared_").astype(jnp.float32)
     stats = {"rows": sizes.astype(jnp.int32),
              "absent": absent.astype(jnp.int32)}
-    return out.reshape(b, s, d).astype(x.dtype), stats
+    return out.reshape(x.shape).astype(x.dtype), stats

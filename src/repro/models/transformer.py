@@ -36,6 +36,7 @@ from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.quant import qlinear
 from repro.models import attention as attn
@@ -624,11 +625,12 @@ def _paged_layers(params, pools, x, cfg, attend, qcfg, impl, valid=None,
     stack or written back into it. `attend(p, h, pool, off)` is the step's
     attention over that flat pool, the layer's page p at p + off (off =
     l*N); with `writes` it returns (out, the written pool), else (out, aux)
-    and the pools are only read. `valid` (B, C) marks the real tokens (the
-    expert layer routes the others nowhere). Returns (x, the pools as
-    given (L, N, ...), each stack's aux stacked over its layers (read-only
-    steps), the expert layers' counters: {"expert_rows": (layers, E_held),
-    "expert_absent": (layers,)}, or {} without a layer of experts)."""
+    and the pools are only read. `valid` (x's leading shape) marks the
+    real tokens (the expert layer routes the others nowhere). Returns (x,
+    the pools as given (L, N, ...), each stack's aux stacked over its
+    layers (read-only steps), the expert layers' counters: {"expert_rows":
+    (layers, E_held), "expert_absent": (layers,)}, or {} without a layer
+    of experts)."""
     new_pools, aux = {}, {}
 
     def scan(x, ps, btypes):
@@ -690,11 +692,9 @@ def decode_step_paged(params, pools, page_table, tokens, pos, cfg, *,
     counters (`_paged_layers`) third."""
     x = _paged_embed(params, tokens, dtype)[:, None, :]
     if cfg.mla is not None:
-        ones = jnp.ones_like(pos)
-
         def attend(p, h, pool, off):
             return attn.attn_mla_paged(p, h, cfg, pool, page_table + off,
-                                       pos, ones, qcfg=qcfg, impl=impl,
+                                       pos, qcfg=qcfg, impl=impl,
                                        paged_impl=paged_impl)
     else:
         def attend(p, h, pool, off):
@@ -709,48 +709,74 @@ def decode_step_paged(params, pools, page_table, tokens, pos, cfg, *,
     return logits, new_pools
 
 
-def prefill_chunk_paged(params, pools, page_table, window_rows, tokens,
-                        q_start, n_new, cfg, *, qcfg=None, impl=None,
-                        paged_impl=None, dtype=jnp.bfloat16,
+def pack_rows(tokens, q_start, n_new, t: int, order=None):
+    """Lay a mixed step's real tokens on one row axis of `t` rows, on the
+    host. tokens (B, C): lane b's tokens in columns [0, n_new[b]), from
+    absolute position q_start[b]; order: the lanes in the order their
+    tokens take the rows (by default every lane with a token, in lane
+    order). Returns numpy (tokens (T,), pos (T,), lane (T,), grid (B, C))
+    as `prefill_chunk_paged` takes them: padding rows hold token 0 at
+    position 0 in lane B, and an empty slot of the grid names row T."""
+    tokens, q_start, n_new = (np.asarray(a, np.int32)
+                              for a in (tokens, q_start, n_new))
+    b, c = tokens.shape
+    order = np.flatnonzero(n_new) if order is None else np.asarray(
+        order, np.int64)
+    n = n_new[order]
+    lane = np.repeat(order, n)                                 # real rows
+    col = np.arange(lane.size) - np.repeat(np.cumsum(n) - n, n)
+    assert lane.size <= t, (lane.size, t)
+    packed = np.zeros((3, t), np.int32)                  # tokens, pos, lane
+    packed[2] = b
+    packed[:, :lane.size] = tokens[lane, col], q_start[lane] + col, lane
+    grid = np.full((b, c), t, np.int32)
+    grid[lane, col] = np.arange(lane.size)
+    return packed[0], packed[1], packed[2], grid
+
+
+def prefill_chunk_paged(params, pools, page_table, window_rows, tokens, pos,
+                        lane, grid, q_start, n_new, cfg, *, qcfg=None,
+                        impl=None, paged_impl=None, dtype=jnp.bfloat16,
                         with_stats: bool = False):
     """One mixed chunked-prefill/decode step against paged KV pools — the
-    single steady-state "mixed" compilation of the continuous-batching
-    engine (C = chunk width is static; every step has the same shape, so
-    decode latency stays flat while long prompts stream in chunks).
+    continuous-batching engine's mixed program, one compilation for each
+    size T of its row axis.
 
-    tokens: (B, C) int32 — a prompt chunk for prefilling slots, the last
-    sampled token in column 0 for decode slots, zeros for idle slots;
-    q_start: (B,) absolute position of chunk token 0 (== tokens already in
-    cache); n_new: (B,) valid tokens (C/partial = prefill chunk, 1 =
-    decode, 0 = idle); window_rows: (B, Wc) write-window pages
-    (kv_pool.write_chunk); page_table: (B, W) full table for reads.
+    The step's real tokens lie packed on one row axis (`pack_rows`):
+    tokens, pos, lane (T,) — each row's token, absolute position and lane
+    (B for a padding row) — and grid (B, C), the row of each lane's chunk
+    slot (T for an empty one). q_start: (B,) absolute position of a lane's
+    first new token (== tokens already in cache); n_new: (B,) its real
+    tokens (a prefill chunk or part of one, 1 = decode, 0 = idle);
+    window_rows: (B, Wc) write-window pages (kv_pool.write_chunk);
+    page_table: (B, W) full table for reads.
 
-    Each block quantizes the chunk's K/V straight into int8 or packed-int4
-    pages (per-(page, head) scales) and attends causally over written pages
-    plus the in-flight chunk. Returns (logits (B, V) f32 at each slot's
-    last valid token, pools), and with `with_stats` the expert layers'
+    The embedding, norms, linears and the expert layer run on the T rows;
+    each block's attention gathers its K/V and queries onto the (B, C)
+    grid, quantizes the K/V straight into int8 or packed-int4 pages
+    (per-(page, head) scales), attends causally over written pages plus
+    the in-flight chunk, and brings its output back onto the rows
+    (`attention.PackedRows`). Returns (logits (B, V) f32 at each lane's
+    last real row, pools), and with `with_stats` the expert layers'
     counters third."""
-    c = tokens.shape[1]
-    x = _paged_embed(params, tokens, dtype)                   # (B, C, d)
+    rows = attn.PackedRows(pos, lane, grid)
+    x = _paged_embed(params, tokens, dtype)                   # (T, d)
     if cfg.mla is not None:
         def attend(p, h, pool, off):
-            return attn.attn_mla_paged(p, h, cfg, pool, page_table + off,
-                                       q_start, n_new, window_rows + off,
-                                       qcfg=qcfg, impl=impl,
-                                       paged_impl=paged_impl)
+            return attn.attn_mla_chunk_paged(
+                p, h, cfg, pool, page_table + off, window_rows + off, rows,
+                q_start, n_new, qcfg=qcfg, impl=impl, paged_impl=paged_impl)
     else:
         def attend(p, h, pool, off):
             return attn.attn_prefill_chunk_paged(
-                p, h, cfg, pool, page_table + off, window_rows + off,
+                p, h, cfg, pool, page_table + off, window_rows + off, rows,
                 q_start, n_new, qcfg=qcfg, impl=impl, paged_impl=paged_impl)
-    valid = jnp.arange(c)[None, :] < n_new[:, None]
     x, new_pools, _, counters = _paged_layers(params, pools, x, cfg, attend,
-                                              qcfg, impl, valid)
-    with jax.named_scope("lm_head"):
-        x = rms_norm(x, params["final_norm"]["g"], cfg.norm_eps)
-        last = jnp.clip(n_new - 1, 0, c - 1)
-        x_last = x[jnp.arange(x.shape[0]), last]
-        logits = _lm_logits(params, x_last[:, None], cfg)[:, 0]
+                                              qcfg, impl, rows.real)
+    b, c = grid.shape
+    last = grid[jnp.arange(b), jnp.clip(n_new - 1, 0, c - 1)]
+    x = jnp.take(x, last, axis=0, mode="clip")[:, None]      # (B, 1, d)
+    logits = _paged_head(params, x, cfg)[:, 0]
     if with_stats:
         return logits, new_pools, counters
     return logits, new_pools

@@ -8,14 +8,16 @@ max_len, and finished sequences' pages are immediately reusable. The three
 CoT think modes are just different (directive token, stop policy) pairs
 feeding the same scheduler (cot.StopPolicy).
 
-Prompts stream through the scheduler in fixed-shape page-aligned chunks of
+Prompts stream through the scheduler in page-aligned chunks of
 `chunk_pages` pages (Sarathi/vLLM-style chunked prefill). Each step batches
 prompt chunks from up to `token_budget` worth of prefilling slots
-*together with* every ongoing decode slot into one jitted mixed step
+*together with* every ongoing decode slot into one mixed step
 (`transformer.prefill_chunk_paged`) whose K/V is quantized directly into
-the pool's pages (`kv_pool.write_chunk`), with no dense cache. Steady state
-compiles exactly two programs: the mixed step (any prefill in flight) and
-the pure decode step.
+the pool's pages (`kv_pool.write_chunk`), with no dense cache. The mixed
+step carries its real tokens packed on one row axis, whose size is the
+smallest of a few (`mixed_row_sizes`) that holds them. Every program a
+step can run is compiled when the engine is built: the pure decode step
+and the mixed step at each row size.
 """
 from __future__ import annotations
 
@@ -35,6 +37,27 @@ from repro.serving.draft import NgramDrafter
 from repro.serving.kv_pool import (SCRATCH_PAGE, chunk_window_pages,
                                    verify_window_pages)
 from repro.serving.scheduler import PagedScheduler, Request
+
+
+def mixed_row_sizes(chunk_tokens: int, max_batch: int,
+                    token_budget: int) -> List[int]:
+    """The sizes T of the mixed step's row axis, smallest first, from the
+    engine's geometry alone: round_up(n * chunk_tokens + max_batch, 256)
+    for n = 1, 4, 16, ... while that is below the most real rows a step
+    can carry, then that most. The most is token_budget (one chunk beside
+    every other lane's token when that exceeds it, as `_plan_chunked`
+    always advances one prompt), and never more than max_batch x
+    chunk_tokens, the lanes' grid."""
+    most = min(max_batch * chunk_tokens,
+               max(token_budget, chunk_tokens + max_batch - 1))
+    sizes, n = [], 1
+    while True:
+        t = -(-(n * chunk_tokens + max_batch) // 256) * 256
+        if t >= most:
+            return sizes + [most]
+        if not sizes or t > sizes[-1]:
+            sizes.append(t)
+        n *= 4
 
 
 @dataclasses.dataclass
@@ -69,7 +92,8 @@ class ContinuousBatchingEngine:
     pages in bit-exact (no recompute), and only the uncached tail is
     chunk-prefilled; finished requests promote their prompt pages. Cache
     hits change page-table *contents*, never step shapes, so
-    compile_counts() stays at the two-program steady state.
+    compile_counts() stays at the programs compiled when the engine was
+    built.
 
     spec_decode=k turns on draft-free self-speculative decoding: in
     pure-decode steps an n-gram prompt-lookup drafter (serving/draft.py)
@@ -138,6 +162,8 @@ class ContinuousBatchingEngine:
         # prefill chunk costs chunk_tokens; default = one chunk + all lanes
         self.token_budget = (token_budget if token_budget is not None
                              else self.chunk_tokens + max_batch)
+        self.row_sizes = mixed_row_sizes(self.chunk_tokens, max_batch,
+                                         self.token_budget)
         assert sampler in ("greedy", "temperature"), sampler
         assert spec_decode >= 0, spec_decode
         self.sampler = sampler
@@ -180,7 +206,8 @@ class ContinuousBatchingEngine:
         # input-output aliasing XLA copies every pool leaf per step (the
         # layer scans write them in place, `transformer._paged_layers`).
         # All call sites immediately rebind self.pools to the returned
-        # pools.
+        # pools. The decode and mixed programs are compiled ahead of time
+        # below (`_compile_steps`); the jitted functions only lower them.
         def serve_decode(params, pools, page_table, tokens, pos):
             return transformer.decode_step_paged(
                 params, pools, page_table, tokens, pos, cfg, qcfg=qcfg,
@@ -188,11 +215,11 @@ class ContinuousBatchingEngine:
                 with_stats=True)
 
         def serve_mixed(params, pools, page_table, window_rows, tokens,
-                        q_start, n_new):
+                        pos, lane, grid, q_start, n_new):
             return transformer.prefill_chunk_paged(
-                params, pools, page_table, window_rows, tokens, q_start,
-                n_new, cfg, qcfg=qcfg, impl=impl, paged_impl=paged_impl,
-                dtype=dtype, with_stats=True)
+                params, pools, page_table, window_rows, tokens, pos, lane,
+                grid, q_start, n_new, cfg, qcfg=qcfg, impl=impl,
+                paged_impl=paged_impl, dtype=dtype, with_stats=True)
 
         def serve_sample(logits):
             return jnp.argmax(logits, -1).astype(jnp.int32)
@@ -229,6 +256,16 @@ class ContinuousBatchingEngine:
 
         self._verify = jax.jit(serve_verify, donate_argnums=(1,))
         self._zero_key = jax.random.PRNGKey(0)
+        self._compile_steps()
+
+    def _compile_steps(self) -> None:
+        """Compile the decode program and the mixed program at every row
+        size, so that no step compiles (through JAX's persistent cache
+        where an entry point enables it, `launch/jax_cache.py`)."""
+        lowered = self.lower_steps()
+        self._decode_prog = lowered["decode"].compile()
+        self._mixed_progs = {t: low.compile()
+                             for t, low in lowered["mixed"].items()}
 
     # -- accounting -----------------------------------------------------------
 
@@ -241,23 +278,24 @@ class ContinuousBatchingEngine:
             / (n_pages * self.page_size)
 
     def compile_counts(self) -> Dict[str, int]:
-        """Compilation-cache sizes of the jitted step functions. Steady
-        state is exactly {mixed: 1, decode: 1, verify: 0}; spec_decode adds
-        at most one `verify` program (fixed k+1 window shape)."""
-        return {"mixed": self._mixed._cache_size(),
-                "decode": self._decode._cache_size(),
+        """The step programs compiled: {mixed: one per row size, decode:
+        1, verify: 0} from the engine's build on; spec_decode adds at most
+        one `verify` program (fixed k+1 window shape) on first use."""
+        return {"mixed": len(self._mixed_progs), "decode": 1,
                 "verify": self._verify._cache_size()}
 
-    def lower_steps(self) -> Dict[str, "jax.stages.Lowered"]:
-        """The steady-state mixed and decode programs lowered at this
-        engine's shapes, from the arguments `step` builds (here with no
-        lane advancing), so that compiling them yields the programs serving
-        runs (which kernels they call, their memory)."""
-        mixed = self._mixed_args([], [])
-        return {"mixed": self._mixed.lower(
-                    self.params, self.pools, *map(jnp.asarray, mixed)),
-                "decode": self._decode.lower(
-                    self.params, self.pools, *self._decode_args())}
+    def lower_steps(self) -> Dict[str, object]:
+        """The decode program and the mixed program at each row size
+        ({"decode": Lowered, "mixed": {T: Lowered}}), lowered at this
+        engine's shapes from the arguments `step` builds (here with no lane
+        working), so that compiling them yields the programs serving runs
+        (which kernels they call, their memory)."""
+        return {"decode": self._decode.lower(
+                    self.params, self.pools, *self._decode_args()),
+                "mixed": {t: self._mixed.lower(
+                    self.params, self.pools,
+                    *map(jnp.asarray, self._mixed_args([], [], t)))
+                    for t in self.row_sizes}}
 
     def spec_stats(self) -> Dict[str, float]:
         """Cumulative speculative-decoding counters and acceptance rate
@@ -397,10 +435,12 @@ class ContinuousBatchingEngine:
         return (jnp.asarray(sched.page_table), jnp.asarray(self._last_tok),
                 jnp.asarray(sched.lengths))
 
-    def _mixed_args(self, advancing, decoding):
-        """The mixed step's host arrays (page_table, windows, tokens,
-        q_start, n_new): the next prompt chunk of each advancing slot, one
-        token for each decoding slot, idle lanes n_new = 0."""
+    def _mixed_args(self, advancing, decoding, t: Optional[int] = None):
+        """The mixed step's host arrays (page_table, windows, tokens, pos,
+        lane, grid, q_start, n_new): the next prompt chunk of each
+        advancing slot, then one token for each decoding slot, packed on a
+        row axis of the smallest size that holds them, or of `t`
+        (`transformer.pack_rows`); idle lanes n_new = 0."""
         sched = self.sched
         page = self.page_size
         c, wc = self.chunk_tokens, self.window_pages
@@ -430,12 +470,18 @@ class ContinuousBatchingEngine:
             q_start[slot] = start
             n_new[slot] = 1
             fill_window(slot, start)
-        return sched.page_table, windows, toks, q_start, n_new
+        rows = int(n_new.sum())
+        if t is None:
+            t = next(s for s in self.row_sizes if s >= rows)
+        packed = transformer.pack_rows(toks, q_start, n_new, t,
+                                       list(advancing) + list(decoding))
+        return (sched.page_table, windows) + packed + (q_start, n_new)
 
     def _step_chunked(self, st) -> bool:
-        """Admit lazily (first chunk's pages only), then run one fixed-shape
-        mixed step: prompt chunks for advancing prefill slots, one token for
-        each decode slot, idle lanes masked out with n_new = 0."""
+        """Admit lazily (first chunk's pages only), then run one mixed step
+        (prompt chunks for advancing prefill slots, one token for each
+        decode slot, packed on the smallest row axis that holds them) or,
+        with no prompt advancing, one decode step."""
         sched = self.sched
         span = self.telemetry.span
         with span("serve.admit"):
@@ -458,7 +504,7 @@ class ContinuousBatchingEngine:
             with span("serve.inputs"):
                 args = self._decode_args()
             with span("serve.launch"):
-                logits, self.pools, counters = self._decode(
+                logits, self.pools, counters = self._decode_prog(
                     self.params, self.pools, *args)
             self.steps_run += 1
             st.ran("decode", [(s, int(sched.lengths[s]), 1)
@@ -469,15 +515,15 @@ class ContinuousBatchingEngine:
                 args = self._mixed_args(advancing, decoding)
                 n_new = args[-1]
                 dev_args = [jnp.asarray(a) for a in args]
+            t = len(args[2])
             with span("serve.launch"):
-                logits, self.pools, counters = self._mixed(
+                logits, self.pools, counters = self._mixed_progs[t](
                     self.params, self.pools, *dev_args)
             self.mixed_steps += 1
             q_start = args[-2]
             st.ran("mixed", [(s, int(q_start[s]), int(n_new[s]))
                              for s in range(b) if n_new[s] > 0],
-                   b * self.chunk_tokens,
-                   self._pages_walked(q_start + n_new))
+                   t, self._pages_walked(q_start + n_new))
 
         with span("serve.wait") as wait:
             nxt = self._sample_tokens(st, logits, counters)

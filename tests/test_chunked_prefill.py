@@ -1,8 +1,9 @@
 """Chunked-prefill regression tests: fused quantize-on-write page writes
 (`kv_pool.write_chunk` vs the one-shot and per-token paths) across all pool
 dtypes (bf16/int8/packed-int4), the chunk attention kernel (Pallas
-interpret vs jnp oracle vs dense causal SDPA), and the chunked engine
-against the dense forward, including preemption mid-prefill."""
+interpret vs jnp oracle vs dense causal SDPA), one packed mixed step
+against the full forward pass, and the chunked engine against the dense
+forward, including preemption mid-prefill."""
 from types import SimpleNamespace
 
 import numpy as np
@@ -10,7 +11,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from conftest import dense_greedy, make_engine, pool_leaves
+from conftest import (MIXED_LANES, dense_greedy, make_engine,
+                      packed_mixed_step, pages_changed, pool_leaves)
 from repro.kernels.paged_prefill import (paged_prefill_attention,
                                          paged_prefill_attention_ref)
 from repro.models import attention as attn
@@ -199,6 +201,34 @@ def test_paged_prefill_kernel_matches_ref(b, t, nq, nkv, hd, page, c, lanes,
     d = np.abs(np.asarray(got).reshape(b, c, -1)
                - np.asarray(dense).reshape(b, c, -1)).max(-1)
     assert d[rows].max() < tol, d[rows].max()
+
+
+# ---------------------------------------------------------------------------
+# one packed mixed step against the full forward pass
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("t", [40, 80])     # tight, and the lanes' grid
+def test_packed_mixed_step_matches_the_full_forward(cfg_params, kv_bits, t):
+    """Two prompts at different chunk offsets (one a partial chunk), two
+    decode lanes and an idle lane in one mixed step on a row axis of `t`
+    rows: each working lane's logits are the full forward pass's at its
+    last token (as closely as bf16 pages hold the keys, within a page
+    scale's rounding for int8 and int4), and no page outside the working
+    lanes' write windows (and the scratch page the idle lane writes)
+    changed."""
+    cfg, params = cfg_params
+    got = packed_mixed_step(cfg, params, kv_bits, t)
+    tol = {16: 0.03, 8: 0.06, 4: 0.35}[kv_bits]
+    for lane, (_, n) in enumerate(MIXED_LANES):
+        if n:
+            want = got["want"][lane]
+            np.testing.assert_allclose(
+                got["logits"][lane, :cfg.vocab], want[:cfg.vocab],
+                atol=tol * np.abs(want).max(), err_msg=f"lane {lane}")
+    written = set(got["windows"].reshape(-1).tolist()) | {
+        kv_pool.SCRATCH_PAGE}
+    assert pages_changed(got["before"], got["after"]) <= written
+    assert got["counters"] == {}
 
 
 # ---------------------------------------------------------------------------

@@ -11,13 +11,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import make_engine
+from conftest import (MIXED_LANES, make_engine, packed_mixed_step,
+                      pages_changed)
 from repro.configs import get_arch, reduced
 from repro.core.quant.qtypes import INT8
 from repro.core.quant import ptq
 from repro.kernels import ops, paged_mla
 from repro.models import moe as moe_mod
 from repro.models import transformer
+from repro.serving import kv_pool
 
 PROMPTS = [list(range(1, 20)), [9] * 17, [3, 1, 4, 1, 5, 9, 2, 6]]
 
@@ -60,9 +62,10 @@ def test_paged_latent_steps_match_the_full_forward(ultra, kv_bits, rows):
     pools = transformer.init_paged_pools(cfg, n_pages, page, kv_bits)
     table = jnp.arange(1, n_pages, dtype=jnp.int32)[None]
     chunk = jnp.zeros((1, c), jnp.int32).at[0, :24].set(toks[0, :24])
+    q_start, n_new = jnp.zeros(1, jnp.int32), jnp.full((1,), 24, jnp.int32)
+    packed = transformer.pack_rows(chunk, q_start, n_new, c)
     logits, pools = transformer.prefill_chunk_paged(
-        params, pools, table, table[:, :5], chunk, jnp.zeros(1, jnp.int32),
-        jnp.full((1,), 24, jnp.int32), cfg)
+        params, pools, table, table[:, :5], *packed, q_start, n_new, cfg)
     got = [logits[0]]
     for i in range(rows):
         pos = jnp.full((1,), 24 + i, jnp.int32)
@@ -74,6 +77,36 @@ def test_paged_latent_steps_match_the_full_forward(ultra, kv_bits, rows):
     scale = float(jnp.abs(want).max())
     np.testing.assert_allclose(np.asarray(jnp.stack(got)), np.asarray(want),
                                atol=tol * scale)
+
+
+@pytest.mark.parametrize("t", [40, 80])     # tight, and the lanes' grid
+def test_packed_mixed_step_matches_the_full_forward(ultra, kv_bits, t):
+    """Latent attention and the expert layer on the packed rows: two
+    prompts at different chunk offsets (one a partial chunk), two decode
+    lanes and an idle lane in one mixed step. Each working lane's logits
+    are the full forward pass's at its last token; the expert counters
+    hold the real tokens' pairs only (padding rows route nowhere); no page
+    outside the working lanes' write windows (and the scratch page the
+    idle lane writes) changed. Every expert is chosen, as above."""
+    cfg, params = ultra
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, top_k=cfg.moe.num_experts))
+    got = packed_mixed_step(cfg, params, kv_bits, t)
+    tol = {16: 0.03, 8: 0.06, 4: 0.35}[kv_bits]
+    for lane, (_, n) in enumerate(MIXED_LANES):
+        if n:
+            want = got["want"][lane]
+            np.testing.assert_allclose(
+                got["logits"][lane, :cfg.vocab], want[:cfg.vocab],
+                atol=tol * np.abs(want).max(), err_msg=f"lane {lane}")
+    rows = np.asarray(got["counters"]["expert_rows"])
+    absent = np.asarray(got["counters"]["expert_absent"])
+    assert rows.shape[0] == absent.shape[0] == cfg.n_groups
+    np.testing.assert_array_equal(rows.sum(-1) + absent,
+                                  got["tokens"] * cfg.moe.top_k)
+    written = set(got["windows"].reshape(-1).tolist()) | {
+        kv_pool.SCRATCH_PAGE}
+    assert pages_changed(got["before"], got["after"]) <= written
 
 
 def test_the_engine_serves_it_with_expert_counters(ultra, kv_bits):

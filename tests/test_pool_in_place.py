@@ -70,11 +70,12 @@ def _steps(model, kv_bits):
 def _run(cfg, params, toks, kv_bits):
     pools0 = _noise_pools(cfg, kv_bits)
     n_new = jnp.asarray(PROMPTS, jnp.int32)
-    chunk = jnp.where(jnp.arange(CHUNK)[None] < n_new[:, None], toks, 0)
+    q_start = jnp.zeros(3, jnp.int32)
+    rows = transformer.pack_rows(toks, q_start, n_new, 2 * CHUNK)
     table = jnp.asarray(TABLE)
     mixed, pools1 = transformer.prefill_chunk_paged(
-        params, pools0, table, table[:, :WINDOW], chunk,
-        jnp.zeros(3, jnp.int32), n_new, cfg)
+        params, pools0, table, table[:, :WINDOW], *rows, q_start, n_new,
+        cfg)
     pos = jnp.asarray(PROMPTS, jnp.int32)
     decode, pools2 = transformer.decode_step_paged(
         params, pools1, table, toks[np.arange(3), PROMPTS], pos, cfg)

@@ -8,8 +8,9 @@ of its DMAs meet the chip's compiler at the size the benchmark runs; and
 openPangu-Ultra-MoE's two kernels at its cell's geometry (latent pages of
 576 for 128 heads, 64 lanes, a 641-column table; 8 held experts of 7680 ->
 2 x 2048 -> 7680, the rows of a decode and of a mixed step). The engine's
-decode and mixed step programs compile too, at both cells' page counts, to
-check that they write the KV pools in place.
+decode program and its mixed program at every size of its packed row axis
+compile too, at both cells' page counts, to check that they write the KV
+pools in place.
 
 Nothing runs: the TPU compiler, which is installed here, compiles for a
 chip that is described and not attached, and refuses what the chip's
@@ -220,17 +221,47 @@ def _pool_sized_copies(text, pages):
     return out
 
 
-def _step_programs(one_chip, cfg, *, lanes, max_seq_len, n_pages,
-                   token_budget):
-    """The engine's decode and mixed step programs as a cell serves them
-    (W8A8, int8 pages of 16, the pools donated), compiled for one v5e chip
-    at the cell's page count: ({program: compiled text}, params, pools) with
-    params and pools as shapes. The engine itself holds a two-page pool;
-    its jitted programs take the pools' shapes from their arguments."""
+# The two cells' engines: config, lanes, max_seq_len, pages, token budget.
+def _cell(geometry):
+    if geometry == "pangu-1b":
+        # bench/configs/pangu-1b-w8a8.json's widths, two of its 26 layers
+        cfg = dataclasses.replace(get_arch("pangu_1b"), n_layers=2,
+                                  n_kv_heads=NKV, d_ff=6144)
+        return cfg, LANES, 4624, 4625, 2048
+    # bench/configs/pangu-ultra-moe-w8a8.json's block: its leading dense
+    # layer and two expert layers, its 8 held experts
+    base = get_arch("pangu_ultra_moe")
+    cfg = dataclasses.replace(
+        base, n_layers=3, first_k_dense=1, vocab=19200, tie_embeddings=False,
+        moe=dataclasses.replace(base.moe, held=(0, HELD)))
+    return cfg, MLA_LANES, 10256, MLA_PAGES, 8192
+
+
+def _programs(geometry):
+    """Each step program of a cell's engine by name: "decode" and "mixed"
+    at each size of the row axis (`engine.mixed_row_sizes`)."""
+    from repro.serving.engine import mixed_row_sizes
+    _, lanes, _, _, budget = _cell(geometry)
+    return ["decode"] + [f"mixed{t}" for t in
+                         mixed_row_sizes(CHUNK, lanes, budget)]
+
+
+STEP_PROGRAMS = [(g, p) for g in ("pangu-1b", "pangu-ultra-moe")
+                 for p in _programs(g)]
+
+
+def _step_program(one_chip, geometry, program):
+    """One of the engine's step programs as a cell serves it (W8A8, int8
+    pages of 16, the pools donated), compiled for one v5e chip at the
+    cell's page count: (compiled text, params, pools) with params and pools
+    as shapes. The engine itself holds a two-page pool and compiles nothing
+    when built here; its jitted programs take the pools' shapes from their
+    arguments."""
     from repro.core.quant import ptq
     from repro.core.quant.qtypes import preset
     from repro.models import transformer
     from repro.serving.engine import ContinuousBatchingEngine
+    cfg, lanes, max_seq_len, n_pages, token_budget = _cell(geometry)
     qcfg = preset("int8")
     params = jax.eval_shape(lambda k: ptq.quantize_model(
         transformer.init_params(k, cfg), cfg, qcfg), jax.random.PRNGKey(0))
@@ -244,51 +275,38 @@ def _step_programs(one_chip, cfg, *, lanes, max_seq_len, n_pages,
         # "auto" takes the kernels, as it does on the chip
         mp.setattr(ops, "resolve_impl", lambda impl: "pallas"
                    if (impl or "auto") == "auto" else resolve(impl))
+        mp.setattr(ContinuousBatchingEngine, "_compile_steps",
+                   lambda self: None)
         eng = ContinuousBatchingEngine(
             params, cfg, qcfg=qcfg, kv_bits=8, page_size=PAGE,
             max_batch=lanes, max_seq_len=max_seq_len, n_pages=2,
             chunk_pages=CHUNK // PAGE, token_budget=token_budget)
-        args = {"decode": eng._decode_args(),
-                "mixed": tuple(map(jnp.asarray, eng._mixed_args([], [])))}
-        texts = {name: prog.lower(on_chip(params), on_chip(pools),
-                                  *on_chip(args[name])).compile().as_text()
-                 for name, prog in (("decode", eng._decode),
-                                    ("mixed", eng._mixed))}
-    return texts, params, pools
+        if program == "decode":
+            prog, args = eng._decode, eng._decode_args()
+        else:
+            t = int(program[len("mixed"):])
+            prog = eng._mixed
+            args = tuple(map(jnp.asarray, eng._mixed_args([], [], t)))
+        text = prog.lower(on_chip(params), on_chip(pools),
+                          *on_chip(args)).compile().as_text()
+    return text, params, pools
 
 
-@pytest.mark.parametrize("geometry", ["pangu-1b", "pangu-ultra-moe"])
-def test_step_programs_write_the_pools_in_place(one_chip, geometry):
-    """The decode and mixed programs at a cell's page count make no copy of
-    a pool stack or of one layer's pool: no whole-pool copy, no layer's
-    pool sliced out of its stack or written back into it, only the pages a
-    step writes, in place; and every pool buffer passed in is the buffer
-    that comes out. Two layers in a scanned stack, the fewest at which the
-    scan stays a loop (a one-layer scan compiles to straight-line code)."""
-    if geometry == "pangu-1b":
-        # bench/configs/pangu-1b-w8a8.json's widths, two of its 26 layers
-        cfg = dataclasses.replace(get_arch("pangu_1b"), n_layers=2,
-                                  n_kv_heads=NKV, d_ff=6144)
-        texts, params, pools = _step_programs(
-            one_chip, cfg, lanes=LANES, max_seq_len=4624, n_pages=4625,
-            token_budget=2048)
-    else:
-        # bench/configs/pangu-ultra-moe-w8a8.json's block: its leading
-        # dense layer and two expert layers, its 8 held experts
-        base = get_arch("pangu_ultra_moe")
-        cfg = dataclasses.replace(
-            base, n_layers=3, first_k_dense=1, vocab=19200,
-            tie_embeddings=False,
-            moe=dataclasses.replace(base.moe, held=(0, HELD)))
-        texts, params, pools = _step_programs(
-            one_chip, cfg, lanes=MLA_LANES, max_seq_len=10256,
-            n_pages=MLA_PAGES, token_budget=8192)
+@pytest.mark.parametrize("geometry, program", STEP_PROGRAMS)
+def test_step_programs_write_the_pools_in_place(one_chip, geometry, program):
+    """The decode program and the mixed program at every size of its row
+    axis, at a cell's page count, make no copy of a pool stack or of one
+    layer's pool: no whole-pool copy, no layer's pool sliced out of its
+    stack or written back into it, only the pages a step writes, in place;
+    and every pool buffer passed in is the buffer that comes out. Two
+    layers in a scanned stack, the fewest at which the scan stays a loop
+    (a one-layer scan compiles to straight-line code)."""
+    text, params, pools = _step_program(one_chip, geometry, program)
     n_params = len(jax.tree.leaves(params))
     n_pools = len(jax.tree.leaves(pools))
-    for name, text in texts.items():
-        for stack in pools.values():
-            assert _pool_sized_copies(text, stack["k"]) == [], name
-        aliased = {int(o): int(p)
-                   for o, p in _ALIAS.findall(text.split("\n", 1)[0])}
-        # outputs (logits, pools, counters); arguments (params, pools, ...)
-        assert aliased == {1 + i: n_params + i for i in range(n_pools)}, name
+    for stack in pools.values():
+        assert _pool_sized_copies(text, stack["k"]) == [], program
+    aliased = {int(o): int(p)
+               for o, p in _ALIAS.findall(text.split("\n", 1)[0])}
+    # outputs (logits, pools, counters); arguments (params, pools, ...)
+    assert aliased == {1 + i: n_params + i for i in range(n_pools)}, program
